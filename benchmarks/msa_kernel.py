@@ -32,8 +32,8 @@ def _setup(cached: int, seed: int = 0):
     key = jax.random.PRNGKey(seed)
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (1, NEW, H, D), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (npages + 2, PAGE, KH, D), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (npages + 2, PAGE, KH, D), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (npages + 2, KH, PAGE, D), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (npages + 2, KH, PAGE, D), jnp.float32)
     bt = jnp.arange(npages, dtype=jnp.int32)[None, :]
     ctx = jnp.array([total], jnp.int32)
     q_lens = jnp.array([NEW], jnp.int32)

@@ -121,6 +121,8 @@ print("RESULT " + json.dumps(out))
 
 def _run_child(n_jobs: int, seed: int) -> dict:
     env = dict(os.environ)
+    # host devices only: never compete with a parent for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={N_DEVICES}"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

@@ -100,7 +100,7 @@ def bucket_footprints(cfg, ecfg, n_shards: int = 1,
     params_bytes = _bytes_of(abstract_params(cfg))
     kv_pool = jax.eval_shape(
         lambda: jnp.zeros((cfg.n_layers, 2, ecfg.num_pages,
-                           ecfg.page_size, kv_heads, cfg.head_dim),
+                           kv_heads, ecfg.page_size, cfg.head_dim),
                           pool_dt))
     kv_pool_bytes = _bytes_of(kv_pool)
 

@@ -237,7 +237,7 @@ def snap_to_grid_np(arr: np.ndarray, mode: str, scale: float) -> np.ndarray:
 
 def quantize_half(arr: np.ndarray, fmt: str, static_scale: float = 0.0,
                   scale: Optional[np.ndarray] = None) -> HostHalf:
-    """Encode one (L, page, KH, D) half for the host tier.
+    """Encode one (L, KH, page, D) half for the host tier.
 
     ``fmt="q8"``: int8 codes + per-page-per-head (L, KH) f32 scale —
     the given ``scale`` (requantization of previously restored
@@ -254,12 +254,12 @@ def quantize_half(arr: np.ndarray, fmt: str, static_scale: float = 0.0,
     f32 = arr.astype(np.float32)
     if scale is None:
         if static_scale > 0.0:
-            L, _, KH, _ = arr.shape
+            L, KH = arr.shape[:2]
             scale = np.full((L, KH), np.float32(static_scale), np.float32)
         else:
-            amax = np.max(np.abs(f32), axis=(1, 3))          # (L, KH)
+            amax = np.max(np.abs(f32), axis=(2, 3))          # (L, KH)
             scale = np.maximum(amax / INT8_QMAX, 1e-12).astype(np.float32)
-    codes = np.clip(np.round(f32 / scale[:, None, :, None]),
+    codes = np.clip(np.round(f32 / scale[:, :, None, None]),
                     -INT8_QMAX, INT8_QMAX).astype(np.int8)
     return HostHalf(data=codes, scale=scale,
                     nbytes=codes.nbytes + scale.nbytes, fmt="q8")
@@ -274,7 +274,7 @@ def dequantize_half(half: HostHalf, dtype) -> np.ndarray:
         return half.data
     if half.fmt == "f8":
         return half.data.astype(dtype)
-    out = half.data.astype(np.float32) * half.scale[:, None, :, None]
+    out = half.data.astype(np.float32) * half.scale[:, :, None, None]
     return out.astype(dtype)
 
 

@@ -15,11 +15,6 @@ from typing import Dict, Optional, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # JAX >= 0.6 exports shard_map at the top level
-    shard_map = jax.shard_map
-except AttributeError:  # pinned 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map
-
 _STATE = threading.local()
 
 

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import context as ctx
-from repro.distributed.context import shard_map
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -116,10 +115,10 @@ def sharded_decode_attention(q: jax.Array, k_cache: jax.Array,
         o, lse = _local_partial(ql, kl, vl, start, lenl, window, softcap)
         return lse_merge(o, lse, seq_tuple).astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, len_spec),
-        out_specs=q_spec, check_rep=False,
+        out_specs=q_spec, check_vma=False,
     )(q, k_cache, v_cache, kv_len)
 
 
@@ -145,7 +144,7 @@ def sharded_msa_fused(q, k_pool, v_pool, k_new, v_new, write_slot,
     """One layer's KV page write + fused varlen MSA over a page-sharded
     pool, inside ``shard_map``.  Returns ``(k_pool', v_pool', attn)``.
 
-    ``k_pool``/``v_pool`` are the layer's (P, page, KH, D) pools sharded on
+    ``k_pool``/``v_pool`` are the layer's (P, KH, page, D) pools sharded on
     the page axis over ``axis``; everything else is replicated.  Each shard
     (a) scatters the new tokens whose destination page it owns (non-local
     rows steered out of range and dropped — the same mechanism that drops
@@ -173,11 +172,11 @@ def sharded_msa_fused(q, k_pool, v_pool, k_new, v_new, write_slot,
             va, page_valid, window=window, softcap=softcap)
         return kp, vp, lse_merge(o, lse, axis).astype(ql.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), pool_spec, pool_spec, P(), P(), P(), P(), P(), P(),
                   P(), P(), P()),
-        out_specs=(pool_spec, pool_spec, P()), check_rep=False,
+        out_specs=(pool_spec, pool_spec, P()), check_vma=False,
     )(q, k_pool, v_pool, k_new, v_new, write_slot, write_off, valid, bt,
       context_lens, q_pos, seq_ids)
 
@@ -192,7 +191,7 @@ def sharded_pool_ops(k_pools, v_pools, swap_k_dst, swap_v_dst,
     padding: swap dst == P_loc, copies repeat the last real local pair
     or the identity 0 -> 0).  The K and V swap halves carry independent
     destination buckets (split residency: a V-only swap-in ships no K
-    payload).  ``swap_k``/``swap_v`` are (n, L, S, page, KH, D) payloads
+    payload).  ``swap_k``/``swap_v`` are (n, L, S, KH, page, D) payloads
     sharded on the leading shard axis (full precision only — quantized
     payloads require the single-device engine).  Cross-shard copies
     cannot be expressed here — the engine routes them through its eager
@@ -208,10 +207,10 @@ def sharded_pool_ops(k_pools, v_pools, swap_k_dst, swap_v_dst,
         k, v = apply_page_copies(k, v, cs[i], cd[i])
         return k, v
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(pool_spec, pool_spec, P(), P(), swap_spec, swap_spec,
                   P(), P()),
-        out_specs=(pool_spec, pool_spec), check_rep=False,
+        out_specs=(pool_spec, pool_spec), check_vma=False,
     )(k_pools, v_pools, swap_k_dst, swap_v_dst, swap_k, swap_v,
       copy_src, copy_dst)

@@ -22,7 +22,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.distributed import context as ctx
-from repro.distributed.context import shard_map
 from repro.models.layers import capacity_dispatch, topk_route
 
 
@@ -99,8 +98,8 @@ def moe_ffn_alltoall(x: jax.Array, router_w: jax.Array, we1: jax.Array,
         out = jax.lax.psum(out, tp_axis)                   # bf16 on the wire
         return out.reshape(b_l, s_l, d)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, w_router_spec, w13_spec, w13_spec, w2_spec),
-        out_specs=x_spec, check_rep=False,
+        out_specs=x_spec, check_vma=False,
     )(x, router_w, we1, we3, we2)

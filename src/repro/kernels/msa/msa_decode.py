@@ -29,8 +29,8 @@ def _decode_kernel(
     context_lens,    # (B,)
     # inputs
     q_ref,           # (1, 1, G, D)
-    k_ref,           # (1, page, 1, D)
-    v_ref,           # (1, page, 1, D)
+    k_ref,           # (1, 1, page, D)
+    v_ref,           # (1, 1, page, D)
     # outputs
     o_ref,           # (1, 1, G, D)
     # scratch
@@ -62,8 +62,8 @@ def _decode_kernel(
         scale = 1.0 / math.sqrt(d)
         g = q_ref.shape[2]
         qt = q_ref[0, 0, :, :].astype(jnp.float32) * scale     # (G, D)
-        kt = k_ref[0, :, 0, :].astype(jnp.float32)             # (page, D)
-        vt = v_ref[0, :, 0, :].astype(jnp.float32)
+        kt = k_ref[0, 0].astype(jnp.float32)                   # (page, D)
+        vt = v_ref[0, 0].astype(jnp.float32)
 
         s = jax.lax.dot_general(qt, kt, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -95,7 +95,7 @@ def _decode_kernel(
 
 def msa_decode_pallas(
     q: jax.Array,              # (B, H, D)
-    k_pages: jax.Array,        # (P, page, KH, D)
+    k_pages: jax.Array,        # (P, KH, page, D) head-major pool
     v_pages: jax.Array,
     block_tables: jax.Array,   # (B, NP)
     context_lens: jax.Array,   # (B,)
@@ -105,7 +105,7 @@ def msa_decode_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     b, h, d = q.shape
-    p_, page, kh, _ = k_pages.shape
+    p_, kh, page, _ = k_pages.shape
     np_ = block_tables.shape[1]
     grp = h // kh
     qg = q.reshape(b, kh, grp, d)
@@ -114,7 +114,7 @@ def msa_decode_pallas(
         return (b_, g_, 0, 0)
 
     def kv_index(b_, g_, j_, block_tables_, context_lens_):
-        return (block_tables_[b_, j_], 0, g_, 0)
+        return (block_tables_[b_, j_], g_, 0, 0)
 
     grid = (b, kh, np_)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -122,8 +122,8 @@ def msa_decode_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, grp, d), q_index),
-            pl.BlockSpec((1, page, 1, d), kv_index),
-            pl.BlockSpec((1, page, 1, d), kv_index),
+            pl.BlockSpec((1, 1, page, d), kv_index),
+            pl.BlockSpec((1, 1, page, d), kv_index),
         ],
         out_specs=pl.BlockSpec((1, 1, grp, d), q_index),
         scratch_shapes=[

@@ -18,16 +18,28 @@ All work-list metadata is scalar-prefetched; the kv-page BlockSpec
 index_map streams the *pool slot* recorded in the work-list straight out
 of paged HBM.
 
-Grid: ``(H, W)`` — W iterates sequentially on a TPU core.  Items of one
-q tile are consecutive, carrying the flash running max/sum in VMEM
-scratch across pages (and across the several sequences that may share a
-tile: each item contributes only rows inside its own sequence's run; the
-row-wise accumulator merges them exactly).  Work-list padding items point
-at the sentinel sequence row N (``q_len == 0``), mask every row, and are
-exact no-ops.
+Layout: the pools are head-major ``(P, KH, page, D)`` and the wrapper
+feeds q grouped by KV head, ``(KH, G, T, D)`` with ``G = H / KH``, so
+every block ends in a ``(rows, D)`` tile — ``(page, D)`` for K/V,
+``(TQ, D)`` per query head for q/out, ``(TQ, 1)`` for the q positions —
+which is what Mosaic's (8, 128) tiling accepts.  A size-1 head axis
+second to last (the token-major ``(P, page, KH, D)`` layout) is refused
+by the TPU compiler.
 
-VMEM working set mirrors the split prefill kernel (q tile + 2 kv pages +
-f32 scratch ≈ 164 KB at TQ=128, page=64, D=128 ≪ 16 MB).
+Grid: ``(KH, W)`` — W iterates sequentially on a TPU core.  One grid
+step serves all G query heads of a KV head: the ``(G, TQ, D)`` q block is
+one ``(G·TQ, D)`` matmul operand, so each K/V page is fetched once per
+KV head (not once per query head) and the grid has KH·W steps, not H·W.
+Items of one q tile are consecutive, carrying the flash running max/sum
+in VMEM scratch across pages (and across the several sequences that may
+share a tile: each item contributes only rows inside its own sequence's
+run; the row-wise accumulator merges them exactly).  Work-list padding
+items point at the sentinel sequence row N (``q_len == 0``), mask every
+row, and are exact no-ops.
+
+VMEM working set at TQ=128, G=4, page=16, D=128: q block 128 KB (bf16),
+f32 accumulator 256 KB, two 4 KB K/V pages, double-buffered — well under
+the scoped VMEM limit.
 """
 from __future__ import annotations
 
@@ -155,16 +167,16 @@ def _msa_fused_kernel(
     q_len,            # (N+1,) run length (sentinel row: 0)
     context_lens,     # (N+1,)
     # inputs
-    q_pos_ref,        # (1, TQ) int32 — logical positions of this q tile
-    q_ref,            # (1, TQ, 1, D)
-    k_ref,            # (1, page, 1, D)
-    v_ref,            # (1, page, 1, D)
+    q_pos_ref,        # (G, TQ, 1) int32 — logical positions of this q tile
+    q_ref,            # (1, G, TQ, D) — the G query heads of one KV head
+    k_ref,            # (1, 1, page, D)
+    v_ref,            # (1, 1, page, D)
     # outputs
-    o_ref,            # (1, TQ, 1, D)
+    o_ref,            # (1, G, TQ, D)
     # scratch
-    acc_ref,          # (TQ, D) f32
-    m_ref,            # (TQ, 1) f32
-    l_ref,            # (TQ, 1) f32
+    acc_ref,          # (G*TQ, D) f32
+    m_ref,            # (G*TQ, 1) f32
+    l_ref,            # (G*TQ, 1) f32
     *,
     page: int,
     window: int,
@@ -173,6 +185,8 @@ def _msa_fused_kernel(
 ):
     w = pl.program_id(1)
     s = wl_seq[w]
+    _, grp, tq, d = q_ref.shape
+    rows_g = grp * tq
 
     @pl.when(wl_init[w] == 1)
     def _init():
@@ -180,11 +194,11 @@ def _msa_fused_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    d = q_ref.shape[-1]
     scale = 1.0 / math.sqrt(d)
-    qt = q_ref[0, :, 0, :].astype(jnp.float32) * scale          # (TQ, D)
-    kt = k_ref[0, :, 0, :].astype(jnp.float32)                  # (page, D)
-    vt = v_ref[0, :, 0, :].astype(jnp.float32)
+    # the G heads' (TQ, D) tiles stacked into one (G*TQ, D) operand
+    qt = q_ref[0].astype(jnp.float32).reshape(rows_g, d) * scale
+    kt = k_ref[0, 0].astype(jnp.float32)                        # (page, D)
+    vt = v_ref[0, 0].astype(jnp.float32)
 
     sc = jax.lax.dot_general(qt, kt, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -194,14 +208,13 @@ def _msa_fused_kernel(
     # rows of this tile that belong to THIS item's sequence run; rows of
     # other sequences sharing the tile are handled by their own items
     rows = wl_qtile[w] * q_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (q_tile, 1), 0)                              # (TQ, 1)
+        jnp.int32, (grp, tq, 1), 1).reshape(rows_g, 1)          # (G*TQ, 1)
     row_ok = (rows >= q_start[s]) & (rows < q_start[s] + q_len[s])
 
     ctx = context_lens[s]
     kv_pos = wl_kvbase[w] + jax.lax.broadcasted_iota(
-        jnp.int32, (q_tile, page), 1)
-    qpos = q_pos_ref[0, :]
-    rel = qpos[:, None] - kv_pos
+        jnp.int32, (rows_g, page), 1)
+    rel = q_pos_ref[...].reshape(rows_g, 1) - kv_pos            # (G*TQ, page)
     mask = row_ok & (rel >= 0) & (kv_pos < ctx)
     if window > 0:
         mask = mask & (rel < window)
@@ -223,14 +236,13 @@ def _msa_fused_kernel(
         # fully masked rows (padding / other sequences' rows already
         # emitted by their items' earlier tiles never reach here with
         # l == 0 except true padding, which emits exact zeros like the ref
-        o_ref[0, :, 0, :] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = out.reshape(grp, tq, d).astype(o_ref.dtype)
 
 
 def msa_fused_pallas(
     q: jax.Array,              # (T, H, D) flattened mixed token stream
-    k_pages: jax.Array,        # (P, page, KH, D)
+    k_pages: jax.Array,        # (P, KH, page, D) head-major pool
     v_pages: jax.Array,
     q_start: jax.Array,        # (N,) int32
     q_len: jax.Array,          # (N,) int32
@@ -249,7 +261,7 @@ def msa_fused_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     t, h, d = q.shape
-    p_, page, kh, _ = k_pages.shape
+    _, kh, page, _ = k_pages.shape
     grp = h // kh
     q_tile = min(q_tile, t)
     n_tiles = -(-t // q_tile)
@@ -257,8 +269,12 @@ def msa_fused_pallas(
     if t_pad != t:
         q = jnp.pad(q, ((0, t_pad - t), (0, 0), (0, 0)))
         q_pos = jnp.pad(q_pos, (0, t_pad - t))
-    q4 = q.reshape(n_tiles, q_tile, h, d)
-    qpos2 = q_pos.reshape(n_tiles, q_tile).astype(jnp.int32)
+    # q grouped by KV head, (KH, G, T, D): one block per (KV head, q tile)
+    # carries every query head that reads the same K/V pages.  Only the
+    # activations move; the pools are already head-major.
+    qg = q.reshape(t_pad, kh, grp, d).transpose(1, 2, 0, 3)
+    qpos = jnp.broadcast_to(q_pos.astype(jnp.int32).reshape(1, t_pad, 1),
+                            (grp, t_pad, 1))
     # sentinel sequence row N: padding work-list items resolve to it and
     # mask every q row (q_len 0)
     zero = jnp.zeros((1,), jnp.int32)
@@ -266,30 +282,30 @@ def msa_fused_pallas(
     ql = jnp.concatenate([q_len.astype(jnp.int32), zero])
     ctx = jnp.concatenate([context_lens.astype(jnp.int32), zero])
 
-    def qpos_index(h_, w_, wl_seq_, wl_qtile_, *refs):
-        return (wl_qtile_[w_], 0)
+    def qpos_index(g_, w_, wl_seq_, wl_qtile_, *refs):
+        return (0, wl_qtile_[w_], 0)
 
-    def q_index(h_, w_, wl_seq_, wl_qtile_, *refs):
-        return (wl_qtile_[w_], 0, h_, 0)
+    def q_index(g_, w_, wl_seq_, wl_qtile_, *refs):
+        return (g_, 0, wl_qtile_[w_], 0)
 
-    def kv_index(h_, w_, wl_seq_, wl_qtile_, wl_slot_, *refs):
-        return (wl_slot_[w_], 0, h_ // grp, 0)
+    def kv_index(g_, w_, wl_seq_, wl_qtile_, wl_slot_, *refs):
+        return (wl_slot_[w_], g_, 0, 0)
 
-    grid = (h, wl_seq.shape[0])
+    grid = (kh, wl_seq.shape[0])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=9,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, q_tile), qpos_index),
-            pl.BlockSpec((1, q_tile, 1, d), q_index),
-            pl.BlockSpec((1, page, 1, d), kv_index),
-            pl.BlockSpec((1, page, 1, d), kv_index),
+            pl.BlockSpec((grp, q_tile, 1), qpos_index),
+            pl.BlockSpec((1, grp, q_tile, d), q_index),
+            pl.BlockSpec((1, 1, page, d), kv_index),
+            pl.BlockSpec((1, 1, page, d), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, q_tile, 1, d), q_index),
+        out_specs=pl.BlockSpec((1, grp, q_tile, d), q_index),
         scratch_shapes=[
-            pltpu.VMEM((q_tile, d), jnp.float32),
-            pltpu.VMEM((q_tile, 1), jnp.float32),
-            pltpu.VMEM((q_tile, 1), jnp.float32),
+            pltpu.VMEM((grp * q_tile, d), jnp.float32),
+            pltpu.VMEM((grp * q_tile, 1), jnp.float32),
+            pltpu.VMEM((grp * q_tile, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
@@ -298,10 +314,11 @@ def msa_fused_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q4.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         interpret=interpret,
+        name="msa_fused",
     )(wl_seq.astype(jnp.int32), wl_qtile.astype(jnp.int32),
       wl_slot.astype(jnp.int32), wl_kvbase.astype(jnp.int32),
       wl_init.astype(jnp.int32), wl_last.astype(jnp.int32),
-      qs, ql, ctx, qpos2, q4, k_pages, v_pages)
-    return out.reshape(t_pad, h, d)[:t]
+      qs, ql, ctx, qpos, qg, k_pages, v_pages)
+    return out.transpose(2, 0, 1, 3).reshape(t_pad, h, d)[:t]

@@ -41,8 +41,8 @@ def _msa_prefill_kernel(
     # inputs
     q_pos_ref,        # (1, TQ) int32 — logical positions of this q tile
     q_ref,            # (1, TQ, 1, D)
-    k_ref,            # (1, page, 1, D)
-    v_ref,            # (1, page, 1, D)
+    k_ref,            # (1, 1, page, D)
+    v_ref,            # (1, 1, page, D)
     # outputs
     o_ref,            # (1, TQ, 1, D)
     # scratch
@@ -90,8 +90,8 @@ def _msa_prefill_kernel(
         d = q_ref.shape[-1]
         scale = 1.0 / math.sqrt(d)
         qt = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # (TQ, D)
-        kt = k_ref[0, :, 0, :].astype(jnp.float32)              # (page, D)
-        vt = v_ref[0, :, 0, :].astype(jnp.float32)
+        kt = k_ref[0, 0].astype(jnp.float32)                    # (page, D)
+        vt = v_ref[0, 0].astype(jnp.float32)
 
         s = jax.lax.dot_general(qt, kt, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -126,7 +126,7 @@ def _msa_prefill_kernel(
 
 def msa_prefill_pallas(
     q: jax.Array,              # (R, QP, H, D)
-    k_pages: jax.Array,        # (P, page, KH, D)
+    k_pages: jax.Array,        # (P, KH, page, D) head-major pool
     v_pages: jax.Array,
     block_tables: jax.Array,   # (R, NP) int32
     context_lens: jax.Array,   # (R,) int32
@@ -139,7 +139,7 @@ def msa_prefill_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     r, qp, h, d = q.shape
-    p_, page, kh, _ = k_pages.shape
+    p_, kh, page, _ = k_pages.shape
     np_ = block_tables.shape[1]
     assert qp % q_tile == 0, (qp, q_tile)
     qt_per_req = qp // q_tile
@@ -152,7 +152,7 @@ def msa_prefill_pallas(
         return (r_, qt_)
 
     def kv_index(r_, h_, qt_, j_, block_tables_, context_lens_, q_lens_):
-        return (block_tables_[r_, j_], 0, h_ // grp, 0)
+        return (block_tables_[r_, j_], h_ // grp, 0, 0)
 
     grid = (r, h, qt_per_req, np_)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -161,8 +161,8 @@ def msa_prefill_pallas(
         in_specs=[
             pl.BlockSpec((1, q_tile), qpos_index),
             pl.BlockSpec((1, q_tile, 1, d), q_index),
-            pl.BlockSpec((1, page, 1, d), kv_index),
-            pl.BlockSpec((1, page, 1, d), kv_index),
+            pl.BlockSpec((1, 1, page, d), kv_index),
+            pl.BlockSpec((1, 1, page, d), kv_index),
         ],
         out_specs=pl.BlockSpec((1, q_tile, 1, d), q_index),
         scratch_shapes=[

@@ -18,7 +18,7 @@ from repro.kernels.msa.msa_decode import msa_decode_pallas
 from repro.kernels.msa.msa_fused import msa_fused_pallas
 from repro.kernels.msa.msa_prefill import msa_prefill_pallas
 
-DEFAULT_IMPL = "xla"  # CPU container default; TPU deployments use "pallas"
+DEFAULT_IMPL = "xla"  # the oracle; the served TPU path passes "pallas"
 
 
 def msa_prefill(q, k_pages, v_pages, block_tables, context_lens, q_pos,
@@ -107,7 +107,7 @@ write_kv_pages = ref.write_kv_pages
 # Copy-on-write forks and host-tier swap-ins used to run as eager un-jitted
 # ``.at[].set`` dispatches between steps; folding them into the jitted step
 # as padded index arrays removes those host round-trips.  Both operate on
-# the layer-stacked pools (L, P, page, KH, D) and use out-of-range
+# the layer-stacked pools (L, P, KH, page, D) and use out-of-range
 # destination indices (dst == P) as padding, dropped by the scatter.
 # ---------------------------------------------------------------------------
 
@@ -129,7 +129,7 @@ def apply_page_copies(k_pools: jax.Array, v_pools: jax.Array,
     c = copy_src.shape[0]
     if c == 0:
         return k_pools, v_pools
-    k_pages = k_pools[:, copy_src]      # (L, C, page, KH, D) — small
+    k_pages = k_pools[:, copy_src]      # (L, C, KH, page, D) — small
     v_pages = v_pools[:, copy_src]
     for j in range(c):
         k_pools = jax.lax.dynamic_update_slice_in_dim(
@@ -140,13 +140,13 @@ def apply_page_copies(k_pools: jax.Array, v_pools: jax.Array,
 
 
 def _dequant_payload(payload: jax.Array, scale, dtype) -> jax.Array:
-    """In-step dequantization of a (L, S, page, KH, D) swap payload.
+    """In-step dequantization of a (L, S, KH, page, D) swap payload.
     int8 codes carry a per-page-per-head (L, S, KH) scale; fp8 payloads
     just cast.  The f32 multiply matches the host-side
     ``offload.dequantize_half`` operand order exactly, so eager and
     in-step swap-ins reproduce identical pool bytes."""
     if scale is not None:
-        out = payload.astype(jnp.float32) * scale[:, :, None, :, None]
+        out = payload.astype(jnp.float32) * scale[:, :, :, None, None]
         return out.astype(dtype)
     if payload.dtype != dtype:
         return payload.astype(dtype)
@@ -157,7 +157,7 @@ def apply_swap_ins(k_pools: jax.Array, v_pools: jax.Array,
                    swap_k_dst: jax.Array, swap_v_dst: jax.Array,
                    swap_k: jax.Array, swap_v: jax.Array,
                    swap_k_scale=None, swap_v_scale=None):
-    """Host-tier swap-ins: scatter (L, S, page, KH, D) payloads into pool
+    """Host-tier swap-ins: scatter (L, S, KH, page, D) payloads into pool
     pages, padding steered out of range and dropped.
 
     The K and V halves carry INDEPENDENT destination buckets

@@ -2,7 +2,9 @@
 
 These define the exact contract both Pallas kernels implement:
 
-Paged KV layout: ``k_pages``/``v_pages`` are (P, page, KH, D) pools.  A
+Paged KV layout: ``k_pages``/``v_pages`` are head-major (P, KH, page, D)
+pools, so one (page, D) tile of one KV head is contiguous — the block
+shape the TPU kernels stream (Mosaic tiles the last two dims).  A
 request's logical KV space is mapped to pool pages through its row of
 ``block_tables`` (R, NP): logical block j lives in pool page
 ``block_tables[r, j]``.  *Multi-segment* contexts need no special casing —
@@ -30,17 +32,17 @@ NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def _gather_kv(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """(P, page, KH, D), (R, NP) -> (R, NP*page, KH, D)."""
+    """(P, KH, page, D), (R, NP) -> (R, NP*page, KH, D)."""
     r, np_ = block_tables.shape
-    p, page, kh, d = pages.shape
-    out = pages[block_tables]            # (R, NP, page, KH, D)
-    return out.reshape(r, np_ * page, kh, d)
+    p, kh, page, d = pages.shape
+    out = pages[block_tables]            # (R, NP, KH, page, D)
+    return out.transpose(0, 1, 3, 2, 4).reshape(r, np_ * page, kh, d)
 
 
 def msa_prefill_ref(
     q: jax.Array,              # (R, QP, H, D)
-    k_pages: jax.Array,        # (P, page, KH, D)
-    v_pages: jax.Array,        # (P, page, KH, D)
+    k_pages: jax.Array,        # (P, KH, page, D)
+    v_pages: jax.Array,        # (P, KH, page, D)
     block_tables: jax.Array,   # (R, NP) int32
     context_lens: jax.Array,   # (R,) int32 — total logical kv length
     q_pos: jax.Array,          # (R, QP) int32 logical position per q token
@@ -50,7 +52,7 @@ def msa_prefill_ref(
     softcap: float = 0.0,
 ) -> jax.Array:
     r, qp, h, d = q.shape
-    kh = k_pages.shape[2]
+    kh = k_pages.shape[1]
     n_rep = h // kh
     scale = 1.0 / math.sqrt(d)
 
@@ -88,7 +90,7 @@ def msa_prefill_ref(
 
 def msa_fused_ref(
     q: jax.Array,              # (T, H, D) flattened mixed token stream
-    k_pages: jax.Array,        # (P, page, KH, D)
+    k_pages: jax.Array,        # (P, KH, page, D)
     v_pages: jax.Array,
     block_tables: jax.Array,   # (N, NP) int32 — one row per sequence
     context_lens: jax.Array,   # (N,) int32
@@ -119,7 +121,7 @@ def msa_fused_ref(
 
 def msa_fused_partial_ref(
     q: jax.Array,              # (T, H, D) flattened mixed token stream
-    k_pages: jax.Array,        # (P_loc, page, KH, D) — a LOCAL pool shard
+    k_pages: jax.Array,        # (P_loc, KH, page, D) — a LOCAL pool shard
     v_pages: jax.Array,
     block_tables: jax.Array,   # (N, NP) int32 — LOCAL page ids
     context_lens: jax.Array,   # (N,) int32
@@ -148,8 +150,8 @@ def msa_fused_partial_ref(
     shards) return ``lse = NEG_INF`` and ``o = 0`` — a zero-weight term in
     the merge.  Returns ``(o (T, H, D) f32, lse (T, H) f32)``."""
     t, h, d = q.shape
-    kh = k_pages.shape[2]
-    page = k_pages.shape[1]
+    kh = k_pages.shape[1]
+    page = k_pages.shape[2]
     n_rep = h // kh
     scale = 1.0 / math.sqrt(d)
 
@@ -191,8 +193,8 @@ def msa_fused_partial_ref(
 
 def msa_decode_ref(
     q: jax.Array,              # (B, H, D)
-    k_pages: jax.Array,        # (P, page, KH, D)
-    v_pages: jax.Array,        # (P, page, KH, D)
+    k_pages: jax.Array,        # (P, KH, page, D)
+    v_pages: jax.Array,        # (P, KH, page, D)
     block_tables: jax.Array,   # (B, NP)
     context_lens: jax.Array,   # (B,) — includes the new token
     *,
@@ -208,7 +210,7 @@ def msa_decode_ref(
 
 
 def write_kv_pages(
-    k_pages: jax.Array,        # (P, page, KH, D)
+    k_pages: jax.Array,        # (P, KH, page, D)
     v_pages: jax.Array,
     k_new: jax.Array,          # (T, KH, D)
     v_new: jax.Array,
@@ -219,9 +221,10 @@ def write_kv_pages(
     """Scatter freshly computed K/V into the paged pool (pre-attention).
 
     Invalid (padding) rows are routed out of range and dropped by the
-    scatter itself — no read-modify-write, stays a pure scatter."""
+    scatter itself — no read-modify-write, stays a pure scatter.  The
+    (T, KH, D) rows land at ``[slot, :, offset]`` of the head-major pool."""
     p = k_pages.shape[0]
     oob = jnp.where(valid, slot_ids, p)     # out-of-range -> dropped
-    k_pages = k_pages.at[oob, slot_offsets].set(k_new, mode="drop")
-    v_pages = v_pages.at[oob, slot_offsets].set(v_new, mode="drop")
+    k_pages = k_pages.at[oob, :, slot_offsets].set(k_new, mode="drop")
+    v_pages = v_pages.at[oob, :, slot_offsets].set(v_new, mode="drop")
     return k_pages, v_pages

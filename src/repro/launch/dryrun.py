@@ -120,8 +120,6 @@ def _probe_cfg(cfg: ModelConfig, n_layers: int) -> ModelConfig:
 
 def _costs_of(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):       # 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     coll = parse_collectives(compiled.as_text())
     out = {"flops": float(ca.get("flops", 0.0)),
            "bytes": float(ca.get("bytes accessed", 0.0)),
@@ -358,11 +356,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 "argument_bytes": mem.argument_size_in_bytes,
                 "output_bytes": mem.output_size_in_bytes,
                 "temp_bytes": mem.temp_size_in_bytes,
-                # 0.4.x CompiledMemoryStats has no peak field; args+temp
-                # upper-bounds live bytes (outputs alias donated inputs)
-                "peak_bytes": getattr(
-                    mem, "peak_memory_in_bytes",
-                    mem.argument_size_in_bytes + mem.temp_size_in_bytes),
+                "peak_bytes": mem.peak_memory_in_bytes,
                 "alias_bytes": mem.alias_size_in_bytes,
             },
             "raw_module_costs": raw,

@@ -1,57 +1,24 @@
-"""Production mesh construction + JAX version-compat shims.
+"""Mesh construction.
 
 Mesh builders are FUNCTIONS (not module-level constants) so importing this
 module never touches jax device state — critical because smoke tests must
 see 1 CPU device while the dry-run forces 512 host devices via XLA_FLAGS
 before any jax import.
-
-Version compat: newer JAX exposes ``jax.sharding.AxisType`` and accepts an
-``axis_types`` kwarg on ``jax.make_mesh`` / ``AbstractMesh(shape, names)``;
-the pinned 0.4.x toolchain has neither (and its ``AbstractMesh`` takes a
-``((name, size), ...)`` tuple).  ``make_mesh`` / ``abstract_mesh`` below
-paper over both so callers never import ``AxisType`` directly.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
-
-try:  # JAX >= 0.5: explicit/auto axis types
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pinned 0.4.x: no axis types — plain meshes only
-    _AxisType = None
-
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    _AxisType is not None
-    and "axis_types" in inspect.signature(jax.make_mesh).parameters)
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with ``axis_types=Auto`` where supported."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _MAKE_MESH_HAS_AXIS_TYPES:
-        kwargs["axis_types"] = (_AxisType.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
-
-
-def abstract_mesh(shape: Sequence[int],
-                  axes: Sequence[str]) -> "jax.sharding.AbstractMesh":
-    """Device-free mesh carrying axis sizes (sharding-rule sanity tests).
-
-    Newer JAX: ``AbstractMesh(shape, axis_names)``; 0.4.x takes one
-    ``((name, size), ...)`` tuple — passing ``(2, 2)`` there dies with
-    ``TypeError: 'int' object is not iterable`` when it zips the entries.
-    """
-    from jax.sharding import AbstractMesh
-    params = list(inspect.signature(AbstractMesh.__init__).parameters)
-    if "shape_tuple" in params:        # 0.4.x signature
-        return AbstractMesh(tuple(zip(tuple(axes), tuple(shape))))
-    return AbstractMesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD propagates
+    shardings; JAX's own default is ``Explicit``)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         (AxisType.Auto,) * len(axes), devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

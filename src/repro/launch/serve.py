@@ -1,8 +1,9 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Runs the AsymCache serving stack either for real (reduced model, CPU) or
-in discrete-event mode at full scale.  On a TPU deployment the same entry
-point selects ``attn_impl=pallas`` and the production mesh.
+Runs the AsymCache serving stack either for real (reduced model) or in
+discrete-event mode at full scale.  ``--attn-impl pallas`` serves through
+the compiled TPU kernel (a TPU backend is required; the XLA oracle is the
+CPU default).
 
 ``--devices N`` serves sharded: KV page pools sequence-shard over an
 N-way mesh with the flash-decode LSE merge (docs/ARCHITECTURE.md
@@ -39,6 +40,7 @@ import jax
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config, scaled_config
 from repro.core import TPU_V5E, analytic_cost_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import (
     AsymCacheServer,
@@ -71,6 +73,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.devices < 1:
         ap.error(f"--devices must be >= 1, got {args.devices}")
+    enable_compile_cache()
 
     if args.mode == "online":
         # closed-loop agent serving: sessions suspend on tool calls, the
@@ -89,6 +92,7 @@ def main() -> None:
         srv = AsymCacheServer(cfg, params, ServerConfig(
             policy=args.policy, num_blocks=args.blocks, block_size=16,
             clock="model", host_blocks=args.host_blocks,
+            attn_impl=args.attn_impl,
             scheduler=SchedulerConfig(token_budget=160, max_chunk=96,
                                       max_prefills=2, max_decodes=8)))
         fe = OnlineFrontend(srv, scripts,
@@ -114,7 +118,7 @@ def main() -> None:
                   f"(pool must divide across {n_dev} devices)")
         srv = AsymCacheServer(cfg, params, ServerConfig(
             policy=args.policy, num_blocks=blocks, block_size=16,
-            clock="wall", n_shards=args.devices,
+            clock="wall", n_shards=args.devices, attn_impl=args.attn_impl,
             scheduler=SchedulerConfig(token_budget=128, max_chunk=64,
                                       max_prefills=2, max_decodes=8)))
     else:

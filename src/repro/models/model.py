@@ -87,18 +87,29 @@ def _block_defs(cfg: ModelConfig, b: _Builder, blocks: Dict, axes: Dict,
 
     has_attn = cfg.family != "ssm"
     has_ssm = cfg.ssm is not None
+    # q/k/v projections contract over d_model (not the head axis the
+    # default shape[-2] rule would pick): unit-variance q and k keep the
+    # attention logits O(1) instead of O(d/H), where random weights give
+    # near-argmax attention and a chaotic forward
+    qkv = 1.0 / math.sqrt(d)
     if has_attn:
         b.add(blocks, axes, "attn_norm", (L, d), (None, None), zeros=True)
-        b.add(blocks, axes, "wq", (L, d, H, hd), (None, "fsdp", "heads", None))
-        b.add(blocks, axes, "wk", (L, d, KH, hd), (None, "fsdp", "kv_heads", None))
-        b.add(blocks, axes, "wv", (L, d, KH, hd), (None, "fsdp", "kv_heads", None))
+        b.add(blocks, axes, "wq", (L, d, H, hd), (None, "fsdp", "heads", None),
+              scale=qkv)
+        b.add(blocks, axes, "wk", (L, d, KH, hd),
+              (None, "fsdp", "kv_heads", None), scale=qkv)
+        b.add(blocks, axes, "wv", (L, d, KH, hd),
+              (None, "fsdp", "kv_heads", None), scale=qkv)
         b.add(blocks, axes, "wo", (L, H, hd, d), (None, "heads", None, "fsdp"),
               scale=depth / math.sqrt(H * hd))
     if cross_attn:
         b.add(blocks, axes, "xattn_norm", (L, d), (None, None), zeros=True)
-        b.add(blocks, axes, "xwq", (L, d, H, hd), (None, "fsdp", "heads", None))
-        b.add(blocks, axes, "xwk", (L, d, KH, hd), (None, "fsdp", "kv_heads", None))
-        b.add(blocks, axes, "xwv", (L, d, KH, hd), (None, "fsdp", "kv_heads", None))
+        b.add(blocks, axes, "xwq", (L, d, H, hd),
+              (None, "fsdp", "heads", None), scale=qkv)
+        b.add(blocks, axes, "xwk", (L, d, KH, hd),
+              (None, "fsdp", "kv_heads", None), scale=qkv)
+        b.add(blocks, axes, "xwv", (L, d, KH, hd),
+              (None, "fsdp", "kv_heads", None), scale=qkv)
         b.add(blocks, axes, "xwo", (L, H, hd, d), (None, "heads", None, "fsdp"),
               scale=depth / math.sqrt(H * hd))
     if has_ssm:
@@ -440,12 +451,13 @@ def _encoder_forward(params, cfg: ModelConfig, enc_embeds: jax.Array,
 
 
 def forward(params, cfg: ModelConfig, batch: Dict, *, remat: bool = False,
-            return_kv: bool = False, last_only: bool = False):
+            return_kv: bool = False, last_only: bool = False, at=None):
     """Full causal forward: returns logits (B, S, V).
 
     ``return_kv`` additionally returns the per-layer KV cache stacks
     (L, B, S, KH, D) — the product of an inference *prefill* step.
-    ``last_only`` computes logits for the final position only (prefill)."""
+    ``last_only`` computes logits for the final position only (prefill);
+    ``at`` (an int array) for those positions only."""
     x = embed_inputs(params, cfg, batch)
     x = constrain(x, "batch", None, None)
     bsz, S = x.shape[:2]
@@ -461,6 +473,8 @@ def forward(params, cfg: ModelConfig, batch: Dict, *, remat: bool = False,
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if last_only:
         x = x[:, -1:]
+    elif at is not None:
+        x = x[:, at]
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = x @ head
     logits = constrain(logits, "batch", None, "vocab")

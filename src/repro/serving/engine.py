@@ -193,7 +193,8 @@ class EngineConfig:
     max_chunk: int = 128           # QP (per-request compute tokens per step)
     max_decodes: int = 64          # B
     max_blocks_per_seq: int = 64   # NP
-    attn_impl: str = "xla"         # "xla" | "pallas" | "pallas_interpret"
+    # "pallas" (TPU only) | "xla" (oracle) | "pallas_interpret" (CPU)
+    attn_impl: str = "xla"
     q_tile: int = 128
     # "fused": one varlen attention dispatch per layer over the flattened
     # (T, H, D) mixed stream, with the occupancy bucket lattice.
@@ -271,6 +272,9 @@ class StepHandle:
     prefill_logits: jax.Array      # (R, V) rows ((R+B, V) full-logits mode)
     assembly_time: float = 0.0     # host-side build_inputs seconds
     full_logits: bool = False
+    # each decode row's input position (set by the server when it records
+    # decode logits)
+    decode_pos: Optional[List[int]] = None
     _ids_np: Optional[np.ndarray] = None
     _pre_np: Optional[np.ndarray] = None
 
@@ -298,6 +302,13 @@ class Engine:
         assert cfg.family in ("dense", "moe", "vlm"), cfg.family
         assert not cfg.enc_dec
         assert ecfg.attn_mode in ("fused", "split"), ecfg.attn_mode
+        assert ecfg.attn_impl in ("xla", "pallas", "pallas_interpret"), \
+            ecfg.attn_impl
+        if ecfg.attn_impl == "pallas" and jax.default_backend() != "tpu":
+            # never degrade silently to the interpreter or the oracle
+            raise ValueError(
+                "attn_impl='pallas' compiles the TPU kernel; this backend is "
+                f"{jax.default_backend()!r} (use 'xla' or 'pallas_interpret')")
         if ecfg.assembly == "legacy" and ecfg.attn_mode != "split":
             raise ValueError("legacy assembly implies attn_mode='split'")
         self.cfg = cfg
@@ -327,8 +338,11 @@ class Engine:
             self._payload_npdt = (np.dtype(cfg.dtype)
                                   if self._payload_fmt == "fp"
                                   else np.dtype(np.int8))
+        # head-major pools: one (page, D) tile per (slot, kv head) is the
+        # block the TPU kernel streams
         self.k_pools = jnp.zeros(
-            (L, ecfg.num_pages, ecfg.page_size, cfg.n_kv_heads, cfg.head_dim), dt)
+            (L, ecfg.num_pages, cfg.n_kv_heads, ecfg.page_size, cfg.head_dim),
+            dt)
         self.v_pools = jnp.zeros_like(self.k_pools)
         in_shardings = None
         if self.n_shards > 1:
@@ -387,11 +401,11 @@ class Engine:
         pdt = self._payload_dtype
         if self.n_shards > 1:
             self._zero_swap = jax.device_put(jnp.zeros(
-                (self.n_shards, L, ecfg.max_instep_swaps, ecfg.page_size,
-                 cfg.n_kv_heads, cfg.head_dim), dt), self._swap_sh)
+                (self.n_shards, L, ecfg.max_instep_swaps, cfg.n_kv_heads,
+                 ecfg.page_size, cfg.head_dim), dt), self._swap_sh)
         else:
             self._zero_swap = jnp.zeros(
-                (L, ecfg.max_instep_swaps, ecfg.page_size, cfg.n_kv_heads,
+                (L, ecfg.max_instep_swaps, cfg.n_kv_heads, ecfg.page_size,
                  cfg.head_dim), pdt)
         self._zero_scale = (jnp.zeros(
             (L, ecfg.max_instep_swaps, cfg.n_kv_heads), jnp.float32)
@@ -1185,7 +1199,7 @@ class Engine:
                 out[key_s] = self._zero_scale
             return out
         cfg = self.cfg
-        buf = np.zeros((cfg.n_layers, S, e.page_size, cfg.n_kv_heads,
+        buf = np.zeros((cfg.n_layers, S, cfg.n_kv_heads, e.page_size,
                         cfg.head_dim), self._payload_npdt)
         scale = (np.zeros((cfg.n_layers, S, cfg.n_kv_heads), np.float32)
                  if self._payload_fmt == "q8" else None)
@@ -1267,15 +1281,15 @@ class Engine:
             if not any(per):
                 out[f"swap_{name}"] = self._zero_swap
                 continue
-            buf = np.zeros((ns, self.cfg.n_layers, S, e.page_size,
-                            self.cfg.n_kv_heads, self.cfg.head_dim),
+            buf = np.zeros((ns, self.cfg.n_layers, S, self.cfg.n_kv_heads,
+                            e.page_size, self.cfg.head_dim),
                            self._payload_npdt)
             for i in range(ns):
                 for j, (ls, half) in enumerate(per[i]):
                     dst[i, j] = ls
                     buf[i, :, j] = half.data
             self.swap_bytes_shipped += buf.nbytes
-            out[f"swap_{name}"] = buf
+            out[f"swap_{name}"] = jax.device_put(buf, self._swap_sh)
         return out
 
     # -- copy-on-write page forks (cross-request prefix sharing) --------
@@ -1433,6 +1447,27 @@ class Engine:
         self.multi_token_rollbacks = 0
         self.k_counts = {}
 
+    def compiled_step(self, t_bucket: int, np_bucket: int,
+                      w_bucket: int = 0):
+        """Compile one step variant against the engine's live params and
+        pools, outside the jit cache and the ``jit_traces`` count — for
+        inspecting the program (HLO text, shardings, memory analysis)."""
+        _, size = self.pack_layout(t_bucket, np_bucket, w_bucket)
+        inp = {"pack": jnp.zeros((size,), jnp.int32),
+               "swap_k": self._zero_swap, "swap_v": self._zero_swap}
+        if self._payload_fmt == "q8":
+            inp["swap_k_scale"] = self._zero_scale
+            inp["swap_v_scale"] = self._zero_scale
+        traces = self.jit_traces
+        try:
+            # lower() always retraces outside the jit cache; the trace
+            # counter must keep meaning "compiled step variants executed"
+            return self._step.lower(self.params, self.k_pools, self.v_pools,
+                                    inp, t_bucket, np_bucket, w_bucket,
+                                    1).compile()
+        finally:
+            self.jit_traces = traces
+
     def collective_counts(self, t_bucket: Optional[int] = None,
                           np_bucket: Optional[int] = None) -> Dict[str, int]:
         """Collective ops in one compiled step variant, by kind —
@@ -1443,22 +1478,7 @@ class Engine:
         from repro.roofline import parse_collectives
         t_b = t_bucket if t_bucket is not None else self.token_buckets[0]
         np_b = np_bucket if np_bucket is not None else self.np_buckets[0]
-        _, size = self.pack_layout(t_b, np_b, 0)
-        inp = {"pack": jnp.zeros((size,), jnp.int32),
-               "swap_k": self._zero_swap, "swap_v": self._zero_swap}
-        if self._payload_fmt == "q8":
-            inp["swap_k_scale"] = self._zero_scale
-            inp["swap_v_scale"] = self._zero_scale
-        traces = self.jit_traces
-        try:
-            # lower() always retraces outside the jit cache; the trace
-            # counter must keep meaning "compiled step variants executed"
-            compiled = self._step.lower(self.params, self.k_pools,
-                                        self.v_pools, inp, t_b, np_b,
-                                        0, 1).compile()
-        finally:
-            self.jit_traces = traces
-        coll = parse_collectives(compiled.as_text())
+        coll = parse_collectives(self.compiled_step(t_b, np_b).as_text())
         return {kind: int(v["count"]) for kind, v in sorted(coll.items())}
 
     # ------------------------------------------------------------------

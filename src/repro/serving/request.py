@@ -112,6 +112,9 @@ class Request:
     n_prefill_compute: int = 0  # prompt positions actually (re)computed
     # logits at prefill completion (losslessness validation)
     first_logits: Optional[object] = None
+    # (logical position, logits) of the first decode steps, recorded only
+    # when ``ServerConfig.record_decode_logits`` asks for them
+    decode_logits: List = field(default_factory=list)
     # structured terminal-fault result: {"status": "failed"|"rejected",
     # "reason": ..., + site-specific fields such as required_blocks /
     # available_blocks}; None for every other outcome

@@ -22,12 +22,16 @@ so their logits and sampled ids match byte-for-byte.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
 import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
@@ -182,6 +186,16 @@ class ServerConfig:
     # "split" = the original padded prefill + decode two-dispatch layout
     # (the baseline benchmarks/kernel_fusion.py compares against).
     attn_mode: str = "fused"
+    # attention backend of the default-constructed engine: "pallas" is the
+    # compiled TPU kernel (raises on any other backend), "xla" the pure-jnp
+    # oracle (tests, CPU launcher), "pallas_interpret" the kernel under the
+    # Pallas interpreter (CPU validation)
+    attn_impl: str = "xla"
+    # keep the logits of each request's first N decode steps in
+    # ``Request.decode_logits`` (correctness phases: the engine then
+    # returns full (R+B, V) logits every step and decodes one token per
+    # dispatch).  0 = off.
+    record_decode_logits: int = 0
     # sharded multi-device serving: KV page pools sequence-shard over an
     # n-way ("data"=1, "model"=n) mesh, weights shard by the decode
     # sharding rules, and per-shard attention partials merge through the
@@ -225,7 +239,7 @@ class AsymCacheServer:
         policy = make_policy(scfg.policy, self.freq,
                              **({"use_hit_count": scfg.use_hit_count}
                                 if scfg.policy.startswith("asymcache") else {}))
-        # per-half byte sizes: one (L, page, KH, D) half in pool precision
+        # per-half byte sizes: one (L, KH, page, D) half in pool precision
         # (the host-tier BYTE budget unit) and in the configured wire
         # format (what a spill actually moves)
         fp_half = (cfg.n_layers * scfg.block_size
@@ -258,14 +272,16 @@ class AsymCacheServer:
                 max_chunk=scfg.scheduler.max_chunk,
                 max_prefills=scfg.scheduler.max_prefills,
                 max_decodes=scfg.scheduler.max_decodes,
-                attn_mode=scfg.attn_mode)
+                attn_mode=scfg.attn_mode, attn_impl=scfg.attn_impl)
+            if scfg.record_decode_logits:
+                ecfg = dataclasses.replace(ecfg, return_full_logits=True)
+                scfg.scheduler.max_decode_steps = 1
             if scfg.offload.quant != "off":
                 # quantized payload serving: the engine snaps KV writes to
                 # the grid (lossless mode) and dequantizes wire payloads
                 # inside the jitted step
                 assert scfg.n_shards == 1, \
                     "quantized swap payloads require single-device serving"
-                import dataclasses
                 ecfg = dataclasses.replace(
                     ecfg, swap_payload=scfg.offload.wire_format,
                     snap=scfg.offload.snap,
@@ -518,6 +534,11 @@ class AsymCacheServer:
             t1 = time.perf_counter()
             handle = self.engine.dispatch(plan)
             self.control_plane_time += handle.assembly_time
+            if self.scfg.record_decode_logits:
+                # each decode row's input position, before _postprocess
+                # appends the token it emits
+                handle.decode_pos = [r.prompt_len + len(r.generated) - 1
+                                     for r in plan.decodes]
 
             if depth == 0:
                 handle.block()     # synchronous order: wait for the device
@@ -822,6 +843,10 @@ class AsymCacheServer:
         else:
             for i, req in enumerate(plan.decodes):
                 req.sampled_ids.append(int(ids[R + i]))
+                if len(req.decode_logits) < self.scfg.record_decode_logits:
+                    req.decode_logits.append(
+                        (handle.decode_pos[i],
+                         handle.prefill_logits_np()[R + i].copy()))
 
     def _finish(self, req: Request) -> None:
         # §5.1 online lifespan: feed actual per-block reuse intervals
@@ -861,11 +886,43 @@ class AsymCacheServer:
 # Reference-output helper for losslessness checks
 # ---------------------------------------------------------------------------
 
-def reference_logits(cfg: ModelConfig, params, tokens: List[int]) -> np.ndarray:
-    """Logits for the last position of ``tokens`` via the dense (non-paged,
-    non-evicting) model path — the ground truth for lossless serving."""
-    import jax.numpy as jnp
+def reference_logits(cfg: ModelConfig, params, tokens: List[int],
+                     positions: Optional[List[int]] = None,
+                     pad_to: int = 256) -> np.ndarray:
+    """Float32 logits of the dense (non-paged, non-evicting) model path —
+    the ground truth for lossless serving.
+
+    Returns the row of the last position of ``tokens``, or one row per
+    entry of ``positions``.  Outputs are teacher-forced, so the decode
+    step at logical position p sees exactly ``tokens[:p + 1]``: its
+    logits through the paged cache compare with row p here, as the first
+    token's compare with row ``prompt_len - 1``.
+
+    Activations are float32 and every matmul runs at
+    ``default_matmul_precision("highest")`` over the given (possibly
+    bf16) weights, so on a TPU this is a float32 reference and not a
+    bf16 one.  The token stream is zero-padded to a multiple of
+    ``pad_to`` (causal attention: padding never reaches earlier
+    positions) so that one compiled program serves many lengths."""
+    n = len(tokens)
+    s = -(-n // pad_to) * pad_to
+    toks = np.zeros((1, s), np.int32)
+    toks[0, :n] = tokens
+    at = np.asarray([n - 1] if positions is None else positions, np.int32)
+    if not at.size or int(at.min()) < 0 or int(at.max()) >= n:
+        raise ValueError(f"positions {at.tolist()} outside [0, {n})")
+    with jax.default_matmul_precision("highest"):
+        lg = _dense_logits_at(params,
+                              dataclasses.replace(cfg, dtype="float32"),
+                              jnp.asarray(toks), jnp.asarray(at))
+    out = np.asarray(lg)
+    return out[0] if positions is None else out
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _dense_logits_at(params, cfg: ModelConfig, tokens, at):
     from repro.models import forward
-    t = jnp.asarray(tokens, jnp.int32)[None]
-    lg = forward(params, cfg, {"tokens": t})
-    return np.asarray(lg[0, -1])
+    # embeds enter at cfg.dtype (float32 here): float32 activations over
+    # the unchanged weights
+    return forward(params, cfg, {"embeds": params["embed"][tokens]},
+                   at=at)[0]

@@ -29,6 +29,8 @@ def run_devices(code: str, n_devices: int) -> str:
     devices (jax locks the device count at first init, and the main
     pytest process must keep seeing 1 CPU device for the smoke tests)."""
     env = dict(os.environ)
+    # host devices only: never compete with a parent for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

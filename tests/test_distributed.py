@@ -180,10 +180,9 @@ def test_banded_attention_model_equivalence():
 
 def test_sharding_rules_sanity():
     from repro.distributed.sharding import sharding_rules
-    from repro.launch.mesh import abstract_mesh
-    # AbstractMesh carries axis sizes without requiring real devices; the
-    # compat constructor handles the 0.4.x ((name, size), ...) signature
-    mesh = abstract_mesh((2, 2), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    # AbstractMesh carries axis sizes without requiring real devices
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     for arch in ARCH_IDS:
         cfg = effective_config(get_config(arch), tp=2, ep=2)
         for kind in ("train", "prefill", "decode"):

@@ -47,8 +47,8 @@ def _varlen_case(rng, *, n_pre, n_dec, page, NP, P, H, KH, D, max_run=14):
     ks = jax.random.split(jax.random.PRNGKey(rng.randint(1 << 30)), 3)
     return dict(
         q=_rand((T, H, D), ks[0]),
-        k_pages=_rand((P, page, KH, D), ks[1]),
-        v_pages=_rand((P, page, KH, D), ks[2]),
+        k_pages=_rand((P, KH, page, D), ks[1]),
+        v_pages=_rand((P, KH, page, D), ks[2]),
         bt=jnp.asarray(rng.randint(0, P, (n, NP)), jnp.int32),
         ctx=jnp.asarray(ctx, jnp.int32),
         q_pos=jnp.asarray(np.concatenate(q_pos), jnp.int32),
@@ -201,8 +201,8 @@ def test_prefill_kernel_masks_invalid_q_rows(window):
     R, QP, NP, P, page, H, KH, D = 2, 16, 5, 32, 8, 4, 2, 32
     ks = jax.random.split(KEY, 4)
     q = _rand((R, QP, H, D), ks[0])
-    k_pages = _rand((P, page, KH, D), ks[1])
-    v_pages = _rand((P, page, KH, D), ks[2])
+    k_pages = _rand((P, KH, page, D), ks[1])
+    v_pages = _rand((P, KH, page, D), ks[2])
     bt = jax.random.randint(ks[3], (R, NP), 0, P).astype(jnp.int32)
     ctx = jnp.array([NP * page, 2 * page + 3], jnp.int32)
     q_pos = jnp.stack([
@@ -232,8 +232,8 @@ def test_prefill_wrapper_rounds_ragged_qp_up(qp, q_tile):
     R, NP, P, page, H, KH, D = 2, 4, 16, 8, 4, 2, 16
     ks = jax.random.split(KEY, 4)
     q = _rand((R, qp, H, D), ks[0])
-    k_pages = _rand((P, page, KH, D), ks[1])
-    v_pages = _rand((P, page, KH, D), ks[2])
+    k_pages = _rand((P, KH, page, D), ks[1])
+    v_pages = _rand((P, KH, page, D), ks[2])
     bt = jax.random.randint(ks[3], (R, NP), 0, P).astype(jnp.int32)
     ctx = jnp.array([NP * page, 2 * page + 1], jnp.int32)
     q_pos = jnp.stack([jnp.arange(qp), jnp.arange(qp)]).astype(jnp.int32)

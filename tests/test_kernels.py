@@ -38,8 +38,8 @@ def test_msa_prefill_sweep(dtype, h, kh, d, page, q_tile, window, softcap):
     R, QP, NP, P = 2, 16, 5, 32
     ks = jax.random.split(KEY, 4)
     q = _rand((R, QP, h, d), ks[0], dtype)
-    k_pages = _rand((P, page, kh, d), ks[1], dtype)
-    v_pages = _rand((P, page, kh, d), ks[2], dtype)
+    k_pages = _rand((P, kh, page, d), ks[1], dtype)
+    v_pages = _rand((P, kh, page, d), ks[2], dtype)
     bt = jax.random.randint(ks[3], (R, NP), 0, P).astype(jnp.int32)
     ctx = jnp.array([NP * page, 2 * page + 3], jnp.int32)
     q_pos = jnp.stack([
@@ -67,8 +67,8 @@ def test_msa_decode_sweep(dtype, h, kh, d, window):
     B, NP, P, page = 3, 6, 24, 8
     ks = jax.random.split(KEY, 4)
     q = _rand((B, h, d), ks[0], dtype)
-    k_pages = _rand((P, page, kh, d), ks[1], dtype)
-    v_pages = _rand((P, page, kh, d), ks[2], dtype)
+    k_pages = _rand((P, kh, page, d), ks[1], dtype)
+    v_pages = _rand((P, kh, page, d), ks[2], dtype)
     bt = jax.random.randint(ks[3], (B, NP), 0, P).astype(jnp.int32)
     ctx = jnp.array([NP * page, 17, 1], jnp.int32)
     o_ref = msa_decode(q, k_pages, v_pages, bt, ctx, window=window, impl="xla")
@@ -99,11 +99,11 @@ def test_msa_equals_contiguous_attention():
     # paged: scatter KV into shuffled pool pages
     NP = S // page
     perm = np.random.RandomState(0).permutation(16)[:NP]
-    k_pages = jnp.zeros((16, page, KH, D))
-    v_pages = jnp.zeros((16, page, KH, D))
+    k_pages = jnp.zeros((16, KH, page, D))
+    v_pages = jnp.zeros((16, KH, page, D))
     for j in range(NP):
-        k_pages = k_pages.at[perm[j]].set(k_full[0, j * page:(j + 1) * page])
-        v_pages = v_pages.at[perm[j]].set(v_full[0, j * page:(j + 1) * page])
+        k_pages = k_pages.at[perm[j]].set(k_full[0, j * page:(j + 1) * page].transpose(1, 0, 2))
+        v_pages = v_pages.at[perm[j]].set(v_full[0, j * page:(j + 1) * page].transpose(1, 0, 2))
     bt = jnp.asarray(perm)[None, :].astype(jnp.int32)
     o_paged = msa_prefill(q_full, k_pages, v_pages, bt,
                           jnp.array([S], jnp.int32), pos,
@@ -138,11 +138,11 @@ def test_msa_segment_merge_property(n_seg, seed):
 
     NP = S // page
     perm = rng.permutation(NP + 4)[:NP]
-    k_pages = jnp.zeros((NP + 4, page, KH, D))
-    v_pages = jnp.zeros((NP + 4, page, KH, D))
+    k_pages = jnp.zeros((NP + 4, KH, page, D))
+    v_pages = jnp.zeros((NP + 4, KH, page, D))
     for j in range(NP):
-        k_pages = k_pages.at[perm[j]].set(k_full[0, j * page:(j + 1) * page])
-        v_pages = v_pages.at[perm[j]].set(v_full[0, j * page:(j + 1) * page])
+        k_pages = k_pages.at[perm[j]].set(k_full[0, j * page:(j + 1) * page].transpose(1, 0, 2))
+        v_pages = v_pages.at[perm[j]].set(v_full[0, j * page:(j + 1) * page].transpose(1, 0, 2))
     bt = jnp.asarray(perm)[None, :].astype(jnp.int32)
     o_paged = msa_prefill(q, k_pages, v_pages, bt, jnp.array([S], jnp.int32),
                           q_pos, jnp.array([len(idx)], jnp.int32), impl="xla")
@@ -153,19 +153,19 @@ def test_msa_segment_merge_property(n_seg, seed):
 def test_write_kv_pages_roundtrip():
     P, page, KH, D, T = 6, 4, 2, 8, 10
     ks = jax.random.split(KEY, 3)
-    k_pages = jnp.zeros((P, page, KH, D))
-    v_pages = jnp.zeros((P, page, KH, D))
+    k_pages = jnp.zeros((P, KH, page, D))
+    v_pages = jnp.zeros((P, KH, page, D))
     k_new = _rand((T, KH, D), ks[0], jnp.float32)
     v_new = _rand((T, KH, D), ks[1], jnp.float32)
     slot_ids = jnp.array([0, 0, 0, 0, 2, 2, 2, 2, 5, 5], jnp.int32)
     offs = jnp.array([0, 1, 2, 3, 0, 1, 2, 3, 0, 1], jnp.int32)
     valid = jnp.array([True] * 8 + [False, True])
     k2, v2 = write_kv_pages(k_pages, v_pages, k_new, v_new, slot_ids, offs, valid)
-    np.testing.assert_allclose(np.asarray(k2[0, 0]), np.asarray(k_new[0]))
-    np.testing.assert_allclose(np.asarray(k2[2, 3]), np.asarray(k_new[7]))
+    np.testing.assert_allclose(np.asarray(k2[0, :, 0]), np.asarray(k_new[0]))
+    np.testing.assert_allclose(np.asarray(k2[2, :, 3]), np.asarray(k_new[7]))
     # dropped write leaves zeros
-    np.testing.assert_allclose(np.asarray(k2[5, 0]), np.zeros((KH, D)))
-    np.testing.assert_allclose(np.asarray(v2[5, 1]), np.asarray(v_new[9]))
+    np.testing.assert_allclose(np.asarray(k2[5, :, 0]), np.zeros((KH, D)))
+    np.testing.assert_allclose(np.asarray(v2[5, :, 1]), np.asarray(v_new[9]))
 
 
 # ---------------------------------------------------------------------------

@@ -83,7 +83,7 @@ def test_lossless_int8_roundtrip_bitwise():
     dequantizing them reproduces the pool bytes bit-for-bit.  A second
     spill/restore generation must also be a fixed point."""
     rng = np.random.default_rng(0)
-    arr = (rng.standard_normal((4, BS, 2, 8)) * 3).astype(np.float32)
+    arr = (rng.standard_normal((4, 2, BS, 8)) * 3).astype(np.float32)
     snapped = snap_to_grid_np(arr, "int8", GRID)
     hh = quantize_half(snapped, "q8", static_scale=GRID)
     back = dequantize_half(hh, np.float32)
@@ -98,7 +98,7 @@ def test_lossless_int8_roundtrip_bitwise():
 def test_lossless_fp8_roundtrip_bitwise():
     pytest.importorskip("ml_dtypes")
     rng = np.random.default_rng(1)
-    arr = rng.standard_normal((2, BS, 1, 4)).astype(np.float32)
+    arr = rng.standard_normal((2, 1, BS, 4)).astype(np.float32)
     snapped = snap_to_grid_np(arr, "fp8", 0.0)
     hh = quantize_half(snapped, "f8")
     back = dequantize_half(hh, np.float32)
@@ -112,10 +112,10 @@ def test_lossy_error_bounded_and_requant_exact():
     content with the REMEMBERED scale recovers identical codes, so the
     error is incurred exactly once."""
     rng = np.random.default_rng(2)
-    arr = (rng.standard_normal((3, BS, 2, 4)) * 5).astype(np.float32)
+    arr = (rng.standard_normal((3, 2, BS, 4)) * 5).astype(np.float32)
     hh = quantize_half(arr, "q8")                   # dynamic max-abs scale
     back = dequantize_half(hh, np.float32)
-    bound = hh.scale[:, None, :, None] * 0.5 + 1e-6
+    bound = hh.scale[:, :, None, None] * 0.5 + 1e-6
     assert np.all(np.abs(back - arr) <= bound)
     hh2 = quantize_half(back, "q8", scale=hh.scale)
     assert np.array_equal(hh2.data, hh.data)

@@ -31,7 +31,7 @@ def small_model():
 
 def _run(cfg, params, policy="asymcache", n_sessions=3, num_blocks=64,
          clock="wall", continuum=False, agentic=False, attn_impl="xla",
-         seed=0, **wl_kw):
+         seed=0, record_decode_logits=0, **wl_kw):
     if agentic:
         wl = agentic_workload(AgenticConfig(n_jobs=n_sessions, seed=seed))
     else:
@@ -41,7 +41,7 @@ def _run(cfg, params, policy="asymcache", n_sessions=3, num_blocks=64,
             n_sessions=n_sessions, turns_per_session=(2, 3), seed=seed, **kw))
     scfg = ServerConfig(
         policy=policy, num_blocks=num_blocks, block_size=16, clock=clock,
-        continuum_ttl=continuum,
+        continuum_ttl=continuum, record_decode_logits=record_decode_logits,
         scheduler=SchedulerConfig(token_budget=128, max_chunk=64,
                                   max_prefills=2, max_decodes=8))
     ecfg = EngineConfig(num_pages=num_blocks, page_size=16, max_prefills=2,
@@ -85,6 +85,40 @@ def test_multi_segment_hits_occur(small_model):
         1 for r in wl
         if any(not h1 and h2 for h1, h2 in zip(r.hit_mask, r.hit_mask[1:])))
     assert multi_seg > 0, "no gap-then-hit (multi-segment) pattern generated"
+
+
+def test_decode_logits_through_cache_match_reference(small_model):
+    """Decode steps read their context from the paged pool (evicted and
+    recomputed segments included); each recorded decode row must equal
+    the dense reference's row at the same logical position."""
+    cfg, params = small_model
+    wl, res, srv = _run(cfg, params, num_blocks=48, n_sessions=4,
+                        clock="model", record_decode_logits=3)
+    assert res["evictions"] > 0
+    rows = 0
+    for r in wl:
+        assert len(r.decode_logits) == min(3, len(r.output_script) - 1)
+        toks = r.prompt_tokens + r.output_script
+        ref = reference_logits(cfg, params, toks,
+                               [p for p, _ in r.decode_logits])
+        for (p, got), want in zip(r.decode_logits, ref):
+            assert p >= r.prompt_len
+            rel = float(np.max(np.abs(want - got))) / max(
+                1e-9, float(np.max(np.abs(want))))
+            assert rel < 2e-3, (r.rid, p, rel)
+            rows += 1
+    assert rows > 0
+
+
+def test_pallas_impl_refuses_non_tpu_backend(small_model):
+    """The compiled kernel is never replaced in silence by the
+    interpreter or the oracle."""
+    from repro.serving.engine import Engine
+    cfg, params = small_model
+    if jax.default_backend() == "tpu":
+        pytest.skip("a TPU backend compiles the kernel")
+    with pytest.raises(ValueError, match="pallas"):
+        Engine(cfg, EngineConfig(num_pages=8, attn_impl="pallas"), params)
 
 
 def test_engine_with_pallas_interpret(small_model):

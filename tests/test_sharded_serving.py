@@ -176,8 +176,8 @@ def test_sharded_attention_unit_equivalence():
 
         rng = np.random.default_rng(0)
         Pg, page, KH, D, H, T, N, NP = 16, 4, 2, 8, 4, 12, 5, 6
-        kp = jnp.asarray(rng.normal(size=(Pg, page, KH, D)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(Pg, page, KH, D)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(Pg, KH, page, D)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(Pg, KH, page, D)), jnp.float32)
         q = jnp.asarray(rng.normal(size=(T, H, D)), jnp.float32)
         kn = jnp.asarray(rng.normal(size=(T, KH, D)), jnp.float32)
         vn = jnp.asarray(rng.normal(size=(T, KH, D)), jnp.float32)
