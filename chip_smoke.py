@@ -39,13 +39,15 @@ SEED = 0
 
 # Largest accepted max|served - reference| / max|reference| over one
 # logits row.  The served path keeps bf16 activations and a bf16 KV pool
-# (unit roundoff 2^-9) through 2 x depth residual sublayers; the
-# reference is float32 at highest matmul precision over the same bf16
-# weights.  That rounding alone gave 1-2e-2 for a dense bf16 forward of a
-# narrower (d_model 1024) 16-layer model of this recipe on a CPU, while
-# the attention sublayers move these logits by O(1): a wrong page, mask,
-# position or layer cannot hide under 5e-2.
-LOGITS_TOL = 5e-2
+# through 2 x depth residual sublayers; the reference is float32 at
+# highest matmul precision over the same bf16 weights.  On a TPU v5e that
+# rounding alone read at most 1.63e-2 here (16 layers) and 1.98e-2 with
+# --chips 4 (32 layers).  One wrong block-table entry (page 1 of each
+# context reading page 2's K/V) planted in the one-chip served path read
+# 9.1e-2 to 1.5e-1 on the decode rows when only decode reads were wrong,
+# and 6.4e-1 to 9.1e-1 on every row when every read was.  4e-2 sits about
+# a factor of two from each side.
+LOGITS_TOL = 4e-2
 
 
 def one_chip_setup(cfg):
@@ -58,7 +60,7 @@ def one_chip_setup(cfg):
         token_buckets=(16,), np_buckets=(128,))
     scfg = ServerConfig(
         num_blocks=ecfg.num_pages, block_size=ecfg.page_size, clock="model",
-        record_decode_logits=2,
+        record_decode_logits=True,
         scheduler=SchedulerConfig(
             token_budget=2048, max_chunk=ecfg.max_chunk,
             max_prefills=ecfg.max_prefills, max_decodes=ecfg.max_decodes))
@@ -83,7 +85,7 @@ def four_chip_setup(cfg):
         attn_impl="xla", token_buckets=(8,), np_buckets=(64,))
     scfg = ServerConfig(
         num_blocks=ecfg.num_pages, block_size=ecfg.page_size, clock="model",
-        n_shards=4, record_decode_logits=2,
+        n_shards=4, record_decode_logits=True,
         scheduler=SchedulerConfig(
             token_budget=64, max_chunk=ecfg.max_chunk,
             max_prefills=ecfg.max_prefills, max_decodes=ecfg.max_decodes))
@@ -135,7 +137,7 @@ def context_stats(wl, block):
     return recomputed, multi_segment
 
 
-def logits_errors(cfg, params, requests, pad_to):
+def logits_errors(cfg, params, requests):
     """max|served - reference| / max|reference| per compared row, split
     into the prefill's last row and decode rows through the cache."""
     import numpy as np
@@ -145,8 +147,7 @@ def logits_errors(cfg, params, requests, pad_to):
         toks = list(r.prompt_tokens) + list(r.output_script)
         rows = [(r.prompt_len - 1, r.first_logits, pre)]
         rows += [(p, lg, dec) for p, lg in r.decode_logits]
-        ref = reference_logits(cfg, params, toks, [p for p, _, _ in rows],
-                               pad_to=pad_to)
+        ref = reference_logits(cfg, params, toks, [p for p, _, _ in rows])
         for (_, got, out), want in zip(rows, ref):
             got = np.asarray(got, np.float32)
             out.append(float(np.max(np.abs(got - want)))
@@ -208,6 +209,7 @@ def phase_one_chip(clock):
     cfg = dataclasses.replace(get_config("llama31-8b"), n_layers=16)
     ecfg, scfg, wl = one_chip_setup(cfg)
     ecfg = dataclasses.replace(ecfg, attn_impl="pallas")
+    scfg = dataclasses.replace(scfg, attn_impl="pallas")
     print(f"config: {cfg.name} d_model {cfg.d_model} heads {cfg.n_heads}/"
           f"{cfg.n_kv_heads} head_dim {cfg.head_dim} d_ff {cfg.d_ff} vocab "
           f"{cfg.vocab_size} {cfg.dtype}, depth {cfg.n_layers} of 32 layers, "
@@ -228,7 +230,7 @@ def phase_one_chip(clock):
     print(f"served step (t {t_b}, np {np_b}, w {w_b}) HLO contains "
           f"tpu_custom_call: {kernel}")
     ok &= kernel
-    pre, dec = logits_errors(cfg, params, pick_checked(wl, 8), pad_to=2048)
+    pre, dec = logits_errors(cfg, params, pick_checked(wl, 8))
     ok &= check_errors(pre, dec)
     stats = jax.devices()[0].memory_stats() or {}
     print(f"peak_bytes_in_use: {stats.get('peak_bytes_in_use', 'n/a')}")
@@ -279,8 +281,7 @@ def phase_four_chips(clock):
     ok &= per_dev < 0.5 * total
     coll = eng.collective_counts(t_max, ecfg.max_blocks_per_seq)
     print(f"collectives in the compiled step: {coll}")
-    pre, dec = logits_errors(cfg, eng.params, pick_checked(wl, 6),
-                             pad_to=1024)
+    pre, dec = logits_errors(cfg, eng.params, pick_checked(wl, 6))
     ok &= check_errors(pre, dec)
     print(f"backend compiles: {clock.n}, {clock.secs:.1f} s (one run)")
     return ok
@@ -299,10 +300,10 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     from repro.launch.compile_cache import enable_compile_cache
-    enable_compile_cache(ROOT)
-    print(f"chip_smoke: single smoke run on {devs[0].device_kind} x "
-          f"{len(devs)}; every time below is from this one run, not a "
-          f"measurement")
+    enable_compile_cache()
+    print(f"chip_smoke: single smoke run on {args.chips} of {len(devs)} "
+          f"{devs[0].device_kind} chip(s); every time below is from this "
+          f"one run, not a measurement")
     clock = CompileClock()
     ok = phase_four_chips(clock) if args.chips == 4 else \
         phase_one_chip(clock)
@@ -311,7 +312,7 @@ def main(argv=None) -> int:
         return 1
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
-        "count": len(devs)}}))
+        "count": args.chips}}))
     return 0
 
 

@@ -39,12 +39,12 @@ def _msa_prefill_kernel(
     context_lens,     # (R,) int32
     q_lens,           # (R,) int32
     # inputs
-    q_pos_ref,        # (1, TQ) int32 — logical positions of this q tile
-    q_ref,            # (1, TQ, 1, D)
+    q_pos_ref,        # (1, TQ, 1) int32 — logical positions of this q tile
+    q_ref,            # (1, 1, TQ, D)
     k_ref,            # (1, 1, page, D)
     v_ref,            # (1, 1, page, D)
     # outputs
-    o_ref,            # (1, TQ, 1, D)
+    o_ref,            # (1, 1, TQ, D)
     # scratch
     acc_ref,          # (TQ, D) f32
     m_ref,            # (TQ, 1) f32
@@ -67,7 +67,7 @@ def _msa_prefill_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     ctx = context_lens[r]
-    qpos = q_pos_ref[0, :]                       # (TQ,)
+    qpos = q_pos_ref[0]                          # (TQ, 1)
     # q rows at padded indices >= q_lens[r] carry qpos 0; they must not
     # attend (the ref zeroes them) and must not drag the tile's position
     # range — a padding qpos of 0 would pull `lo` to the bottom of the
@@ -80,16 +80,15 @@ def _msa_prefill_kernel(
     # horizon of the tile's VALID rows (and, under a sliding window, not
     # fully below their window band); an all-padding tile skips every
     # page and emits exact zeros
-    qpos_v = jnp.where(qvalid[:, 0], qpos, -1)
-    horizon = jnp.max(qpos_v)
-    lo = (jnp.min(jnp.where(qvalid[:, 0], qpos, jnp.int32(2**30)))
+    horizon = jnp.max(jnp.where(qvalid, qpos, -1))
+    lo = (jnp.min(jnp.where(qvalid, qpos, jnp.int32(2**30)))
           - window + 1) if window > 0 else 0
 
     @pl.when((kv_base < ctx) & (kv_base <= horizon) & (kv_base + page > lo))
     def _compute():
         d = q_ref.shape[-1]
         scale = 1.0 / math.sqrt(d)
-        qt = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # (TQ, D)
+        qt = q_ref[0, 0].astype(jnp.float32) * scale            # (TQ, D)
         kt = k_ref[0, 0].astype(jnp.float32)                    # (page, D)
         vt = v_ref[0, 0].astype(jnp.float32)
 
@@ -99,7 +98,7 @@ def _msa_prefill_kernel(
             s = softcap * jnp.tanh(s / softcap)
 
         kv_pos = kv_base + jax.lax.broadcasted_iota(jnp.int32, (q_tile, page), 1)
-        rel = qpos[:, None] - kv_pos
+        rel = qpos - kv_pos
         mask = qvalid & (rel >= 0) & (kv_pos < ctx)
         if window > 0:
             mask = mask & (rel < window)
@@ -119,7 +118,7 @@ def _msa_prefill_kernel(
 
     @pl.when(j == num_pages - 1)
     def _emit():
-        o_ref[0, :, 0, :] = (
+        o_ref[0, 0] = (
             acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         ).astype(o_ref.dtype)
 
@@ -145,11 +144,17 @@ def msa_prefill_pallas(
     qt_per_req = qp // q_tile
     grp = h // kh
 
+    # q head-major, (R, H, QP, D), and positions as a (R, QP, 1) column:
+    # every block then ends in a (TQ, D) or (TQ, 1) tile.  Only the
+    # activations move; the pools are already head-major.
+    qh = q.transpose(0, 2, 1, 3)
+    qpos = q_pos.astype(jnp.int32).reshape(r, qp, 1)
+
     def q_index(r_, h_, qt_, j_, *refs):
-        return (r_, qt_, h_, 0)
+        return (r_, h_, qt_, 0)
 
     def qpos_index(r_, h_, qt_, j_, *refs):
-        return (r_, qt_)
+        return (r_, qt_, 0)
 
     def kv_index(r_, h_, qt_, j_, block_tables_, context_lens_, q_lens_):
         return (block_tables_[r_, j_], h_ // grp, 0, 0)
@@ -159,12 +164,12 @@ def msa_prefill_pallas(
         num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, q_tile), qpos_index),
-            pl.BlockSpec((1, q_tile, 1, d), q_index),
+            pl.BlockSpec((1, q_tile, 1), qpos_index),
+            pl.BlockSpec((1, 1, q_tile, d), q_index),
             pl.BlockSpec((1, 1, page, d), kv_index),
             pl.BlockSpec((1, 1, page, d), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, q_tile, 1, d), q_index),
+        out_specs=pl.BlockSpec((1, 1, q_tile, d), q_index),
         scratch_shapes=[
             pltpu.VMEM((q_tile, d), jnp.float32),
             pltpu.VMEM((q_tile, 1), jnp.float32),
@@ -177,8 +182,8 @@ def msa_prefill_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      q_lens.astype(jnp.int32), q_pos.astype(jnp.int32), q, k_pages, v_pages)
-    return out
+      q_lens.astype(jnp.int32), qpos, qh, k_pages, v_pages)
+    return out.transpose(0, 2, 1, 3)

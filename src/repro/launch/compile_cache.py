@@ -10,18 +10,16 @@ from __future__ import annotations
 
 import os
 from pathlib import Path
-from typing import Optional
 
 # src/repro/launch/compile_cache.py -> the checkout root
-CHECKOUT_ROOT = Path(__file__).resolve().parents[3]
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def enable_compile_cache(root: Optional[Path] = None) -> str:
+def enable_compile_cache() -> str:
     """Turn on the persistent compile cache; returns its directory."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
     import jax
-    path = Path(root or CHECKOUT_ROOT) / ".jax_cache"
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    return str(path)
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)

@@ -62,6 +62,8 @@ from repro.serving.scheduler import ChunkingScheduler, SchedulerConfig, StepPlan
 STALL_RETRY_LIMIT = 64
 DISPATCH_RETRY_LIMIT = 8
 SOURCE_ERROR_LIMIT = 100
+# decode steps per request whose logits ``record_decode_logits`` keeps
+DECODE_LOGITS_KEPT = 3
 
 
 class _SimEngine:
@@ -191,11 +193,11 @@ class ServerConfig:
     # oracle (tests, CPU launcher), "pallas_interpret" the kernel under the
     # Pallas interpreter (CPU validation)
     attn_impl: str = "xla"
-    # keep the logits of each request's first N decode steps in
-    # ``Request.decode_logits`` (correctness phases: the engine then
-    # returns full (R+B, V) logits every step and decodes one token per
-    # dispatch).  0 = off.
-    record_decode_logits: int = 0
+    # keep the logits of each request's first DECODE_LOGITS_KEPT decode
+    # steps in ``Request.decode_logits`` (correctness phases: the engine
+    # then returns full (R+B, V) logits every step and decodes one token
+    # per dispatch)
+    record_decode_logits: bool = False
     # sharded multi-device serving: KV page pools sequence-shard over an
     # n-way ("data"=1, "model"=n) mesh, weights shard by the decode
     # sharding rules, and per-shard attention partials merge through the
@@ -267,6 +269,10 @@ class AsymCacheServer:
                                store=self.store)
         self.sched = ChunkingScheduler(scfg.scheduler, self.bm)
         if scfg.execute_model:
+            if ecfg is not None and ecfg.attn_impl != scfg.attn_impl:
+                raise ValueError(
+                    f"ServerConfig.attn_impl {scfg.attn_impl!r} != "
+                    f"EngineConfig.attn_impl {ecfg.attn_impl!r}")
             ecfg = ecfg or EngineConfig(
                 num_pages=scfg.num_blocks, page_size=scfg.block_size,
                 max_chunk=scfg.scheduler.max_chunk,
@@ -843,7 +849,8 @@ class AsymCacheServer:
         else:
             for i, req in enumerate(plan.decodes):
                 req.sampled_ids.append(int(ids[R + i]))
-                if len(req.decode_logits) < self.scfg.record_decode_logits:
+                if (self.scfg.record_decode_logits
+                        and len(req.decode_logits) < DECODE_LOGITS_KEPT):
                     req.decode_logits.append(
                         (handle.decode_pos[i],
                          handle.prefill_logits_np()[R + i].copy()))
@@ -887,8 +894,7 @@ class AsymCacheServer:
 # ---------------------------------------------------------------------------
 
 def reference_logits(cfg: ModelConfig, params, tokens: List[int],
-                     positions: Optional[List[int]] = None,
-                     pad_to: int = 256) -> np.ndarray:
+                     positions: Optional[List[int]] = None) -> np.ndarray:
     """Float32 logits of the dense (non-paged, non-evicting) model path —
     the ground truth for lossless serving.
 
@@ -901,11 +907,11 @@ def reference_logits(cfg: ModelConfig, params, tokens: List[int],
     Activations are float32 and every matmul runs at
     ``default_matmul_precision("highest")`` over the given (possibly
     bf16) weights, so on a TPU this is a float32 reference and not a
-    bf16 one.  The token stream is zero-padded to a multiple of
-    ``pad_to`` (causal attention: padding never reaches earlier
-    positions) so that one compiled program serves many lengths."""
+    bf16 one.  The token stream is zero-padded to the next power of two,
+    at least 256 (causal attention: padding never reaches earlier
+    positions), so that one compiled program serves many lengths."""
     n = len(tokens)
-    s = -(-n // pad_to) * pad_to
+    s = max(256, 1 << (n - 1).bit_length())
     toks = np.zeros((1, s), np.int32)
     toks[0, :n] = tokens
     at = np.asarray([n - 1] if positions is None else positions, np.int32)

@@ -358,6 +358,7 @@ def test_fused_engine_through_pallas_worklist(small_model):
         output_len=(8, 12), qps=1.0, seed=0))
     scfg = ServerConfig(
         policy="asymcache", num_blocks=48, block_size=16, clock="model",
+        attn_impl="pallas_interpret",
         scheduler=SchedulerConfig(token_budget=128, max_chunk=64,
                                   max_prefills=2, max_decodes=8))
     ecfg = EngineConfig(num_pages=48, page_size=16, max_prefills=2,

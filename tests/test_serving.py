@@ -18,6 +18,7 @@ from repro.serving import (
     multi_turn_workload,
     reference_logits,
 )
+from repro.serving.server import DECODE_LOGITS_KEPT
 
 KEY = jax.random.PRNGKey(0)
 
@@ -31,7 +32,7 @@ def small_model():
 
 def _run(cfg, params, policy="asymcache", n_sessions=3, num_blocks=64,
          clock="wall", continuum=False, agentic=False, attn_impl="xla",
-         seed=0, record_decode_logits=0, **wl_kw):
+         seed=0, record_decode_logits=False, **wl_kw):
     if agentic:
         wl = agentic_workload(AgenticConfig(n_jobs=n_sessions, seed=seed))
     else:
@@ -42,6 +43,7 @@ def _run(cfg, params, policy="asymcache", n_sessions=3, num_blocks=64,
     scfg = ServerConfig(
         policy=policy, num_blocks=num_blocks, block_size=16, clock=clock,
         continuum_ttl=continuum, record_decode_logits=record_decode_logits,
+        attn_impl=attn_impl,
         scheduler=SchedulerConfig(token_budget=128, max_chunk=64,
                                   max_prefills=2, max_decodes=8))
     ecfg = EngineConfig(num_pages=num_blocks, page_size=16, max_prefills=2,
@@ -93,11 +95,12 @@ def test_decode_logits_through_cache_match_reference(small_model):
     the dense reference's row at the same logical position."""
     cfg, params = small_model
     wl, res, srv = _run(cfg, params, num_blocks=48, n_sessions=4,
-                        clock="model", record_decode_logits=3)
+                        clock="model", record_decode_logits=True)
     assert res["evictions"] > 0
     rows = 0
     for r in wl:
-        assert len(r.decode_logits) == min(3, len(r.output_script) - 1)
+        assert len(r.decode_logits) == min(DECODE_LOGITS_KEPT,
+                                          len(r.output_script) - 1)
         toks = r.prompt_tokens + r.output_script
         ref = reference_logits(cfg, params, toks,
                                [p for p, _ in r.decode_logits])
@@ -108,6 +111,36 @@ def test_decode_logits_through_cache_match_reference(small_model):
             assert rel < 2e-3, (r.rid, p, rel)
             rows += 1
     assert rows > 0
+
+
+def test_planted_wrong_page_fails_the_check(small_model, monkeypatch):
+    """The 2e-3 check sees one wrong block-table entry: when page 1 of
+    every context reads page 2's K/V, each first-token and decode row
+    whose context spans it leaves the tolerance."""
+    from repro.serving.engine import Engine
+    assemble = Engine._assemble_fused
+
+    def one_wrong_page(self, plan, t_bucket, np_bucket):
+        f = assemble(self, plan, t_bucket, np_bucket)
+        spans = f["ctx"] > 2 * self.ecfg.page_size
+        f["bt"][spans, 1] = f["bt"][spans, 2]
+        return f
+
+    monkeypatch.setattr(Engine, "_assemble_fused", one_wrong_page)
+    cfg, params = small_model
+    wl, res, srv = _run(cfg, params, num_blocks=48, n_sessions=4,
+                        clock="model", record_decode_logits=True)
+    errs = []
+    for r in wl:
+        rows = [(r.prompt_len - 1, r.first_logits)] + r.decode_logits
+        toks = r.prompt_tokens + r.output_script
+        ref = reference_logits(cfg, params, toks, [p for p, _ in rows])
+        errs += [float(np.max(np.abs(want - got)))
+                 / max(1e-9, float(np.max(np.abs(want))))
+                 for (_, got), want in zip(rows, ref)]
+    print(f"planted wrong page: rel err min {min(errs):.3e} "
+          f"max {max(errs):.3e} over {len(errs)} rows")
+    assert len(errs) > len(wl) and min(errs) > 2e-3
 
 
 def test_pallas_impl_refuses_non_tpu_backend(small_model):
