@@ -1,6 +1,6 @@
 """granite-3-8b — dense GQA kv=8 (40L d=4096 32H d_ff=12800 vocab=49155).
 
-[hf:ibm-granite/granite-3.0-2b-base; hf] — per the assignment table.
+[hf:ibm-granite/granite-3.0-8b-base; hf] — per the assignment table.
 """
 from repro.configs.base import ModelConfig
 
@@ -15,7 +15,7 @@ CONFIG = ModelConfig(
     vocab_size=49_155,
     rope_theta=10_000.0,
     tie_embeddings=True,
-    source="hf:ibm-granite/granite-3.0-2b-base; hf",
+    source="hf:ibm-granite/granite-3.0-8b-base; hf",
 )
 
 SMOKE_CONFIG = ModelConfig(

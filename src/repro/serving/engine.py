@@ -67,7 +67,7 @@ and are served by the dense decode path in ``repro.models`` instead.
 """
 from __future__ import annotations
 
-import time
+import contextlib
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -91,6 +91,7 @@ from repro.core.offload import HostHalf, dequantize_half
 from repro.models.layers import apply_rope, moe_ffn_local, rms_norm, swiglu_mlp
 from repro.models.model import _layer_windows
 from repro.serving.scheduler import StepPlan
+from repro.serving.tracing import ASSEMBLE, LAUNCH, ServeTracer
 
 # minimum work-list bucket (fused Pallas path only); lengths round up to
 # the next power of two above this, so the per-W jit variants are at
@@ -270,7 +271,6 @@ class StepHandle:
     pre-pipeline loop paid every step."""
     token_ids: jax.Array           # (R+B,) device-side greedy samples
     prefill_logits: jax.Array      # (R, V) rows ((R+B, V) full-logits mode)
-    assembly_time: float = 0.0     # host-side build_inputs seconds
     full_logits: bool = False
     # each decode row's input position (set by the server when it records
     # decode logits)
@@ -382,6 +382,9 @@ class Engine:
             **({"in_shardings": in_shardings}
                if in_shardings is not None else {}))
         self.steps_executed = 0
+        # the serve loop's tracer (set by the server that drives this
+        # engine): dispatch marks its assemble and launch phases
+        self.tracer: Optional[ServeTracer] = None
         # trace counter: must equal len(buckets_used) — the
         # compile-once-per-bucket invariant (== 1 in split mode)
         self.jit_traces = 0
@@ -518,21 +521,22 @@ class Engine:
         # in-step page maintenance: swap-ins land first (they commit pages
         # a COW fork in the same round may use as its donor), then copies;
         # both must precede the KV writes/attention that read those pages
-        if self.n_shards > 1:
-            from repro.distributed.flash_decode import sharded_pool_ops
-            k_pools, v_pools = sharded_pool_ops(
-                k_pools, v_pools, inp["swap_k_dst"], inp["swap_v_dst"],
-                inp["swap_k"], inp["swap_v"], inp["copy_src"],
-                inp["copy_dst"], mesh=self.mesh)
-        else:
-            # quantized payloads dequantize inside apply_swap_ins — the
-            # transfer above carried the compressed wire bytes
-            k_pools, v_pools = apply_swap_ins(
-                k_pools, v_pools, inp["swap_k_dst"], inp["swap_v_dst"],
-                inp["swap_k"], inp["swap_v"],
-                inp.get("swap_k_scale"), inp.get("swap_v_scale"))
-            k_pools, v_pools = apply_page_copies(
-                k_pools, v_pools, inp["copy_src"], inp["copy_dst"])
+        with jax.named_scope("page_ops"):
+            if self.n_shards > 1:
+                from repro.distributed.flash_decode import sharded_pool_ops
+                k_pools, v_pools = sharded_pool_ops(
+                    k_pools, v_pools, inp["swap_k_dst"], inp["swap_v_dst"],
+                    inp["swap_k"], inp["swap_v"], inp["copy_src"],
+                    inp["copy_dst"], mesh=self.mesh)
+            else:
+                # quantized payloads dequantize inside apply_swap_ins —
+                # the transfer above carried the compressed wire bytes
+                k_pools, v_pools = apply_swap_ins(
+                    k_pools, v_pools, inp["swap_k_dst"], inp["swap_v_dst"],
+                    inp["swap_k"], inp["swap_v"],
+                    inp.get("swap_k_scale"), inp.get("swap_v_scale"))
+                k_pools, v_pools = apply_page_copies(
+                    k_pools, v_pools, inp["copy_src"], inp["copy_dst"])
 
         if n_iter > 1:
             # multi-token decode dispatch: k fused decode iterations
@@ -541,7 +545,8 @@ class Engine:
             return self._multi_decode_steps(
                 params, k_pools, v_pools, inp, t_bucket, n_iter)
 
-        x = params["embed"][inp["tokens"]]          # (T, d)
+        with jax.named_scope("embed"):
+            x = params["embed"][inp["tokens"]]      # (T, d)
         pos = inp["positions"]
 
         impl = e.attn_impl
@@ -556,68 +561,81 @@ class Engine:
         for l in range(cfg.n_layers):
             blk = jax.tree_util.tree_map(lambda a: a[l], params["blocks"])
             window = self.windows[l]
-            h = rms_norm(x, blk["attn_norm"], cfg.norm_eps)
-            q = jnp.einsum("td,dhk->thk", h, blk["wq"])
-            k_new = jnp.einsum("td,dhk->thk", h, blk["wk"])
-            v_new = jnp.einsum("td,dhk->thk", h, blk["wv"])
-            if cfg.rope_theta > 0:
-                q = apply_rope(q, pos, cfg.rope_theta)
-                k_new = apply_rope(k_new, pos, cfg.rope_theta)
-            k_new = self._snap(k_new)
-            v_new = self._snap(v_new)
+            with jax.named_scope("qkv"):
+                h = rms_norm(x, blk["attn_norm"], cfg.norm_eps)
+                q = jnp.einsum("td,dhk->thk", h, blk["wq"])
+                k_new = jnp.einsum("td,dhk->thk", h, blk["wk"])
+                v_new = jnp.einsum("td,dhk->thk", h, blk["wv"])
+                if cfg.rope_theta > 0:
+                    q = apply_rope(q, pos, cfg.rope_theta)
+                    k_new = apply_rope(k_new, pos, cfg.rope_theta)
+                k_new = self._snap(k_new)
+                v_new = self._snap(v_new)
             if self.n_shards > 1:
                 # per-shard KV write + attention partial + exact LSE
                 # merge, one shard_map per layer (still ONE logical
                 # attention dispatch — each shard computes its segment
                 # subset of the same fused varlen stream)
                 from repro.distributed.flash_decode import sharded_msa_fused
-                kp, vp, attn = sharded_msa_fused(
-                    q, k_pools[l], v_pools[l], k_new, v_new,
-                    inp["write_slot"], inp["write_off"], inp["valid"],
-                    inp["bt"], inp["ctx"], pos, inp["seq_ids"],
-                    mesh=self.mesh, window=window,
-                    softcap=cfg.attn_logit_softcap)
+                with jax.named_scope("attention"):
+                    kp, vp, attn = sharded_msa_fused(
+                        q, k_pools[l], v_pools[l], k_new, v_new,
+                        inp["write_slot"], inp["write_off"], inp["valid"],
+                        inp["bt"], inp["ctx"], pos, inp["seq_ids"],
+                        mesh=self.mesh, window=window,
+                        softcap=cfg.attn_logit_softcap)
+                    x = x + jnp.einsum("thk,hkd->td", attn, blk["wo"])
+                with jax.named_scope("kv_write"):
+                    k_pools = k_pools.at[l].set(kp)
+                    v_pools = v_pools.at[l].set(vp)
+                with jax.named_scope("mlp"):
+                    x = self._mlp_sublayer(x, blk)
+                continue
+            with jax.named_scope("kv_write"):
+                kp, vp = write_kv_pages(
+                    k_pools[l], v_pools[l], k_new, v_new,
+                    inp["write_slot"], inp["write_off"], inp["valid"])
                 k_pools = k_pools.at[l].set(kp)
                 v_pools = v_pools.at[l].set(vp)
+
+            with jax.named_scope("attention"):
+                if fused:
+                    # ONE varlen dispatch over the whole mixed stream
+                    attn = msa_fused(
+                        q, kp, vp, inp["bt"], inp["ctx"], pos,
+                        inp["seq_ids"], inp["valid"], q_start=inp["qstart"],
+                        q_len=inp["qlen"], worklist=worklist, window=window,
+                        softcap=cfg.attn_logit_softcap, q_tile=tq,
+                        impl=impl)
+                else:
+                    qp_ = q[:RQP].reshape(R, QP, cfg.n_heads, cfg.head_dim)
+                    op = msa_prefill(
+                        qp_, kp, vp, inp["bt_pre"], inp["ctx_pre"], qpos_pre,
+                        inp["qlens"], window=window,
+                        softcap=cfg.attn_logit_softcap,
+                        q_tile=min(e.q_tile, QP), impl=impl)
+                    od = msa_decode(
+                        q[RQP:], kp, vp, inp["bt_dec"], inp["ctx_dec"],
+                        window=window, softcap=cfg.attn_logit_softcap,
+                        impl=impl)
+                    attn = jnp.concatenate(
+                        [op.reshape(RQP, cfg.n_heads, cfg.head_dim), od],
+                        axis=0)
                 x = x + jnp.einsum("thk,hkd->td", attn, blk["wo"])
+            with jax.named_scope("mlp"):
                 x = self._mlp_sublayer(x, blk)
-                continue
-            kp, vp = write_kv_pages(
-                k_pools[l], v_pools[l], k_new, v_new,
-                inp["write_slot"], inp["write_off"], inp["valid"])
-            k_pools = k_pools.at[l].set(kp)
-            v_pools = v_pools.at[l].set(vp)
 
-            if fused:
-                # ONE varlen dispatch over the whole mixed stream
-                attn = msa_fused(
-                    q, kp, vp, inp["bt"], inp["ctx"], pos, inp["seq_ids"],
-                    inp["valid"], q_start=inp["qstart"], q_len=inp["qlen"],
-                    worklist=worklist, window=window,
-                    softcap=cfg.attn_logit_softcap, q_tile=tq, impl=impl)
-            else:
-                qp_ = q[:RQP].reshape(R, QP, cfg.n_heads, cfg.head_dim)
-                op = msa_prefill(
-                    qp_, kp, vp, inp["bt_pre"], inp["ctx_pre"], qpos_pre,
-                    inp["qlens"], window=window,
-                    softcap=cfg.attn_logit_softcap,
-                    q_tile=min(e.q_tile, QP), impl=impl)
-                od = msa_decode(
-                    q[RQP:], kp, vp, inp["bt_dec"], inp["ctx_dec"],
-                    window=window, softcap=cfg.attn_logit_softcap, impl=impl)
-                attn = jnp.concatenate(
-                    [op.reshape(RQP, cfg.n_heads, cfg.head_dim), od], axis=0)
-            x = x + jnp.einsum("thk,hkd->td", attn, blk["wo"])
-            x = self._mlp_sublayer(x, blk)
-
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = x[inp["sel"]] @ head                # (R+B, V)
-        # device-side greedy sampling: only (R+B,) ids and the R prefill
-        # rows (losslessness checks) ever leave the device — unless the
-        # legacy full-logits interface is requested for A/B baselines
-        token_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out_logits = logits if e.return_full_logits else logits[:R]
+        with jax.named_scope("logits"):
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["lm_head"])
+            logits = x[inp["sel"]] @ head            # (R+B, V)
+            # device-side greedy sampling: only (R+B,) ids and the R
+            # prefill rows (losslessness checks) ever leave the device —
+            # unless the legacy full-logits interface is requested for
+            # A/B baselines
+            token_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            out_logits = logits if e.return_full_logits else logits[:R]
         return token_ids, out_logits, k_pools, v_pools
 
     def _snap(self, x):
@@ -660,29 +678,35 @@ class Engine:
         Returns the updated pools and the pre-final-norm residual."""
         cfg, e = self.cfg, self.ecfg
         tq = min(e.q_tile, t_bucket)
-        x = params["embed"][tokens]
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]
         for l in range(cfg.n_layers):
             blk = jax.tree_util.tree_map(lambda a: a[l], params["blocks"])
             window = self.windows[l]
-            h = rms_norm(x, blk["attn_norm"], cfg.norm_eps)
-            q = jnp.einsum("td,dhk->thk", h, blk["wq"])
-            k_new = jnp.einsum("td,dhk->thk", h, blk["wk"])
-            v_new = jnp.einsum("td,dhk->thk", h, blk["wv"])
-            if cfg.rope_theta > 0:
-                q = apply_rope(q, pos, cfg.rope_theta)
-                k_new = apply_rope(k_new, pos, cfg.rope_theta)
-            k_new = self._snap(k_new)
-            v_new = self._snap(v_new)
-            kp, vp = write_kv_pages(k_pools[l], v_pools[l], k_new, v_new,
-                                    write_slot, write_off, valid)
-            k_pools = k_pools.at[l].set(kp)
-            v_pools = v_pools.at[l].set(vp)
-            attn = msa_fused(q, kp, vp, bt, ctx, pos, seq_ids, valid,
-                             q_start=qstart, q_len=qlen, worklist=worklist,
-                             window=window, softcap=cfg.attn_logit_softcap,
-                             q_tile=tq, impl=e.attn_impl)
-            x = x + jnp.einsum("thk,hkd->td", attn, blk["wo"])
-            x = self._mlp_sublayer(x, blk)
+            with jax.named_scope("qkv"):
+                h = rms_norm(x, blk["attn_norm"], cfg.norm_eps)
+                q = jnp.einsum("td,dhk->thk", h, blk["wq"])
+                k_new = jnp.einsum("td,dhk->thk", h, blk["wk"])
+                v_new = jnp.einsum("td,dhk->thk", h, blk["wv"])
+                if cfg.rope_theta > 0:
+                    q = apply_rope(q, pos, cfg.rope_theta)
+                    k_new = apply_rope(k_new, pos, cfg.rope_theta)
+                k_new = self._snap(k_new)
+                v_new = self._snap(v_new)
+            with jax.named_scope("kv_write"):
+                kp, vp = write_kv_pages(k_pools[l], v_pools[l], k_new,
+                                        v_new, write_slot, write_off, valid)
+                k_pools = k_pools.at[l].set(kp)
+                v_pools = v_pools.at[l].set(vp)
+            with jax.named_scope("attention"):
+                attn = msa_fused(q, kp, vp, bt, ctx, pos, seq_ids, valid,
+                                 q_start=qstart, q_len=qlen,
+                                 worklist=worklist, window=window,
+                                 softcap=cfg.attn_logit_softcap, q_tile=tq,
+                                 impl=e.attn_impl)
+                x = x + jnp.einsum("thk,hkd->td", attn, blk["wo"])
+            with jax.named_scope("mlp"):
+                x = self._mlp_sublayer(x, blk)
         return k_pools, v_pools, x
 
     def _multi_decode_steps(self, params, k_pools, v_pools, inp,
@@ -715,9 +739,10 @@ class Engine:
                 None if e.attn_impl == "xla"
                 else tuple(inp[f][i] for f in WL_FIELDS),
                 t_bucket)
-            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-            logits = x[inp["sel"]] @ head            # (R+B, V)
-            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("logits"):
+                x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+                logits = x[inp["sel"]] @ head        # (R+B, V)
+                ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             ids_steps.append(ids)
             prev = ids
         token_ids = jnp.stack(ids_steps)             # (n_iter, R+B)
@@ -765,14 +790,16 @@ class Engine:
                 tq = min(self.ecfg.q_tile, t_b)
                 per_it = (lambda a, i: a[i] if n_it > 1 else a)
                 wls = []
-                for i in range(n_it):
-                    wl, _ = build_worklist(
-                        fields["qstart"], per_it(fields["qlen"], i),
-                        per_it(fields["ctx"], i), fields["bt"],
-                        per_it(fields["positions"], i),
-                        page=self.ecfg.page_size, q_tile=tq,
-                        n_tiles=-(-t_b // tq), window=0)
-                    wls.append(wl)
+                with (self.tracer.worklist() if self.tracer is not None
+                      else contextlib.nullcontext()):
+                    for i in range(n_it):
+                        wl, _ = build_worklist(
+                            fields["qstart"], per_it(fields["qlen"], i),
+                            per_it(fields["ctx"], i), fields["bt"],
+                            per_it(fields["positions"], i),
+                            page=self.ecfg.page_size, q_tile=tq,
+                            n_tiles=-(-t_b // tq), window=0)
+                        wls.append(wl)
                 # power-of-two W buckets keep the per-W jit variants at
                 # most log2(Wmax) many
                 w_b = max(WL_BUCKET, 1 << (max(
@@ -1489,12 +1516,18 @@ class Engine:
         pools advance immediately to the (asynchronous) step outputs, so a
         subsequent ``dispatch`` is ordered after this step by data
         dependency — the basis of the one-step-deep pipeline."""
-        t0 = time.perf_counter()
+        tr = self.tracer
+        if tr is not None:
+            tr.phase(ASSEMBLE)
         k = plan.decode_steps
         inp, (t_b, np_b, w_b) = self.build_inputs(plan)
-        t_asm = time.perf_counter() - t0
+        if tr is not None:
+            tr.phase(LAUNCH)
+        traces = self.jit_traces
         token_ids, pre_logits, self.k_pools, self.v_pools = self._step(
             self.params, self.k_pools, self.v_pools, inp, t_b, np_b, w_b, k)
+        if tr is not None:
+            tr.launched((t_b, np_b, w_b, k), self.jit_traces > traces)
         self.steps_executed += 1
         self.buckets_used.add((t_b, np_b, w_b, k))
         fused = self.ecfg.attn_mode == "fused"
@@ -1514,7 +1547,6 @@ class Engine:
                 k * len(plan.decodes) - sum(plan.decode_iters)
             self.k_counts[k] = self.k_counts.get(k, 0) + 1
         return StepHandle(token_ids=token_ids, prefill_logits=pre_logits,
-                          assembly_time=t_asm,
                           full_logits=self.ecfg.return_full_logits)
 
     def execute(self, plan: StepPlan) -> StepHandle:

@@ -25,7 +25,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Tuple
@@ -54,6 +53,8 @@ from repro.core import (
 from repro.serving.engine import Engine, EngineConfig, StepHandle
 from repro.serving.request import Request, RequestState, SessionStats
 from repro.serving.scheduler import ChunkingScheduler, SchedulerConfig, StepPlan
+from repro.serving.tracing import (ASSEMBLE, POLL, POSTPROCESS, RETIRE,
+                                   SCHEDULE, WAIT, ServeTracer)
 
 # graceful-degradation bounds (docs/SERVING.md "Failure semantics"):
 # all-idle admission retries before the head-of-line request is rejected,
@@ -268,6 +269,8 @@ class AsymCacheServer:
                                faults=scfg.faults,
                                store=self.store)
         self.sched = ChunkingScheduler(scfg.scheduler, self.bm)
+        # host phases of the serve loop: spans, counters, per-step ring
+        self.tracer = ServeTracer()
         if scfg.execute_model:
             if ecfg is not None and ecfg.attn_impl != scfg.attn_impl:
                 raise ValueError(
@@ -297,6 +300,7 @@ class AsymCacheServer:
                 from repro.launch.mesh import make_serving_mesh
                 mesh = make_serving_mesh(scfg.n_shards)
             self.engine = Engine(cfg, ecfg, params, mesh=mesh)
+            self.engine.tracer = self.tracer
             # the scheduler picks each step's occupancy bucket from its
             # §5.1 chunk decision — both sides must share one lattice
             self.sched.cfg.token_buckets = self.engine.token_buckets
@@ -325,7 +329,6 @@ class AsymCacheServer:
         self._block_last_release: Dict[int, float] = {}
         self.stats = SessionStats()
         self.now = 0.0
-        self.control_plane_time = 0.0
         # online session serving hooks: listeners fire at the end of
         # _finish (after stats/release) with (request, now); uses_pins
         # gates the per-step pin-expiry sweep (the frontend's prefetch
@@ -346,6 +349,12 @@ class AsymCacheServer:
         self._stall_retries = 0
         self._dispatch_failures = 0      # consecutive
         self._consec_source_errors = 0
+
+    @property
+    def control_plane_time(self) -> float:
+        """Host seconds spent scheduling and assembling steps."""
+        return (self.tracer.totals[SCHEDULE]
+                + self.tracer.totals[ASSEMBLE])
 
     # ------------------------------------------------------------------
     def _hashes_for(self, req: Request, n_blocks: int):
@@ -437,11 +446,12 @@ class AsymCacheServer:
         inflight: Deque[Tuple[StepPlan, StepHandle]] = deque()
         steps = 0
         faults = self.scfg.faults
-        t_run0 = time.perf_counter()
-        t_last_dispatch = t_run0
+        tr = self.tracer
+        t_run0 = tr.start()
 
         while (not source.done() or self.sched.waiting
                or self.sched.running) and steps < max_steps:
+            tr.phase(POLL)
             # admit arrivals due by now (closed-loop sources also fire
             # their due prefetches inside pop_due).  A throwing source
             # (real or injected) degrades to a skipped poll, retried next
@@ -467,9 +477,8 @@ class AsymCacheServer:
 
             if self.uses_pins:
                 self.bm.unpin_expired(self.now)
-            t0 = time.perf_counter()
+            tr.phase(SCHEDULE)
             plan = self.sched.schedule(self.now)
-            self.control_plane_time += time.perf_counter() - t0
 
             if plan.empty():
                 # idle: jump to the source's next event
@@ -537,9 +546,8 @@ class AsymCacheServer:
                 self.engine.queue_copies(copies)
                 self.bm.release([s for s, _ in copies], self.now)
 
-            t1 = time.perf_counter()
+            # the engine marks the assemble and launch phases
             handle = self.engine.dispatch(plan)
-            self.control_plane_time += handle.assembly_time
             if self.scfg.record_decode_logits:
                 # each decode row's input position, before _postprocess
                 # appends the token it emits
@@ -547,17 +555,19 @@ class AsymCacheServer:
                                      for r in plan.decodes]
 
             if depth == 0:
+                tr.phase(WAIT)
                 handle.block()     # synchronous order: wait for the device
+            t_post = tr.phase(POSTPROCESS)
+            n_dec = len(plan.decodes)
+            tr.dispatched(self.now, plan.n_compute_tokens - n_dec, n_dec)
             if self.scfg.clock == "model":
                 self.now += self._step_latency(plan)
             elif depth == 0:
-                self.now += time.perf_counter() - t1
+                self.now += t_post - tr.opened[ASSEMBLE]
             else:
                 # pipelined wall clock: the step's cost is the dispatch-to-
                 # dispatch interval (host and device work overlap inside it)
-                t_now = time.perf_counter()
-                self.now += t_now - t_last_dispatch
-            t_last_dispatch = time.perf_counter()
+                self.now += tr.interval
             steps += 1
             if self.scfg.audit_every \
                     and steps % self.scfg.audit_every == 0:
@@ -566,11 +576,11 @@ class AsymCacheServer:
             self._postprocess(plan)
             inflight.append((plan, handle))
             while len(inflight) > depth:
-                self._retire(*inflight.popleft())
+                self._retire_oldest(inflight)
 
         while inflight:                # drain the pipeline
-            self._retire(*inflight.popleft())
-        wall = time.perf_counter() - t_run0
+            self._retire_oldest(inflight)
+        wall = tr.stop() - t_run0
 
         # serve-drain audit: after a natural drain (every request reached
         # a terminal state) nothing may still hold a block reference or a
@@ -820,6 +830,16 @@ class AsymCacheServer:
                 if req.state is RequestState.DECODE and req.decode_done:
                     self._finish(req)
                     break
+
+    def _retire_oldest(self, inflight: Deque[Tuple[StepPlan, StepHandle]]
+                       ) -> None:
+        """Wait for the oldest in-flight step, then retire it: the wait
+        and the fetch are timed apart."""
+        plan, handle = inflight.popleft()
+        self.tracer.phase(WAIT)
+        jax.block_until_ready((handle.token_ids, handle.prefill_logits))
+        self.tracer.phase(RETIRE)
+        self._retire(plan, handle)
 
     def _retire(self, plan: StepPlan, handle: StepHandle) -> None:
         """Fetch a completed step's device results: greedy sample ids for
