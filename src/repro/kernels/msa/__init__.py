@@ -1,4 +1,5 @@
-from repro.kernels.msa.msa_fused import WL_FIELDS, build_worklist, pad_worklist
+from repro.kernels.msa.msa_fused import (WL_FIELDS, block_pages,
+                                        build_worklist, pad_worklist)
 from repro.kernels.msa.ops import (
     apply_page_copies,
     apply_swap_ins,
@@ -9,6 +10,7 @@ from repro.kernels.msa.ops import (
     write_kv_pages,
 )
 
-__all__ = ["apply_page_copies", "apply_swap_ins", "build_worklist",
+__all__ = ["apply_page_copies", "apply_swap_ins", "block_pages",
+           "build_worklist",
            "msa_decode", "msa_fused", "msa_fused_partial", "msa_prefill",
            "pad_worklist", "write_kv_pages", "WL_FIELDS"]

@@ -10,36 +10,49 @@ rows and the decode half stops being a second kernel launch.
 
 Instead of a dense ``(R, H, QT, NP)`` grid that streams all NP pages for
 every request, the grid iterates a **compacted (sequence, q-tile,
-kv-page) work-list** built on the host at step-assembly time
+page-block) work-list** built on the host at step-assembly time
 (:func:`build_worklist`): only pages that intersect a sequence's context,
-its causal horizon, and (under a sliding window) its window band ever
-become grid steps, so short contexts stop streaming the full page table.
-All work-list metadata is scalar-prefetched; the kv-page BlockSpec
-index_map streams the *pool slot* recorded in the work-list straight out
-of paged HBM.
+its causal horizon, and (under a sliding window) its window band are
+fetched, so short contexts stop streaming the full page table.  An item
+covers up to ``NB = block_pages(page)`` logically consecutive pages of
+one sequence (128 keys: 8 pages of 16 tokens), whose pool slots may lie
+anywhere in the pool — gathering non-contiguous segments is the point of
+MSA.  All work-list metadata is scalar-prefetched.
 
-Layout: the pools are head-major ``(P, KH, page, D)`` and the wrapper
-feeds q grouped by KV head, ``(KH, G, T, D)`` with ``G = H / KH``, so
-every block ends in a ``(rows, D)`` tile — ``(page, D)`` for K/V,
-``(TQ, D)`` per query head for q/out, ``(TQ, 1)`` for the q positions —
-which is what Mosaic's (8, 128) tiling accepts.  A size-1 head axis
-second to last (the token-major ``(P, page, KH, D)`` layout) is refused
-by the TPU compiler.
+Layout: the pools are head-major ``(P, KH, page, D)`` and stay in HBM;
+the wrapper feeds q grouped by KV head, ``(KH, G, T, D)`` with
+``G = H / KH``, so every block ends in a ``(rows, D)`` tile — ``(page,
+D)`` for a K/V page, ``(TQ, D)`` per query head for q/out, ``(TQ, 1)``
+for the q positions — which is what Mosaic's (8, 128) tiling accepts.  A
+size-1 head axis second to last (the token-major ``(P, page, KH, D)``
+layout) is refused by the TPU compiler.
 
-Grid: ``(KH, W)`` — W iterates sequentially on a TPU core.  One grid
-step serves all G query heads of a KV head: the ``(G, TQ, D)`` q block is
-one ``(G·TQ, D)`` matmul operand, so each K/V page is fetched once per
-KV head (not once per query head) and the grid has KH·W steps, not H·W.
-Items of one q tile are consecutive, carrying the flash running max/sum
-in VMEM scratch across pages (and across the several sequences that may
-share a tile: each item contributes only rows inside its own sequence's
-run; the row-wise accumulator merges them exactly).  Work-list padding
-items point at the sentinel sequence row N (``q_len == 0``), mask every
-row, and are exact no-ops.
+Grid: ``(KH, W)``, both axes sequential on a TPU core.  One grid step
+serves all G query heads of a KV head against one item: the ``(G, TQ,
+D)`` q block is one ``(G·TQ, D)`` matmul operand, the item's NB K pages
+and NB V pages are DMA'd from the pool slots in ``wl_slot`` into a
+double-buffered ``(2, NB, page, D)`` VMEM block (the next item's copies
+are in flight while this one computes) and read as one ``(NB·page, D)``
+key block, so the step computes one ``(G·TQ, NB·page)`` score tile and
+one online-softmax update.  Each K/V page is fetched once per KV head
+(not once per query head).  Items of one q tile are consecutive,
+carrying the flash running max/sum in VMEM scratch across blocks (and
+across the several sequences that may share a tile: each item
+contributes only rows inside its own sequence's run; the row-wise
+accumulator merges them exactly).  Keys past the needed range (a block's
+tail) are masked by logical position.  Work-list padding items point at
+the sentinel sequence row N (``q_len == 0``), mask every row, and are
+exact no-ops.
 
-VMEM working set at TQ=128, G=4, page=16, D=128: q block 128 KB (bf16),
-f32 accumulator 256 KB, two 4 KB K/V pages, double-buffered — well under
-the scoped VMEM limit.
+VMEM working set at TQ=128, page=16, NB=8, D=128, bf16 q and pools, for
+G=16 (2,048 rows, ChatGLM3's 2 KV groups): q and out blocks 512 KiB each,
+double-buffered (2 MiB); q positions ``(G, TQ, 1)`` int32, lane-padded,
+1 MiB double-buffered (2 MiB); K/V page buffers 2 x 2 x 32 KiB; f32
+accumulator 1 MiB and running max/sum 1 MiB each (lane-padded); f32
+score, probability and upcast-q temporaries of ``(2048, 128)`` 1 MiB
+each: about 10 MiB by this count, and it compiles for v5e inside the
+default scoped VMEM limit (``tests/test_tpu_compile.py``); at G=4 a
+quarter of it.
 """
 from __future__ import annotations
 
@@ -54,10 +67,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+# lanes of a vreg: a work-list item covers enough pages to fill a score
+# tile's 128 lanes with keys
+LANES = 128
 
-# work-list field order — assembly, packing, and the kernel agree on it
+# work-list field order — assembly, packing, and the kernel agree on it.
+# Every field has one entry per item except ``wl_slot``, which has
+# block_pages(page) entries per item (flat, item-major).
 WL_FIELDS = ("wl_seq", "wl_qtile", "wl_slot", "wl_kvbase", "wl_init",
              "wl_last")
+
+
+def block_pages(page: int) -> int:
+    """Pages one work-list item covers: ``128 // page`` (at least 1), so
+    an item attends its q tile over a full 128-lane tile of keys."""
+    return max(1, LANES // page)
 
 
 def build_worklist(
@@ -72,19 +96,30 @@ def build_worklist(
     n_tiles: int,
     window: int = 0,
     pad_to: Optional[int] = None,
-) -> Tuple[Dict[str, np.ndarray], int]:
+) -> Tuple[Dict[str, np.ndarray], int, int]:
     """Host-side (numpy) construction of the compacted work-list.
 
-    Emits one item per (sequence, q-tile, needed kv page), grouped by q
-    tile in ascending order (the kernel's accumulator residency depends
-    on items of a tile being consecutive).  A page is *needed* iff it
-    starts inside the sequence's context, at or below the tile's causal
-    horizon (max valid q_pos), and — under a sliding window — not
-    entirely below the window's lower edge.  Returns the field dict and
-    the real (pre-padding) item count."""
+    A page is *needed* by a (sequence, q tile) pair iff it starts inside
+    the sequence's context, at or below the tile's causal horizon (max
+    valid q_pos), and — under a sliding window — not entirely below the
+    window's lower edge; the needed pages form one contiguous range of
+    logical pages.  That range is cut into blocks of
+    ``NB = block_pages(page)`` logically consecutive pages, and each
+    block is one item ``(seq, qtile, first page j0)`` whose NB pool
+    slots (``wl_slot[item*NB : (item+1)*NB]``, logical order) may sit
+    anywhere in the pool.  Slots past the range's end repeat its last
+    slot; the kernel masks them by logical position.  Items are grouped
+    by q tile in ascending order (the kernel's accumulator residency
+    depends on items of a tile being consecutive).
+
+    Returns the field dict, the count of page-block items and the count
+    of pages they cover (both before padding, sentinel items not
+    counted)."""
     n = q_start.shape[0]
     np_width = block_tables.shape[1]
+    nb = block_pages(page)
     seqs, qtiles, slots, kvbases, inits, lasts = [], [], [], [], [], []
+    n_pages = 0
     for t in range(n_tiles):
         t_lo, t_hi = t * q_tile, (t + 1) * q_tile
         first_of_tile = len(seqs)
@@ -99,17 +134,21 @@ def build_worklist(
             ctx = int(context_lens[s])
             horizon = int(q_pos[lo:hi].max())
             wlo = int(q_pos[lo:hi].min()) - window + 1 if window > 0 else 0
-            n_pages = min(-(-ctx // page), np_width)
-            for j in range(n_pages):
-                base = j * page
-                if base >= ctx or base > horizon or base + page <= wlo:
-                    continue
+            # needed pages [j_lo, j_hi): end above wlo, start below ctx
+            # and at or below the horizon
+            j_lo = max(0, wlo // page)
+            j_hi = min(-(-ctx // page), np_width, horizon // page + 1)
+            row = block_tables[s]
+            for j0 in range(j_lo, j_hi, nb):
+                j1 = min(j0 + nb, j_hi)
+                blk = [int(x) for x in row[j0:j1]]
                 seqs.append(s)
                 qtiles.append(t)
-                slots.append(int(block_tables[s, j]))
-                kvbases.append(base)
+                slots.extend(blk + blk[-1:] * (nb - len(blk)))
+                kvbases.append(j0 * page)
                 inits.append(0)
                 lasts.append(0)
+                n_pages += j1 - j0
         if len(seqs) > first_of_tile:
             inits[first_of_tile] = 1
             lasts[-1] = 1
@@ -120,11 +159,11 @@ def build_worklist(
             # from an uninitialized buffer)
             seqs.append(n)
             qtiles.append(t)
-            slots.append(0)
+            slots.extend([0] * nb)
             kvbases.append(0)
             inits.append(1)
             lasts.append(1)
-    count = len(seqs)
+    n_items = sum(1 for x in seqs if x != n)
     out = {"wl_seq": np.asarray(seqs, np.int32),
            "wl_qtile": np.asarray(qtiles, np.int32),
            "wl_slot": np.asarray(slots, np.int32),
@@ -133,13 +172,14 @@ def build_worklist(
            "wl_last": np.asarray(lasts, np.int32)}
     if pad_to is not None:
         out = pad_worklist(out, pad_to, sentinel_seq=n)
-    return out, count
+    return out, n_items, n_pages
 
 
 def pad_worklist(wl: Dict[str, np.ndarray], w: int,
                  sentinel_seq: int) -> Dict[str, np.ndarray]:
-    """Pad every work-list field to length ``w`` with exact no-op items:
-    the sentinel sequence row (``q_len == 0``) masks every q row, and
+    """Pad the work-list to ``w`` items (``wl_slot`` to ``w`` blocks of
+    slots) with exact no-op items: the sentinel sequence row
+    (``q_len == 0``) masks every q row, their slots are pool slot 0, and
     ``wl_qtile`` repeats the last real tile so the output block index
     stays monotone.  THE single source of the padding rules — the engine
     and the kernel's no-op-item invariant both rely on it."""
@@ -148,10 +188,12 @@ def pad_worklist(wl: Dict[str, np.ndarray], w: int,
         raise ValueError(f"work-list {count} items > pad_to={w}")
     if count == w:
         return wl
+    nb = wl["wl_slot"].shape[0] // count
     fills = {"wl_seq": sentinel_seq, "wl_qtile": int(wl["wl_qtile"][-1]),
              "wl_slot": 0, "wl_kvbase": 0, "wl_init": 0, "wl_last": 0}
     return {f: np.concatenate(
-        [a, np.full((w - count,), fills[f], np.int32)])
+        [a, np.full(((w - count) * (nb if f == "wl_slot" else 1),),
+                    fills[f], np.int32)])
         for f, a in wl.items()}
 
 
@@ -159,8 +201,8 @@ def _msa_fused_kernel(
     # scalar prefetch (work-list + per-sequence metadata, sentinel row N)
     wl_seq,           # (W,)  sequence row per item
     wl_qtile,         # (W,)  q tile per item
-    wl_slot,          # (W,)  pool page slot per item
-    wl_kvbase,        # (W,)  logical position of the page start
+    wl_slot,          # (W*NB,) pool page slots per item, logical order
+    wl_kvbase,        # (W,)  logical position of the block's first page
     wl_init,          # (W,)  1 = first item of its q tile
     wl_last,          # (W,)  1 = last item of its q tile
     q_start,          # (N+1,) stream row where each sequence's run begins
@@ -169,24 +211,59 @@ def _msa_fused_kernel(
     # inputs
     q_pos_ref,        # (G, TQ, 1) int32 — logical positions of this q tile
     q_ref,            # (1, G, TQ, D) — the G query heads of one KV head
-    k_ref,            # (1, 1, page, D)
-    v_ref,            # (1, 1, page, D)
+    k_hbm,            # (P, KH, page, D) pool, left in HBM
+    v_hbm,            # (P, KH, page, D)
     # outputs
     o_ref,            # (1, G, TQ, D)
     # scratch
+    k_buf,            # (2, NB, page, D) — this item's pages and the next's
+    v_buf,            # (2, NB, page, D)
+    sem,              # DMA semaphores (2 [K, V], 2 buffers)
     acc_ref,          # (G*TQ, D) f32
     m_ref,            # (G*TQ, 1) f32
     l_ref,            # (G*TQ, 1) f32
     *,
-    page: int,
+    nb: int,
     window: int,
     softcap: float,
     q_tile: int,
+    interpret: bool,
 ):
+    g = pl.program_id(0)
     w = pl.program_id(1)
     s = wl_seq[w]
     _, grp, tq, d = q_ref.shape
+    page = k_buf.shape[2]
     rows_g = grp * tq
+
+    def each_page(item, buf, op):
+        """``op`` on each of the item's NB K and NB V page copies into
+        buffer ``buf``."""
+        def body(i, carry):
+            slot = wl_slot[item * nb + i]
+            op(pltpu.make_async_copy(
+                k_hbm.at[slot, g], k_buf.at[buf, i], sem.at[0, buf]))
+            op(pltpu.make_async_copy(
+                v_hbm.at[slot, g], v_buf.at[buf, i], sem.at[1, buf]))
+            return carry
+        # unrolled on the chip, so the scalar core issues the descriptors
+        # back to back; rolled under the interpreter, where unrolling
+        # only lengthens tracing
+        jax.lax.fori_loop(0, nb, body, 0, unroll=not interpret)
+
+    # double buffering across grid steps: the first item of a KV head
+    # fetches its own pages; every item starts the next item's fetch
+    # before it waits for its own
+    @pl.when(w == 0)
+    def _first():
+        each_page(0, 0, lambda c: c.start())
+
+    @pl.when(w + 1 < pl.num_programs(1))
+    def _prefetch():
+        each_page(w + 1, (w + 1) % 2, lambda c: c.start())
+
+    buf = w % 2
+    each_page(w, buf, lambda c: c.wait())
 
     @pl.when(wl_init[w] == 1)
     def _init():
@@ -195,10 +272,11 @@ def _msa_fused_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     scale = 1.0 / math.sqrt(d)
-    # the G heads' (TQ, D) tiles stacked into one (G*TQ, D) operand
+    # the G heads' (TQ, D) tiles stacked into one (G*TQ, D) operand; the
+    # NB gathered pages stacked into one (NB*page, D) block of keys
     qt = q_ref[0].astype(jnp.float32).reshape(rows_g, d) * scale
-    kt = k_ref[0, 0].astype(jnp.float32)                        # (page, D)
-    vt = v_ref[0, 0].astype(jnp.float32)
+    kt = k_buf[buf].astype(jnp.float32).reshape(nb * page, d)
+    vt = v_buf[buf].astype(jnp.float32).reshape(nb * page, d)
 
     sc = jax.lax.dot_general(qt, kt, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -211,10 +289,12 @@ def _msa_fused_kernel(
         jnp.int32, (grp, tq, 1), 1).reshape(rows_g, 1)          # (G*TQ, 1)
     row_ok = (rows >= q_start[s]) & (rows < q_start[s] + q_len[s])
 
+    # logical key positions of the block: slots past the needed range
+    # land beyond the horizon or the context and are masked here
     ctx = context_lens[s]
     kv_pos = wl_kvbase[w] + jax.lax.broadcasted_iota(
-        jnp.int32, (rows_g, page), 1)
-    rel = q_pos_ref[...].reshape(rows_g, 1) - kv_pos            # (G*TQ, page)
+        jnp.int32, (1, nb * page), 1)                            # (1, K)
+    rel = q_pos_ref[...].reshape(rows_g, 1) - kv_pos            # (G*TQ, K)
     mask = row_ok & (rel >= 0) & (kv_pos < ctx)
     if window > 0:
         mask = mask & (rel < window)
@@ -240,6 +320,10 @@ def _msa_fused_kernel(
         o_ref[0] = out.reshape(grp, tq, d).astype(o_ref.dtype)
 
 
+# jitted so that a step's layers share one trace and one Mosaic lowering
+# of the kernel per shape, instead of lowering it again for every layer
+@functools.partial(jax.jit,
+                   static_argnames=("window", "softcap", "q_tile", "interpret"))
 def msa_fused_pallas(
     q: jax.Array,              # (T, H, D) flattened mixed token stream
     k_pages: jax.Array,        # (P, KH, page, D) head-major pool
@@ -250,7 +334,7 @@ def msa_fused_pallas(
     context_lens: jax.Array,   # (N,) int32
     wl_seq: jax.Array,         # (W,) int32 work-list (see build_worklist)
     wl_qtile: jax.Array,
-    wl_slot: jax.Array,
+    wl_slot: jax.Array,        # (W*NB,) int32
     wl_kvbase: jax.Array,
     wl_init: jax.Array,
     wl_last: jax.Array,
@@ -263,6 +347,11 @@ def msa_fused_pallas(
     t, h, d = q.shape
     _, kh, page, _ = k_pages.shape
     grp = h // kh
+    n_items = wl_seq.shape[0]
+    nb = block_pages(page)
+    if wl_slot.shape[0] != n_items * nb:
+        raise ValueError(f"wl_slot has {wl_slot.shape[0]} slots, want "
+                         f"{n_items} items x {nb} pages")
     q_tile = min(q_tile, t)
     n_tiles = -(-t // q_tile)
     t_pad = n_tiles * q_tile
@@ -288,33 +377,36 @@ def msa_fused_pallas(
     def q_index(g_, w_, wl_seq_, wl_qtile_, *refs):
         return (g_, 0, wl_qtile_[w_], 0)
 
-    def kv_index(g_, w_, wl_seq_, wl_qtile_, wl_slot_, *refs):
-        return (wl_slot_[w_], g_, 0, 0)
-
-    grid = (kh, wl_seq.shape[0])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=9,
-        grid=grid,
+        grid=(kh, n_items),
         in_specs=[
             pl.BlockSpec((grp, q_tile, 1), qpos_index),
             pl.BlockSpec((1, grp, q_tile, d), q_index),
-            pl.BlockSpec((1, 1, page, d), kv_index),
-            pl.BlockSpec((1, 1, page, d), kv_index),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, grp, q_tile, d), q_index),
         scratch_shapes=[
+            pltpu.VMEM((2, nb, page, d), k_pages.dtype),
+            pltpu.VMEM((2, nb, page, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((grp * q_tile, d), jnp.float32),
             pltpu.VMEM((grp * q_tile, 1), jnp.float32),
             pltpu.VMEM((grp * q_tile, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _msa_fused_kernel, page=page, window=window, softcap=softcap,
-        q_tile=q_tile)
+        _msa_fused_kernel, nb=nb, window=window, softcap=softcap,
+        q_tile=q_tile, interpret=interpret)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        # the page prefetch of item w+1 runs during item w, so both axes
+        # run in order on one core
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="msa_fused",
     )(wl_seq.astype(jnp.int32), wl_qtile.astype(jnp.int32),

@@ -80,6 +80,7 @@ from repro.kernels.msa import (
     WL_FIELDS,
     apply_page_copies,
     apply_swap_ins,
+    block_pages,
     build_worklist,
     msa_decode,
     msa_fused,
@@ -101,6 +102,23 @@ WL_BUCKET = 64
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def wl_capacity(ecfg: "EngineConfig", t_bucket: int, np_bucket: int) -> int:
+    """The W bucket that holds the most work-list items a step of
+    ``(t_bucket, np_bucket)`` can build: a prefill chunk spans at most
+    ceil(chunk / q_tile) + 1 q tiles and a decode row one, each
+    (sequence, tile) pair needs at most ceil(np_bucket / NB) page blocks,
+    and each all-padding tile adds one sentinel item.  A larger W bucket
+    is laid out and run at this one: the kernel scalar-prefetches
+    W * (5 + NB) int32 words, and a TPU core has 1 MiB of SMEM."""
+    e = ecfg
+    tq = min(e.q_tile, t_bucket)
+    n_tiles = -(-t_bucket // tq)
+    chunk = min(e.max_chunk, t_bucket)
+    pairs = e.max_prefills * (-(-chunk // tq) + 1) + e.max_decodes
+    items = pairs * -(-np_bucket // block_pages(e.page_size)) + n_tiles
+    return max(WL_BUCKET, 1 << (items - 1).bit_length())
 
 
 def derive_bucket_lattice(ecfg: "EngineConfig"
@@ -167,7 +185,11 @@ def pack_layout_for(ecfg: "EngineConfig", n_shards: int, t_bucket: int,
                   ("write_off", k * t), ("seq_ids", t),
                   ("sel", R + B), ("qstart", n), ("qlen", k * n),
                   ("ctx", k * n), ("bt", n * np_bucket)]
-        fields += [(f, k * w_bucket) for f in WL_FIELDS]
+        # wl_slot carries block_pages(page) pool slots per item
+        nb = block_pages(e.page_size)
+        w = min(w_bucket, wl_capacity(e, t_bucket, np_bucket))
+        fields += [(f, k * w * (nb if f == "wl_slot" else 1))
+                   for f in WL_FIELDS]
         fields += [("copy_src", C), ("copy_dst", C),
                    ("swap_k_dst", S), ("swap_v_dst", S)]
     else:
@@ -790,16 +812,21 @@ class Engine:
                 tq = min(self.ecfg.q_tile, t_b)
                 per_it = (lambda a, i: a[i] if n_it > 1 else a)
                 wls = []
+                items = pages = 0
                 with (self.tracer.worklist() if self.tracer is not None
                       else contextlib.nullcontext()):
                     for i in range(n_it):
-                        wl, _ = build_worklist(
+                        wl, n_items, n_pages = build_worklist(
                             fields["qstart"], per_it(fields["qlen"], i),
                             per_it(fields["ctx"], i), fields["bt"],
                             per_it(fields["positions"], i),
                             page=self.ecfg.page_size, q_tile=tq,
                             n_tiles=-(-t_b // tq), window=0)
                         wls.append(wl)
+                        items += n_items
+                        pages += n_pages
+                if self.tracer is not None:
+                    self.tracer.worklist_built(items, pages)
                 # power-of-two W buckets keep the per-W jit variants at
                 # most log2(Wmax) many
                 w_b = max(WL_BUCKET, 1 << (max(
@@ -814,7 +841,7 @@ class Engine:
                 views[name][:] = arr.reshape(-1)
             if wls is not None:
                 for f in WL_FIELDS:
-                    dst = views[f].reshape(n_it, w_b)
+                    dst = views[f].reshape(n_it, -1)
                     for i, wl in enumerate(wls):
                         dst[i] = wl[f]
         else:
@@ -858,7 +885,7 @@ class Engine:
                 out["ctx"] = out["ctx"].reshape(n_iter, self.n_seqs)
                 if w_bucket:
                     for f in WL_FIELDS:
-                        out[f] = out[f].reshape(n_iter, w_bucket)
+                        out[f] = out[f].reshape(n_iter, -1)
         else:
             R, B, NP = e.max_prefills, e.max_decodes, e.max_blocks_per_seq
             out["bt_pre"] = out["bt_pre"].reshape(R, NP)

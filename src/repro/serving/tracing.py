@@ -54,6 +54,11 @@ class StepRecord(NamedTuple):
     decode_rows: int
     variant: Tuple[int, ...]    # the step's jit variant, token bucket first
     compiled: bool          # the launch traced a new step variant
+    # the step's work-lists (Pallas attention only, else 0): page-block
+    # items and the pages they cover, before padding; the block fill is
+    # wl_pages / (wl_items * block_pages(page))
+    wl_items: int = 0
+    wl_pages: int = 0
 
     @property
     def t_bucket(self) -> int:
@@ -94,6 +99,7 @@ class ServeTracer:
         self._step_span = None
         self._acc = [0.0] * n
         self._wl = 0.0
+        self._wl_work = (0, 0)           # (items, pages) of this step
         self._info: Optional[tuple] = None   # (now, prefill, decode rows)
         self._launch: Tuple[tuple, bool] = ((), False)
 
@@ -108,7 +114,7 @@ class ServeTracer:
         if self._step_span is not None:
             self._step_span.__exit__(None, None, None)
         self._acc = [0.0] * len(PHASES)
-        self._wl, self._info = 0.0, None
+        self._wl, self._wl_work, self._info = 0.0, (0, 0), None
         t = time.perf_counter()
         self.t = self.t_dispatch = t
         self._begin_step()
@@ -152,6 +158,12 @@ class ServeTracer:
         self._launch = (variant, compiled)
         if compiled and self._span is not None:
             self._span.set_metadata(compiled=1)
+
+    def worklist_built(self, items: int, pages: int) -> None:
+        """A work-list of this step holds ``items`` page-block items
+        covering ``pages`` pages (before padding)."""
+        i, p = self._wl_work
+        self._wl_work = (i + items, p + pages)
 
     @contextlib.contextmanager
     def worklist(self):
@@ -214,10 +226,11 @@ class ServeTracer:
         now, pre, dec = self._info
         self.ring.append(StepRecord(
             self.step, now, self.interval, tuple(self._acc), self._wl,
-            pre, dec, *self._launch))
+            pre, dec, *self._launch, *self._wl_work))
         self.n_steps += 1
         self._acc = [0.0] * len(PHASES)
         self._wl = 0.0
+        self._wl_work = (0, 0)
         self._info = None
         self._launch = ((), False)
         self._step_span.__exit__(None, None, None)

@@ -8,8 +8,8 @@ import jax.numpy as jnp
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.kernels.msa import (WL_FIELDS, build_worklist, msa_fused,
-                               msa_prefill)
+from repro.kernels.msa import (WL_FIELDS, block_pages, build_worklist,
+                               msa_fused, msa_prefill)
 from repro.kernels.msa import ref as msa_ref
 
 KEY = jax.random.PRNGKey(0)
@@ -19,9 +19,9 @@ def _rand(shape, k, dtype=jnp.float32):
     return jax.random.normal(k, shape, jnp.float32).astype(dtype)
 
 
-def _varlen_case(rng, *, n_pre, n_dec, page, NP, P, H, KH, D, max_run=14):
-    """Random mixed varlen batch: ragged multi-segment prefill runs plus
-    decode rows, flattened into one (T, H, D) stream."""
+def _varlen_meta(rng, *, n_pre, n_dec, page, NP, P, max_run=14):
+    """Random mixed varlen batch metadata: ragged multi-segment prefill
+    runs plus decode rows, flattened into one stream (numpy only)."""
     n = n_pre + n_dec
     q_lens, q_pos, ctx = [], [], []
     for _ in range(n_pre):
@@ -44,25 +44,36 @@ def _varlen_case(rng, *, n_pre, n_dec, page, NP, P, H, KH, D, max_run=14):
     q_start = np.concatenate([[0], np.cumsum(q_lens)[:-1]]).astype(np.int32)
     seq_ids = np.repeat(np.arange(n, dtype=np.int32),
                         np.asarray(q_lens, np.int64))
-    ks = jax.random.split(jax.random.PRNGKey(rng.randint(1 << 30)), 3)
+    key = rng.randint(1 << 30)
+    return dict(
+        bt=rng.randint(0, P, (n, NP)).astype(np.int32),
+        ctx=np.asarray(ctx, np.int32),
+        q_pos=np.concatenate(q_pos).astype(np.int32),
+        seq_ids=seq_ids, q_start=q_start,
+        q_len=np.asarray(q_lens, np.int32), n=n, T=T, key=key)
+
+
+def _varlen_case(rng, *, n_pre, n_dec, page, NP, P, H, KH, D, max_run=14):
+    """Random mixed varlen batch: ragged multi-segment prefill runs plus
+    decode rows, flattened into one (T, H, D) stream."""
+    m = _varlen_meta(rng, n_pre=n_pre, n_dec=n_dec, page=page, NP=NP, P=P,
+                     max_run=max_run)
+    T = m["T"]
+    ks = jax.random.split(jax.random.PRNGKey(m.pop("key")), 3)
     return dict(
         q=_rand((T, H, D), ks[0]),
         k_pages=_rand((P, KH, page, D), ks[1]),
         v_pages=_rand((P, KH, page, D), ks[2]),
-        bt=jnp.asarray(rng.randint(0, P, (n, NP)), jnp.int32),
-        ctx=jnp.asarray(ctx, jnp.int32),
-        q_pos=jnp.asarray(np.concatenate(q_pos), jnp.int32),
-        seq_ids=jnp.asarray(seq_ids),
-        valid=jnp.ones((T,), bool),
-        q_start=jnp.asarray(q_start),
-        q_len=jnp.asarray(q_lens, jnp.int32),
-        n=n, T=T)
+        bt=jnp.asarray(m["bt"]), ctx=jnp.asarray(m["ctx"]),
+        q_pos=jnp.asarray(m["q_pos"]), seq_ids=jnp.asarray(m["seq_ids"]),
+        valid=jnp.ones((T,), bool), q_start=jnp.asarray(m["q_start"]),
+        q_len=jnp.asarray(m["q_len"]), n=m["n"], T=T)
 
 
 def _worklist_for(case, *, page, q_tile, window):
     TQ = min(q_tile, case["T"])
     n_tiles = -(-case["T"] // TQ)
-    wl, _ = build_worklist(
+    wl, _, _ = build_worklist(
         np.asarray(case["q_start"]), np.asarray(case["q_len"]),
         np.asarray(case["ctx"]), np.asarray(case["bt"]),
         np.asarray(case["q_pos"]), page=page, q_tile=TQ,
@@ -151,7 +162,7 @@ def test_fused_kernel_zeroes_bucket_slack_tiles():
     q = jnp.pad(c["q"], ((0, t_bucket - c["T"]), (0, 0), (0, 0)))
     q_pos = jnp.pad(c["q_pos"], (0, t_bucket - c["T"]))
     n_tiles = -(-t_bucket // TQ)
-    wl, _ = build_worklist(
+    wl, _, _ = build_worklist(
         np.asarray(c["q_start"]), np.asarray(c["q_len"]),
         np.asarray(c["ctx"]), np.asarray(c["bt"]), np.asarray(q_pos),
         page=page, q_tile=TQ, n_tiles=n_tiles, window=0)
@@ -186,6 +197,226 @@ def test_fused_kernel_worklist_shared_across_windows():
                           worklist=wl, window=window, q_tile=8,
                           impl="pallas_interpret")
         assert float(jnp.max(jnp.abs(o_ref - o_pal))) < 1e-5, window
+
+
+# ---------------------------------------------------------------------------
+# page blocks: one work-list item attends its q tile over NB = 128 // page
+# gathered pages (NB 8, 2, 1 at pages 16, 64, 128)
+# ---------------------------------------------------------------------------
+
+# a context of two full 128-key blocks and a ragged third
+LONG_CTX = 2 * 128 + 37
+
+
+def _scattered_row(n_pages, P):
+    """A block-table row whose first half lies descending at the top of
+    the pool and second half ascending at the bottom: blocks gather
+    out-of-order slots and one straddles the two segments."""
+    h = n_pages // 2
+    return np.concatenate([P - 1 - 2 * np.arange(h),
+                           3 + 3 * np.arange(n_pages - h)])
+
+
+def _runs_case(runs, *, page, seed, H=4, KH=2, D=16):
+    """Explicit varlen batch: ``runs`` lists each sequence's (q
+    positions, context length) in stream order."""
+    NP = -(-LONG_CTX // page) + 1
+    P = 5 * NP + 8                  # room for _scattered_row's two halves
+    q_lens = [len(pos) for pos, _ in runs]
+    T = sum(q_lens)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    bt = np.stack([np.roll(_scattered_row(NP, P), i) for i in range(len(runs))])
+    return dict(
+        q=_rand((T, H, D), ks[0]),
+        k_pages=_rand((P, KH, page, D), ks[1]),
+        v_pages=_rand((P, KH, page, D), ks[2]),
+        bt=jnp.asarray(bt, jnp.int32),
+        ctx=jnp.asarray([c for _, c in runs], jnp.int32),
+        q_pos=jnp.asarray(np.concatenate([p for p, _ in runs]), jnp.int32),
+        seq_ids=jnp.asarray(np.repeat(np.arange(len(runs)), q_lens),
+                            jnp.int32),
+        valid=jnp.ones((T,), bool),
+        q_start=jnp.asarray(np.cumsum([0] + q_lens[:-1]), jnp.int32),
+        q_len=jnp.asarray(q_lens, jnp.int32),
+        n=len(runs), T=T)
+
+
+# name -> (runs, window, softcap, rows of bucket slack appended); q tiles
+# of 8 rows
+_SCATTERED = [(np.r_[40:43, LONG_CTX - 3:LONG_CTX], LONG_CTX),   # 2 segments
+              (np.asarray([149]), 150)]
+PAGE_BLOCK_CASES = {
+    "scattered_ragged_ctx": (_SCATTERED, 0, 0.0, 0),
+    "chunk_and_decodes_share_tile": (
+        [(np.arange(200, 205), 205), (np.asarray([76]), 77),
+         (np.asarray([129]), 130), (np.asarray([LONG_CTX - 1]), LONG_CTX)],
+        0, 0.0, 0),
+    # lower edge 292 - 150 + 1 = 143: inside a page and inside a block
+    "window_band_inside_block": (
+        [(np.arange(250, 256), 256), (np.asarray([LONG_CTX - 1]), LONG_CTX),
+         (np.asarray([259]), 260)], 150, 0.0, 0),
+    "softcap": (_SCATTERED, 0, 20.0, 0),
+    "all_padding_tiles": (_SCATTERED, 0, 0.0, 2 * 8 + 3),
+    # one q tile, as in the decode bucket, whose warm-up list emits it
+    "warm_lattice_sentinels": ([], 0, 0.0, 8 - 1),
+}
+
+
+@pytest.mark.parametrize("name,page", [
+    *((name, 16) for name in PAGE_BLOCK_CASES),
+    ("scattered_ragged_ctx", 64), ("scattered_ragged_ctx", 128),
+    ("window_band_inside_block", 64), ("window_band_inside_block", 128)])
+def test_fused_kernel_page_blocks(name, page):
+    """The blocked kernel against the varlen oracle on targeted batches;
+    rows of no sequence (bucket slack) come out as exact zeros."""
+    runs, window, softcap, pad = PAGE_BLOCK_CASES[name]
+    TQ = 8
+    if runs:
+        c = _runs_case(runs, page=page, seed=page)
+    else:
+        # the warm-up list of bench/harness/serve.py warm_lattice: every
+        # item the sentinel row, one init, one emit, all slots 0
+        c = _runs_case([(np.asarray([5]), 6)], page=page, seed=page)
+        c["q_len"] = jnp.zeros_like(c["q_len"])
+        c["valid"] = jnp.zeros_like(c["valid"])
+    t_b = c["T"] + pad
+    q = jnp.pad(c["q"], ((0, pad), (0, 0), (0, 0)))
+    q_pos = jnp.pad(c["q_pos"], (0, pad))
+    seq_ids = jnp.pad(c["seq_ids"], (0, pad))
+    valid = jnp.pad(c["valid"], (0, pad))
+    n_tiles = -(-t_b // TQ)
+    if runs:
+        wl, n_items, n_pages = build_worklist(
+            np.asarray(c["q_start"]), np.asarray(c["q_len"]),
+            np.asarray(c["ctx"]), np.asarray(c["bt"]), np.asarray(q_pos),
+            page=page, q_tile=TQ, n_tiles=n_tiles, window=window)
+        assert set(np.asarray(wl["wl_qtile"])) == set(range(n_tiles))
+        assert n_pages > n_items or block_pages(page) == 1
+    else:
+        w = 64
+        wl = {f: np.zeros((w,), np.int32) for f in WL_FIELDS}
+        wl["wl_slot"] = np.zeros((w * block_pages(page),), np.int32)
+        wl["wl_seq"][:] = c["n"]
+        wl["wl_init"][0] = 1
+        wl["wl_last"][-1] = 1
+    o = msa_fused(q, c["k_pages"], c["v_pages"], c["bt"], c["ctx"], q_pos,
+                  seq_ids, valid, q_start=c["q_start"], q_len=c["q_len"],
+                  worklist=tuple(jnp.asarray(wl[f]) for f in WL_FIELDS),
+                  window=window, softcap=softcap, q_tile=TQ,
+                  impl="pallas_interpret")
+    o_ref = msa_fused(q, c["k_pages"], c["v_pages"], c["bt"], c["ctx"],
+                      q_pos, seq_ids, valid, window=window, softcap=softcap,
+                      impl="xla")
+    assert float(jnp.max(jnp.abs(o - o_ref))) < 1e-5
+    assert np.all(np.asarray(o)[~np.asarray(valid)] == 0.0)
+
+
+def _needed_pages(c, *, page, q_tile, n_tiles, window):
+    """Page-granular rule, one page at a time: (seq, tile) -> needed
+    logical pages."""
+    need = {}
+    np_width = c["bt"].shape[1]
+    for t in range(n_tiles):
+        for s in range(c["n"]):
+            lo = max(c["q_start"][s], t * q_tile)
+            hi = min(c["q_start"][s] + c["q_len"][s], (t + 1) * q_tile)
+            if lo >= hi:
+                continue
+            pos = c["q_pos"][lo:hi]
+            wlo = pos.min() - window + 1 if window > 0 else 0
+            pages = [j for j in range(min(-(-c["ctx"][s] // page), np_width))
+                     if j * page < c["ctx"][s] and j * page <= pos.max()
+                     and (j + 1) * page > wlo]
+            if pages:
+                need[(s, t)] = pages
+    return need
+
+
+@pytest.mark.parametrize("page", [16, 64, 128])
+def test_worklist_blocks_the_needed_pages(page):
+    """Blocked items = sum over (seq, tile) of ceil(pages / NB); each
+    item's NB slots are its sequence's block-table slots in logical
+    order (the tail repeats the last one); the block fill counters match."""
+    nb = block_pages(page)
+    rng = np.random.RandomState(page)
+    for trial in range(12):
+        window = (0, 37, 300)[trial % 3]
+        c = _varlen_meta(rng, n_pre=rng.randint(1, 4),
+                         n_dec=rng.randint(0, 5), page=page,
+                         NP=-(-900 // page), P=64, max_run=40)
+        q_tile = (8, 16, 32)[trial % 3]
+        n_tiles = -(-c["T"] // q_tile)
+        wl, n_items, n_pages = build_worklist(
+            c["q_start"], c["q_len"], c["ctx"], c["bt"], c["q_pos"],
+            page=page, q_tile=q_tile, n_tiles=n_tiles, window=window)
+        need = _needed_pages(c, page=page, q_tile=q_tile, n_tiles=n_tiles,
+                             window=window)
+        assert n_items == sum(-(-len(v) // nb) for v in need.values())
+        assert n_pages == sum(len(v) for v in need.values())
+        assert wl["wl_slot"].shape == (wl["wl_seq"].shape[0] * nb,)
+        seen = {}
+        for i, s in enumerate(wl["wl_seq"]):
+            if s == c["n"]:
+                continue
+            t, j0 = int(wl["wl_qtile"][i]), int(wl["wl_kvbase"][i]) // page
+            pages = need[(int(s), t)]
+            blk = [j for j in pages if j0 <= j < j0 + nb]
+            assert blk == list(range(j0, j0 + len(blk)))
+            slots = wl["wl_slot"][i * nb:(i + 1) * nb]
+            assert list(slots[:len(blk)]) == list(c["bt"][s, blk])
+            assert np.all(slots[len(blk):] == slots[len(blk) - 1])
+            seen.setdefault((int(s), t), []).extend(blk)
+        assert seen == need
+
+
+def test_blocked_worklist_fits_the_warm_lattice():
+    """The largest blocked list a step of the benchmark's cells can build
+    fits the W buckets ``bench/harness/serve.py`` compiles before the
+    window, and the engine's capacity for its bucket: prefill chunks that
+    straddle the most q tiles at the deepest context, and every decode
+    row at the deepest context."""
+    import json
+    import sys
+    from pathlib import Path
+    from types import SimpleNamespace
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from bench.harness.serve import w_buckets
+    from repro.serving.engine import WL_BUCKET, EngineConfig, wl_capacity
+    for cell in sorted((root / "bench" / "configs").glob("*.json")):
+        e = json.loads(cell.read_text())["engine"]
+        ecfg = EngineConfig(
+            num_pages=e["num_pages"], page_size=e["page_size"],
+            max_prefills=e["max_prefills"], max_chunk=e["max_chunk"],
+            max_decodes=e["max_decodes"],
+            max_blocks_per_seq=e["max_blocks_per_seq"])
+        R, B, page = ecfg.max_prefills, ecfg.max_decodes, ecfg.page_size
+        n = R + B
+        eng = SimpleNamespace(ecfg=ecfg, n_seqs=n)
+        for t_b in e["token_buckets"]:
+            for np_b in e["np_buckets"]:
+                tq = min(ecfg.q_tile, t_b)
+                ctx = np_b * page
+                chunk = min(ecfg.max_chunk, max(1, (t_b - B) // R))
+                q_len = [chunk] * R + [1] * B
+                if sum(q_len) > t_b:
+                    q_len = [0] * R + [1] * min(B, t_b)
+                    q_len += [0] * (n - len(q_len))
+                # with room, the first chunk starts one row into a tile
+                q_start = np.cumsum([int(sum(q_len) < t_b)] + q_len[:-1])
+                q_pos = np.zeros((t_b,), np.int32)
+                for s in range(n):
+                    lo = q_start[s]
+                    q_pos[lo:lo + q_len[s]] = np.arange(ctx - q_len[s], ctx)
+                bt = np.arange(n * np_b).reshape(n, np_b) % ecfg.num_pages
+                wl, _, _ = build_worklist(
+                    q_start, np.asarray(q_len), np.full((n,), ctx), bt,
+                    q_pos, page=page, q_tile=tq, n_tiles=-(-t_b // tq))
+                w = max(WL_BUCKET, 1 << (wl["wl_seq"].shape[0] - 1)
+                        .bit_length())
+                assert w in w_buckets(eng, t_b, np_b), (cell.name, t_b, w)
+                assert w <= wl_capacity(ecfg, t_b, np_b)
 
 
 # ---------------------------------------------------------------------------
@@ -373,3 +604,42 @@ def test_fused_engine_through_pallas_worklist(small_model):
         rel = float(np.max(np.abs(ref - r.first_logits))) / max(
             1e-9, float(np.max(np.abs(ref))))
         assert rel < 2e-3, rel
+
+
+def test_multi_token_decode_through_pallas_worklist(small_model):
+    """The k>1 decode dispatch builds one work-list per fused iteration
+    (each with its own slot blocks) inside one packed buffer; through the
+    Pallas kernel it samples what the xla oracle's engine samples and
+    leaves the same K/V in the pools (every layer past the first writes
+    K/V computed from the attention below it)."""
+    from repro.serving import (AsymCacheServer, EngineConfig,
+                               SchedulerConfig, ServerConfig)
+    from repro.serving.workload import decode_burst_workload
+    cfg, params = small_model
+    runs = {}
+    for impl in ("xla", "pallas_interpret"):
+        scfg = ServerConfig(
+            policy="asymcache", num_blocks=64, block_size=16, clock="model",
+            attn_impl=impl,
+            scheduler=SchedulerConfig(token_budget=128, max_chunk=64,
+                                      max_prefills=2, max_decodes=4,
+                                      max_decode_steps=4))
+        ecfg = EngineConfig(num_pages=64, page_size=16, max_prefills=2,
+                            max_chunk=64, max_decodes=4,
+                            max_blocks_per_seq=8, attn_impl=impl, q_tile=16)
+        srv = AsymCacheServer(cfg, params, scfg, ecfg=ecfg)
+        # decodes cross the 32-token page boundary inside k>1 dispatches
+        wl = decode_burst_workload(n_requests=3, prompt_len=(24, 30),
+                                   output_len=(12, 16), seed=2)
+        srv.run(wl)
+        runs[impl] = (srv, wl)
+    srv, wl = runs["pallas_interpret"]
+    assert srv.engine.multi_token_dispatches > 0
+    for a, b in zip(runs["xla"][1], wl):
+        assert a.generated == b.generated
+        assert a.sampled_ids == b.sampled_ids and b.sampled_ids
+    ref = runs["xla"][0].engine
+    for pools, want in ((srv.engine.k_pools, ref.k_pools),
+                        (srv.engine.v_pools, ref.v_pools)):
+        np.testing.assert_allclose(np.asarray(pools), np.asarray(want),
+                                   rtol=0, atol=1e-5)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.msa.msa_decode import msa_decode_pallas
-from repro.kernels.msa.msa_fused import msa_fused_pallas
+from repro.kernels.msa.msa_fused import block_pages, msa_fused_pallas
 from repro.kernels.msa.msa_prefill import msa_prefill_pallas
 
 # Llama-3.1-8B attention widths; the engine's page size and q tile
@@ -68,15 +68,23 @@ def _compiles(fn, args):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# (token bucket, work-list length): a decode-only bucket and the maximal
-# prefill bucket (2048 tokens = 16 q tiles)
-@pytest.mark.parametrize("t,w", [(16, 1024), (2048, 2048)])
-def test_msa_fused_compiles_for_v5e(spec, no_compile_cache, t, w):
-    pool = spec((POOL_PAGES, KH, PAGE, D), jnp.bfloat16)
+# (token bucket, work-list items, KV heads): a decode-only bucket and the
+# largest prefill bucket of the benchmark's cells (2064 tokens = 17 q
+# tiles), at G=4 (8 KV heads) and at G=16 (2 KV heads, whose
+# (G*TQ, D) = (2048, 128) operands hold the most VMEM); and the largest
+# work-list the engine runs for ChatGLM3's 512-page bucket
+# (``wl_capacity``), whose scalar-prefetched slots hold the most SMEM
+@pytest.mark.parametrize("t,w,kh", [(16, 256, KH), (2064, 512, KH),
+                                    (16, 256, 2), (2064, 512, 2),
+                                    (2064, 4096, 2)])
+def test_msa_fused_compiles_for_v5e(spec, no_compile_cache, t, w, kh):
+    pool = spec((POOL_PAGES, kh, PAGE, D), jnp.bfloat16)
+    slots = w * block_pages(PAGE)
     _compiles(lambda *a: msa_fused_pallas(*a, q_tile=Q_TILE),
               (spec((t, H, D), jnp.bfloat16), pool, pool,
                spec((N_SEQS,)), spec((N_SEQS,)), spec((t,)), spec((N_SEQS,)),
-               *(spec((w,)) for _ in range(6))))
+               spec((w,)), spec((w,)), spec((slots,)),
+               *(spec((w,)) for _ in range(3))))
 
 
 def test_msa_prefill_compiles_for_v5e(spec, no_compile_cache):
