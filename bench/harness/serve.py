@@ -46,12 +46,18 @@ def load_module(path: Path):
     return mod
 
 
-def model_config(cfgd: Dict, dry: bool):
-    from repro.configs.base import ModelConfig
+def arch_module(cfgd: Dict):
+    """The configuration's architecture module, ``bench/models/<arch>.py``:
+    its ``model_config``, ``shapes``, ``logits``, ``layer_work``,
+    ``step_flops`` and ``KERNELS``."""
+    return load_module(BENCH / "models" / f"{cfgd['arch']}.py")
+
+
+def program_block(cfgd: Dict, dry: bool) -> Dict:
     p = dict(cfgd["program"])
     if dry:
         p.update(cfgd["dry"]["program"])
-    return ModelConfig(name=cfgd["name"], **p), p
+    return p
 
 
 def engine_config(cfgd: Dict, dry: bool) -> Dict:
@@ -61,16 +67,18 @@ def engine_config(cfgd: Dict, dry: bool) -> Dict:
     return e
 
 
+# engine-block keys build_server reads itself; every other key goes to
+# EngineConfig unchanged
+SERVER_KEYS = ("token_budget", "max_running", "token_buckets", "np_buckets")
+
+
 def build_server(mcfg, params, e: Dict, impl: str):
     from repro.serving import (AsymCacheServer, EngineConfig,
                                SchedulerConfig, ServerConfig)
     ecfg = EngineConfig(
-        num_pages=e["num_pages"], page_size=e["page_size"],
-        max_prefills=e["max_prefills"], max_chunk=e["max_chunk"],
-        max_decodes=e["max_decodes"],
-        max_blocks_per_seq=e["max_blocks_per_seq"], attn_impl=impl,
-        token_buckets=tuple(e["token_buckets"]),
-        np_buckets=tuple(e["np_buckets"]))
+        attn_impl=impl, token_buckets=tuple(e["token_buckets"]),
+        np_buckets=tuple(e["np_buckets"]),
+        **{k: v for k, v in e.items() if k not in SERVER_KEYS})
     scfg = ServerConfig(
         num_blocks=e["num_pages"], block_size=e["page_size"], clock="wall",
         pipeline_depth=1, attn_impl=impl,
@@ -215,11 +223,12 @@ class Probe:
 
 
 def plan_summary(plan) -> tuple:
-    """(sequences, logit rows) of a step plan for the work counts."""
+    """(sequences, logit rows) of a step plan for the work counts: each
+    sequence's computed positions and context (``work.Seq``)."""
     seqs = [work.seq_of_positions(c.positions) for c in plan.prefills]
     for r in plan.decodes:
         p = r.prompt_len + len(r.generated) - 1
-        seqs.append((1, p + 1, p + 1))
+        seqs.append(work.Seq(np.array([p], np.int64), p + 1))
     rows = sum(1 for c in plan.prefills if c.completes_prefill) \
         + len(plan.decodes)
     return seqs, rows
@@ -278,12 +287,15 @@ def make_frontend(server, scripts, spec: Dict, probe: Probe,
 def trace_hook(now, server, probe: Probe, trace: Dict) -> None:
     """Start the profiler at ``trace["at"]`` into the window and stop it
     ``trace["len"]`` later, with the device drained at both ends so that
-    the traced steps are exactly those dispatched in between."""
+    the traced steps are exactly those dispatched in between.
+    ``trace["steps"]`` keeps the steps whose poll phase started or
+    stopped the profiler (they drain the device and write the trace)."""
     import jax
     if not trace.get("started") and now >= probe.t0 + trace["at"]:
         jax.block_until_ready(server.engine.k_pools)
         jax.profiler.start_trace(trace["dir"])
         trace["started"] = True
+        trace["steps"] = {server.tracer.step}
         trace["span"] = jax.profiler.TraceAnnotation("bench.window")
         trace["span"].__enter__()
         probe.tracing = True
@@ -294,6 +306,7 @@ def trace_hook(now, server, probe: Probe, trace: Dict) -> None:
         trace["span"].__exit__(None, None, None)
         jax.profiler.stop_trace()
         trace["stopped"] = True
+        trace["steps"].add(server.tracer.step)
 
 
 def instrument(server, probe: Probe, traced: bool) -> None:
@@ -333,6 +346,46 @@ def instrument(server, probe: Probe, traced: bool) -> None:
     eng.dispatch = span("engine.dispatch", counted)
     server.sched.schedule = span("sched.schedule", server.sched.schedule)
     server._retire = span("server._retire", server._retire)
+
+
+def program_record(tracer, t0: float, t_end: float, profiler_steps=()
+                   ) -> Optional[Dict]:
+    """The window's steps from the server tracer's ring (dispatched at
+    server clock ``t0 <= now < t_end``), less ``profiler_steps``: the
+    steps whose poll phase started or stopped the profiler.  Steps, step
+    variants compiled, seconds per serve-loop phase, the work-lists'
+    items and pages, and the longest dispatch-to-dispatch interval with
+    the phases that filled it.  None when the window holds no step."""
+    from repro.serving.tracing import PHASES, POSTPROCESS
+    ring = tracer.steps()
+    recs = [r for r in ring if t0 <= r.now < t_end
+            and r.step not in profiler_steps]
+    if not recs:
+        return None
+    phase_s = {p: sum(r.phases[i] for r in recs)
+               for i, p in enumerate(PHASES)}
+    # an interval runs from the previous launch's end to this one's: the
+    # previous step's postprocess, wait and retire, then this step's poll,
+    # schedule, assemble and launch
+    prev = {r.step: r for r in ring}
+    longest = max(recs, key=lambda r: r.interval_s)
+    before = prev.get(longest.step - 1)
+    filled = {p: (before.phases[i] if before is not None else 0.0)
+              if i >= POSTPROCESS else longest.phases[i]
+              for i, p in enumerate(PHASES)}
+    return {
+        "steps": len(recs),
+        "compiles": sum(1 for r in recs if r.compiled),
+        "phase_s": phase_s,
+        "worklist_s": sum(r.worklist_s for r in recs),
+        "host_loop_s": sum(v for p, v in phase_s.items() if p != "wait"),
+        "interval_s": sum(r.interval_s for r in recs),
+        "wl_items": sum(r.wl_items for r in recs),
+        "wl_pages": sum(r.wl_pages for r in recs),
+        "longest_step": dict(longest.as_dict(), interval_phases=filled),
+        "intervals_over_1_5_s": sum(1 for r in recs if r.interval_s > 1.5),
+        "profiler_steps": sorted(profiler_steps),
+    }
 
 
 def window_turns(frontend, probe: Probe) -> List:

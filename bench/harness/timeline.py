@@ -6,18 +6,24 @@ host caused, and device seconds by program scope.
 into plain tuples; ``reduce`` turns them into numbers, so the arithmetic
 is checked on hand-made events and the reading on a recorded trace.
 
-Clock link.  Each ``serve.launch`` span of the serve loop holds exactly
-one host ``DoEnqueueProgram`` event, whose ``run_id`` names the device's
-``XLA Modules`` event that ran the step.  A device's clock shift is the
-smallest value, over the steps, of module start (device clock) minus
-enqueue end (host clock); how much that value spreads across steps says
-how far to trust the link.
+Clock link.  Each ``serve.launch`` span of the serve loop leads to one
+host ``DoEnqueueProgram`` event, whose ``run_id`` names the device's
+``XLA Modules`` event that ran the step.  The enqueue lies inside the
+span when the runtime enqueues at once; when the step's inputs are still
+being computed by the step before it, the runtime may enqueue later, on
+a thread of its own, before the next launch.  So a launch takes the one
+enqueue inside its span, else the one enqueue between its start and the
+next launch's start; any other count leaves it unlinked.  A device's
+clock shift is the smallest value, over the steps, of module start
+(device clock) minus enqueue end (host clock); how much that value
+spreads across steps says how far to trust the link.
 
 Per step.  The module's interval on the host clock, the device idle
 before it (from the end of the previous module of the window), and the
-part of that idle before the step's enqueue ended: idle the host caused,
-split by the program phase (``serve.*`` span) open at the time.  The
-first module of the window has no earlier module and is left out.
+part of that idle before the step's enqueue ended, whether inside its
+launch span or deferred past it: idle the host caused, split by the
+program phase (``serve.*`` span) open at the time.  The first module of
+the window has no earlier module and is left out.
 
 Scopes.  Each ``XLA Ops`` event inside a linked module is named by its
 HLO instruction; ``scope_map`` maps instruction names to the innermost
@@ -30,6 +36,7 @@ as ``unscoped``.  ``own_scope_map`` keeps the metadata's reading alone.
 """
 from __future__ import annotations
 
+import math
 import re
 from collections import Counter
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -183,14 +190,19 @@ def link(ex: Dict) -> Dict:
     each device's clock shift (device minus host) with its spread."""
     steps, unmatched = [], 0
     enq = sorted(ex["enqueues"], key=lambda e: e[1])
-    for i, (_, step, s, e) in enumerate(ex["launches"]):
-        inside = [q for q in enq if s <= q[1] and q[2] <= e]
-        if len(inside) != 1:
+    launches = ex["launches"]
+    for i, (_, step, s, e) in enumerate(launches):
+        nxt = launches[i + 1][2] if i + 1 < len(launches) else math.inf
+        after = [q for q in enq if s <= q[1] < nxt]
+        inside = [q for q in after if q[2] <= e]
+        pick = inside or after
+        if len(pick) != 1:
             unmatched += 1
             continue
-        run_id, _, q_end = inside[0]
+        run_id, _, q_end = pick[0]
         steps.append({"step": step if step is not None else i + 1,
                       "run_id": run_id, "enqueue_end": q_end,
+                      "deferred": not inside,
                       "launch": (s, e)})
     shifts = {}
     for dev, d in ex["devices"].items():
@@ -202,6 +214,7 @@ def link(ex: Dict) -> Dict:
                            "spread_s": max(diffs) - min(diffs),
                            "linked": len(diffs)}
     return {"steps": steps, "unmatched_launches": unmatched,
+            "deferred_enqueues": sum(1 for st in steps if st["deferred"]),
             "shifts": shifts}
 
 
@@ -264,6 +277,7 @@ def reduce(ex: Dict,
     out = {
         "linked_steps": traced,
         "unmatched_launches": ln["unmatched_launches"],
+        "deferred_enqueues": ln["deferred_enqueues"],
         "shifts": ln["shifts"],
         "steps": per_step,
         "host_stall_s": sum(r["host_stall_s"] for r in stalls) / n_dev,
@@ -273,3 +287,47 @@ def reduce(ex: Dict,
     if scope_for is not None:
         out["scope_s"] = dict(sorted(scope_s.items(), key=lambda kv: -kv[1]))
     return out
+
+
+def _hlo_text(engine, t_b: int, np_b: int, w_b: int) -> str:
+    """The compiled step variant's HLO text, compiled afresh when the copy
+    the persistent cache gave back carries no scope metadata."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    text = engine.compiled_step(t_b, np_b, w_b).as_text()
+    if any(f"/{s}/" in text for s in SCOPES):
+        return text
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return engine.compiled_step(t_b, np_b, w_b).as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+def timeline_record(path: str, server) -> Optional[Dict]:
+    """``reduce`` of the trace at ``path``, each linked step's ops mapped
+    through the scope map of the step variant it ran (from the server's
+    tracer), with the share of device seconds no scope claims.  None
+    when the trace links no step."""
+    variants = {r.step: r.variant for r in server.tracer.steps()}
+    maps: Dict = {}
+
+    def scope_for(step):
+        v = variants.get(step)
+        if v is None:
+            return {}
+        if v not in maps:
+            maps[v] = scope_map(_hlo_text(server.engine, *v[:3]))
+        return maps[v]
+
+    red = reduce(extract(path), scope_for)
+    if not red:
+        return None
+    busy = sum(red["scope_s"].values())
+    red["unscoped_share"] = (red["scope_s"].get(UNSCOPED, 0.0) / busy
+                             if busy else None)
+    red["variants_mapped"] = len(maps)
+    return red

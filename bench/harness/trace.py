@@ -1,5 +1,5 @@
-"""Reduction of a profiler trace to device busy time, per-op time, kernel
-time and idle gaps attributed to host spans.
+"""Reduction of a profiler trace to device busy time, per-op time, the
+time of each named kernel and idle gaps attributed to host spans.
 
 ``extract`` reads one ``.xplane.pb`` through ``jax.profiler.ProfileData``
 into plain intervals; ``reduce`` turns intervals into numbers.  The two
@@ -109,16 +109,18 @@ def open_span(spans: Sequence[Tuple[str, float, float]], t: float) -> str:
     return best[0] if best else "host idle"
 
 
-def reduce(ex: Dict, kernel: str, top: int = 10) -> Dict:
-    """Busy and window seconds, per-op seconds, the named kernel's
-    seconds and the longest idle gaps, all inside the traced window."""
+def reduce(ex: Dict, kernels: Sequence[str], top: int = 10) -> Dict:
+    """Busy and window seconds, per-op seconds, the device seconds of each
+    of ``kernels`` (``kernels[name]["device_s"]``) and the longest idle
+    gaps, all inside the traced window."""
     win = window_of(ex["spans"])
     if win is None or not ex["devices"]:
         return {}
     lo, hi = win
     first = [s for name, s, e in ex["spans"]
              if name == ALIGN_SPAN and lo <= s < hi]
-    busy, kern = [], []
+    busy = []
+    kern = {k: 0.0 for k in kernels}
     per_op: Dict[str, float] = {}
     gaps: List[Tuple[str, float]] = []
     for ops in ex["devices"].values():
@@ -129,9 +131,10 @@ def reduce(ex: Dict, kernel: str, top: int = 10) -> Dict:
                   if e > lo and s < hi]
         u = union([(s, e) for _, s, e in inside])
         busy.append(sum(e - s for s, e in u))
-        kern.append(sum(e - s for n, s, e in inside if n == kernel))
         for n, s, e in inside:
             per_op[n] = per_op.get(n, 0.0) + (e - s)
+            if n in kern:
+                kern[n] += e - s
         edges = [lo] + [x for iv in u for x in iv] + [hi]
         for a, b in zip(edges[::2], edges[1::2]):
             if b > a:
@@ -141,7 +144,7 @@ def reduce(ex: Dict, kernel: str, top: int = 10) -> Dict:
     return {
         "window_s": hi - lo,
         "busy_s": sum(busy) / n_dev,
-        "kernel_s": sum(kern) / n_dev,
+        "kernels": {k: {"device_s": v / n_dev} for k, v in kern.items()},
         "device_ops": [[n, s / n_dev] for n, s in ops_sorted[:top]],
         "idle_gaps": [[n, s] for n, s in
                       sorted(gaps, key=lambda g: -g[1])[:top]],

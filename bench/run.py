@@ -3,20 +3,25 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell names a configuration (``bench/configs/<config>.json``) and a
-traffic mix (``bench/traffic/<traffic>.json``, generated by
+The cell names a configuration (``bench/configs/<config>.json``), whose
+``arch`` names its architecture module (``bench/models/<arch>.py``: the
+program's model config, the weight layout, the reference, the work
+counts and the kernels), and a traffic mix
+(``bench/traffic/<traffic>.json``, generated by
 ``bench/traffic/<kind>.py``); per-layer metrics are read by
-``bench/metrics/<metric>.py``.  All of them are found by the names in
-``BENCHMARK.json``, so a new cell needs only new files and a new entry.
+``bench/metrics/<metric>.py`` from the run's record, which carries the
+program's own records (``program``; traced, also ``timeline``).  All of
+them are found by name, so a new cell, architecture, kernel or metric
+needs only new files and new entries.
 
 Without a TPU (or with fewer chips than the cell asks for) it exits 2
 and prints no result.  ``--dry`` runs the same path on the CPU at the
 configuration's smoke size with the Pallas kernel interpreted; it prints
 the record with every device number marked not measured and never
 prints a result line.  ``--control`` puts the lower-precision control in
-the program's place: the tokens the fp8 reference puts first at the same
-positions are judged by the same rule, so ``correct`` comes out false
-(the program's own reading is still printed beside it).
+the program's place: the tokens the module's fp8 reference puts first at
+the same positions are judged by the same rule, so ``correct`` comes out
+false (the program's own reading is still printed beside it).
 
 The last line of standard output is the result: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (``--trace 1``: also
@@ -38,6 +43,7 @@ import random  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import traceback  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -152,22 +158,23 @@ def pick_checked(turns, seed, rc):
     return out
 
 
-def compare(params, p, turns, control: bool):
+def compare(arch, params, p, turns, control: bool):
     """Widest gap below the reference's best of a served token, and with
-    ``control`` the same for the tokens the fp8 control puts first."""
+    ``control`` the same for the tokens the fp8 control puts first; the
+    reference is the architecture module's ``logits``."""
     import numpy as np
-    from bench.harness import reference as ref
+    from bench.harness import check
     widest, ctl, n_tok = 0.0, 0.0, 0
     for r in turns:
         n = len(r.sampled_ids)
         toks = list(r.prompt_tokens) + list(r.output_script[:n - 1])
-        at = ref.served_positions(r.prompt_len, n)
-        lg = ref.logits(params, p, toks, at, "f32")
-        widest = max(widest, float(ref.gaps(lg, r.sampled_ids).max()))
+        at = check.served_positions(r.prompt_len, n)
+        lg = arch.logits(params, p, toks, at, "f32")
+        widest = max(widest, float(check.gaps(lg, r.sampled_ids).max()))
         n_tok += len(at)
         if control:
-            lc = ref.logits(params, p, toks, at, "fp8")
-            ctl = max(ctl, float(ref.gaps(lg, np.argmax(lc, 1)).max()))
+            lc = arch.logits(params, p, toks, at, "fp8")
+            ctl = max(ctl, float(check.gaps(lg, np.argmax(lc, 1)).max()))
     return widest, (ctl if control else None), n_tok
 
 
@@ -205,14 +212,15 @@ def run_cell(args, cell, cfgd, spec, devs, check: bool = True):
     import jax
     from bench.harness import serve as sv
     from bench.harness import traffic as tr
-    from bench.harness import weights, work
-    from bench.harness.peaks import peaks_for
+    from bench.harness import weights
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     clock = CompileClock()
-    mcfg, p = sv.model_config(cfgd, args.dry)
+    arch = sv.arch_module(cfgd)
+    p = sv.program_block(cfgd, args.dry)
+    mcfg = arch.model_config(cfgd["name"], p)
     e = sv.engine_config(cfgd, args.dry)
-    params = weights.make(p, args.seed)
+    params = weights.make(arch.shapes(p), args.seed)
     impl = "pallas_interpret" if args.dry else "pallas"
     server = sv.build_server(mcfg, params, e, impl)
     n_var = sv.warm_lattice(server.engine)
@@ -294,23 +302,28 @@ def run_cell(args, cell, cfgd, spec, devs, check: bool = True):
         "longest_dispatch_gap": probe.longest_gap,
         "gc_s": probe.gc_s, "gc_max_s": probe.gc_max_s,
     }
-    if args.trace and trace.get("stopped"):
-        from bench.harness import trace as trc
-        ex = trc.extract(trc.find_xplane(trace["dir"]), sv.SPANS)
-        red = trc.reduce(ex, "msa_fused")
-        if not args.dry and red:
-            pk = peaks_for(devs[0].device_kind)
-            red["roofline_s"] = sum(work.roofline_seconds(sq, p, pk)
-                                    for sq, _ in probe.traced_steps)
-            red["model_flops"] = sum(work.step_flops(sq, rows, p)
-                                     for sq, rows in probe.traced_steps)
-            red["peak_flops"] = pk["flops"]
-        red["traced_steps"] = len(probe.traced_steps)
-        rec["trace"] = red
-        shutil.rmtree(trace["dir"], ignore_errors=True)
     stats = devs[0].memory_stats() or {}
     rec["memory_peak_bytes"] = stats.get("peak_bytes_in_use")
     rec["memory_limit_bytes"] = stats.get("bytes_limit")
+    rec["program"] = sv.program_record(
+        server.tracer, probe.t0, probe.t_end,
+        trace.get("steps", ()) if trace else ())
+    if args.trace and trace.get("stopped"):
+        t_red = time.perf_counter()
+        from bench.harness import timeline as tl
+        from bench.harness import trace as trc
+        path = trc.find_xplane(trace["dir"])
+        rec["trace"] = reduce_trace(trc.extract(path, sv.SPANS), arch, p,
+                                    probe.traced_steps, devs, args.dry)
+        t_tl = time.perf_counter()
+        try:
+            rec["timeline"] = tl.timeline_record(path, server)
+        except Exception:  # noqa: BLE001 — the run's other numbers stand
+            rec["timeline"] = {"error": traceback.format_exc()}
+            print(rec["timeline"]["error"], file=sys.stderr)
+        rec["trace_reduce_s"] = t_tl - t_red
+        rec["timeline_s"] = time.perf_counter() - t_tl
+        shutil.rmtree(trace["dir"], ignore_errors=True)
 
     if not check:
         sv.release(server)
@@ -327,11 +340,32 @@ def run_cell(args, cell, cfgd, spec, devs, check: bool = True):
     sv.release(server)
     del server
     t_ref = time.perf_counter()
-    gap, ctl, n_tok = compare(params, p, checked, args.control)
+    gap, ctl, n_tok = compare(arch, params, p, checked, args.control)
     rec["reference_s"] = time.perf_counter() - t_ref
     rec["logit_gap"], rec["control_gap"], rec["checked_tokens"] = \
         gap, ctl, n_tok
     return rec
+
+
+def reduce_trace(ex, arch, p, traced_steps, devs, dry: bool):
+    """Busy, idle gaps and each of the module's ``KERNELS``' device
+    seconds, and on the chip their roofline seconds and the model FLOPs
+    of the traced steps, from the module's work counts."""
+    from bench.harness import trace as trc
+    from bench.harness import work
+    from bench.harness.peaks import peaks_for
+    red = trc.reduce(ex, arch.KERNELS)
+    if not dry and red:
+        pk = peaks_for(devs[0].device_kind)
+        layers = [arch.layer_work(sq, p) for sq, _ in traced_steps]
+        for k, kr in red["kernels"].items():
+            kr["roofline_s"] = sum(work.roofline_seconds(lw, k, pk)
+                                   for lw in layers)
+        red["model_flops"] = sum(arch.step_flops(sq, rows, p)
+                                 for sq, rows in traced_steps)
+        red["peak_flops"] = pk["flops"]
+    red["traced_steps"] = len(traced_steps)
+    return red
 
 
 def report(args, bench, cell, cfgd, rec, devs) -> int:
@@ -371,6 +405,9 @@ def report(args, bench, cell, cfgd, rec, devs) -> int:
 
     skip = ("ttft", "gaps", "queue", "model", "engine", "spec")
     info = {k: v for k, v in rec.items() if k not in skip}
+    if isinstance(info.get("timeline"), dict):     # per-step rows stay out
+        info["timeline"] = {k: v for k, v in info["timeline"].items()
+                            if k != "steps"}
     print("record: " + json.dumps(info, default=float))
     print(f"turns in the system: growth {rec['queue_slope_per_s']:.4g} "
           f"turns/s over the window, {rec['backlog_at_close']} turns due in "
@@ -394,8 +431,9 @@ def report(args, bench, cell, cfgd, rec, devs) -> int:
                 metrics[m["name"]] = "not measured"
         print("dry record: " + json.dumps(
             {"correct": correct, "metrics": metrics,
-             "device": dict(device, memory_peak_bytes="not measured")},
-            default=float))
+             "device": dict(device, memory_peak_bytes="not measured"),
+             "program": rec["program"],
+             "trace": rec.get("trace")}, default=float))
         for line in lines:
             print(line, file=sys.stderr)
         print("dry run: no result line")

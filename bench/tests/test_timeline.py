@@ -84,6 +84,26 @@ def test_launch_without_one_enqueue_is_not_linked():
     assert "scope_s" not in red
 
 
+def test_enqueue_after_its_launch_span_is_linked():
+    """The runtime may enqueue a step whose inputs are still being
+    computed after its launch span has closed: the one enqueue before the
+    next launch is the step's, and the device idle until that enqueue
+    ended is idle the host caused."""
+    ex = _events()
+    # step 2's enqueue moves out of its launch (2.3 .. 3.3) to 3.35 .. 3.4
+    ex["enqueues"] = [q if q[0] != 12 else (12, 3.35, 3.4)
+                      for q in ex["enqueues"]]
+    ex["devices"]["/device:TPU:0"]["modules"][1] = (12, -96.55, -96.5)
+    red = tl.reduce(ex)
+    assert red["linked_steps"] == 3 and red["unmatched_launches"] == 0
+    assert red["deferred_enqueues"] == 1
+    s2 = red["steps"][1]
+    assert s2["module_host"] == pytest.approx([3.45, 3.5])
+    # idle 2.0 .. 3.45; the step was enqueued at 3.4
+    assert s2["idle_s"] == pytest.approx(1.45)
+    assert s2["host_stall_s"] == pytest.approx(1.4)
+
+
 def test_reduce_without_devices_reads_nothing():
     ex = _events()
     ex["devices"] = {}

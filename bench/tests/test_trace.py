@@ -1,6 +1,6 @@
 """Trace reduction: arithmetic on hand-made intervals, reading on a small
 trace recorded on a TPU v5e (one ``msa_fused`` call and one matmul inside
-the ``bench.window`` span)."""
+the ``bench.window`` span); every kernel named gets its device seconds."""
 from pathlib import Path
 
 import pytest
@@ -32,11 +32,13 @@ def test_reduce_busy_kernel_and_gaps():
             ("server._retire", 7.0, 9.0),
         ],
     }
-    r = trc.reduce(ex, "msa_fused")
+    r = trc.reduce(ex, ("msa_fused", "fusion.2", "absent"))
     assert r["window_s"] == pytest.approx(9.5)
     # busy: [1, 3.6] + [6, 7] + [9.5, 10]
     assert r["busy_s"] == pytest.approx(2.6 + 1.0 + 0.5)
-    assert r["kernel_s"] == pytest.approx(1.5 + 0.6)
+    assert r["kernels"]["msa_fused"]["device_s"] == pytest.approx(1.5 + 0.6)
+    assert r["kernels"]["fusion.2"]["device_s"] == pytest.approx(1.0)
+    assert r["kernels"]["absent"]["device_s"] == 0
     assert r["device_ops"][0] == ["msa_fused", pytest.approx(2.1)]
     gaps = {(n, round(s, 9)) for n, s in r["idle_gaps"]}
     assert ("sched.schedule", 2.4) in gaps        # 3.6 .. 6
@@ -45,7 +47,7 @@ def test_reduce_busy_kernel_and_gaps():
 
 
 def test_reduce_without_window_or_devices_reads_nothing():
-    assert trc.reduce({"devices": {}, "spans": []}, "msa_fused") == {}
+    assert trc.reduce({"devices": {}, "spans": []}, ("msa_fused",)) == {}
 
 
 def test_op_names():
@@ -61,9 +63,9 @@ def test_device_clock_aligned_to_first_dispatch():
                                         ("b", 102.0, 103.0)]},
           "spans": [(trc.WINDOW_SPAN, 0.0, 10.0),
                     ("engine.dispatch", 1.0, 1.5)]}
-    r = trc.reduce(ex, "a")
+    r = trc.reduce(ex, ("a",))
     assert r["busy_s"] == pytest.approx(2.0)
-    assert r["kernel_s"] == pytest.approx(1.0)
+    assert r["kernels"]["a"]["device_s"] == pytest.approx(1.0)
     gaps = {(n, round(s, 9)) for n, s in r["idle_gaps"]}
     assert ("engine.dispatch", 0.0) not in gaps
     assert ("host idle", 1.0) in gaps and ("host idle", 6.0) in gaps
@@ -73,8 +75,9 @@ def test_recorded_trace():
     ex = trc.extract(str(DATA / "tiny.xplane.pb"),
                      ("engine.dispatch", "server._retire"))
     assert any(p.startswith("/device:TPU") for p in ex["devices"])
-    r = trc.reduce(ex, "msa_fused")
-    assert 0 < r["kernel_s"] < r["busy_s"] <= r["window_s"]
+    r = trc.reduce(ex, ("msa_fused",))
+    assert 0 < r["kernels"]["msa_fused"]["device_s"] < r["busy_s"] \
+        <= r["window_s"]
     assert "msa_fused" in {n for n, _ in r["device_ops"]}
     assert {n for n, _ in r["idle_gaps"]} <= {
         "engine.dispatch", "server._retire", "host idle"}
