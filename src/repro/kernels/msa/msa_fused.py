@@ -20,6 +20,10 @@ anywhere in the pool — gathering non-contiguous segments is the point of
 MSA.  All work-list metadata is scalar-prefetched.
 
 Layout: the pools are head-major ``(P, KH, page, D)`` and stay in HBM;
+the engine hands the kernel its whole layer-stacked ``(L, P, KH, page,
+D)`` pools and a scalar-prefetched layer index, so no layer's pool is
+ever sliced out into a buffer of its own (a per-layer pool is the
+``L = 1`` case);
 the wrapper feeds q grouped by KV head, ``(KH, G, T, D)`` with
 ``G = H / KH``, so every block ends in a ``(rows, D)`` tile — ``(page,
 D)`` for a K/V page, ``(TQ, D)`` per query head for q/out, ``(TQ, 1)``
@@ -208,11 +212,12 @@ def _msa_fused_kernel(
     q_start,          # (N+1,) stream row where each sequence's run begins
     q_len,            # (N+1,) run length (sentinel row: 0)
     context_lens,     # (N+1,)
+    layer,            # (1,)  layer of the stacked pools to attend over
     # inputs
     q_pos_ref,        # (G, TQ, 1) int32 — logical positions of this q tile
     q_ref,            # (1, G, TQ, D) — the G query heads of one KV head
-    k_hbm,            # (P, KH, page, D) pool, left in HBM
-    v_hbm,            # (P, KH, page, D)
+    k_hbm,            # (L, P, KH, page, D) stacked pools, left in HBM
+    v_hbm,            # (L, P, KH, page, D)
     # outputs
     o_ref,            # (1, G, TQ, D)
     # scratch
@@ -232,6 +237,7 @@ def _msa_fused_kernel(
     g = pl.program_id(0)
     w = pl.program_id(1)
     s = wl_seq[w]
+    lyr = layer[0]
     _, grp, tq, d = q_ref.shape
     page = k_buf.shape[2]
     rows_g = grp * tq
@@ -242,9 +248,9 @@ def _msa_fused_kernel(
         def body(i, carry):
             slot = wl_slot[item * nb + i]
             op(pltpu.make_async_copy(
-                k_hbm.at[slot, g], k_buf.at[buf, i], sem.at[0, buf]))
+                k_hbm.at[lyr, slot, g], k_buf.at[buf, i], sem.at[0, buf]))
             op(pltpu.make_async_copy(
-                v_hbm.at[slot, g], v_buf.at[buf, i], sem.at[1, buf]))
+                v_hbm.at[lyr, slot, g], v_buf.at[buf, i], sem.at[1, buf]))
             return carry
         # unrolled on the chip, so the scalar core issues the descriptors
         # back to back; rolled under the interpreter, where unrolling
@@ -322,12 +328,13 @@ def _msa_fused_kernel(
 
 # jitted so that a step's layers share one trace and one Mosaic lowering
 # of the kernel per shape, instead of lowering it again for every layer
+# (the layer index is an operand, not a static argument, for that reason)
 @functools.partial(jax.jit,
                    static_argnames=("window", "softcap", "q_tile", "interpret"))
 def msa_fused_pallas(
     q: jax.Array,              # (T, H, D) flattened mixed token stream
-    k_pages: jax.Array,        # (P, KH, page, D) head-major pool
-    v_pages: jax.Array,
+    k_pages: jax.Array,        # (L, P, KH, page, D) stacked, or (P, KH,
+    v_pages: jax.Array,        # page, D) one layer's head-major pool
     q_start: jax.Array,        # (N,) int32
     q_len: jax.Array,          # (N,) int32
     q_pos: jax.Array,          # (T,) int32
@@ -338,14 +345,18 @@ def msa_fused_pallas(
     wl_kvbase: jax.Array,
     wl_init: jax.Array,
     wl_last: jax.Array,
+    layer: jax.Array = 0,      # () int32: the stacked pools' layer to read
     *,
     window: int = 0,
     softcap: float = 0.0,
     q_tile: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
+    if k_pages.ndim == 4:      # one layer's pool: a stack of one
+        k_pages, v_pages = k_pages[None], v_pages[None]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     t, h, d = q.shape
-    _, kh, page, _ = k_pages.shape
+    _, _, kh, page, _ = k_pages.shape
     grp = h // kh
     n_items = wl_seq.shape[0]
     nb = block_pages(page)
@@ -378,7 +389,7 @@ def msa_fused_pallas(
         return (g_, 0, wl_qtile_[w_], 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=9,
+        num_scalar_prefetch=10,
         grid=(kh, n_items),
         in_specs=[
             pl.BlockSpec((grp, q_tile, 1), qpos_index),
@@ -412,5 +423,5 @@ def msa_fused_pallas(
     )(wl_seq.astype(jnp.int32), wl_qtile.astype(jnp.int32),
       wl_slot.astype(jnp.int32), wl_kvbase.astype(jnp.int32),
       wl_init.astype(jnp.int32), wl_last.astype(jnp.int32),
-      qs, ql, ctx, qpos, qg, k_pages, v_pages)
+      qs, ql, ctx, layer, qpos, qg, k_pages, v_pages)
     return out.transpose(2, 0, 1, 3).reshape(t_pad, h, d)[:t]

@@ -46,14 +46,18 @@ def msa_prefill(q, k_pages, v_pages, block_tables, context_lens, q_pos,
 
 def msa_fused(q, k_pages, v_pages, block_tables, context_lens, q_pos,
               seq_ids, q_valid, *, q_start=None, q_len=None, worklist=None,
-              window: int = 0, softcap: float = 0.0, q_tile: int = 128,
-              impl: str = DEFAULT_IMPL) -> jax.Array:
+              layer=None, window: int = 0, softcap: float = 0.0,
+              q_tile: int = 128, impl: str = DEFAULT_IMPL) -> jax.Array:
     """One fused dispatch over the flattened (T, H, D) mixed token stream
     (prefill chunks + decode rows).  The xla oracle resolves each token's
     context through ``seq_ids``; the Pallas kernel iterates the compacted
     work-list (``msa_fused.build_worklist``) with per-sequence
-    ``q_start``/``q_len`` runs."""
+    ``q_start``/``q_len`` runs.  With ``layer`` the pools are the
+    layer-stacked ``(L, P, KH, page, D)`` ones: the kernel DMAs that
+    layer's pages straight from them, the oracle slices the layer out."""
     if impl == "xla":
+        if layer is not None:
+            k_pages, v_pages = k_pages[layer], v_pages[layer]
         return ref.msa_fused_ref(q, k_pages, v_pages, block_tables,
                                  context_lens, q_pos, seq_ids, q_valid,
                                  window=window, softcap=softcap)
@@ -61,7 +65,8 @@ def msa_fused(q, k_pages, v_pages, block_tables, context_lens, q_pos,
         raise ValueError("pallas msa_fused needs q_start/q_len + worklist")
     interpret = impl == "pallas_interpret"
     return msa_fused_pallas(q, k_pages, v_pages, q_start, q_len, q_pos,
-                            context_lens, *worklist, window=window,
+                            context_lens, *worklist,
+                            0 if layer is None else layer, window=window,
                             softcap=softcap, q_tile=q_tile,
                             interpret=interpret)
 

@@ -121,6 +121,87 @@ def wl_capacity(ecfg: "EngineConfig", t_bucket: int, np_bucket: int) -> int:
     return max(WL_BUCKET, 1 << (items - 1).bit_length())
 
 
+# step fields that carry one row per fused iteration of a multi-token
+# decode plan (the rest — sequence rows, block tables, page-op queues —
+# are shared by the iterations)
+PER_ITER_FIELDS = ("tokens", "positions", "valid", "write_slot",
+                   "write_off", "write_page", "write_row", "qlen",
+                   "ctx") + WL_FIELDS
+
+
+def kv_write_capacity(ecfg: "EngineConfig", t_bucket: int) -> int:
+    """``NW``: the most distinct pool pages one fused iteration of
+    ``t_bucket`` token rows can write.  A prefill chunk of ``n`` tokens
+    touches at most ``ceil(n / page) + 1`` pages (its run may start
+    mid-page), so R chunks and B decode rows touch fewer than
+    ``t_bucket / page + 2R + B``; and no step writes more pages than it
+    has rows."""
+    e = ecfg
+    return min(t_bucket, -(-t_bucket // e.page_size)
+               + 2 * e.max_prefills + e.max_decodes)
+
+
+def touched_pages(write_slot: np.ndarray, valid: np.ndarray, nw: int,
+                  num_pages: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Host side of the page write: ``write_page``, the distinct pool
+    slots the valid rows write, padded with the out-of-range slot
+    ``num_pages`` to ``nw`` entries; and ``write_row``, each row's index
+    into ``write_page`` (0 on invalid rows, which the device drops)."""
+    live = valid.astype(bool)
+    pages, rows = np.unique(write_slot[live], return_inverse=True)
+    n = pages.shape[0]
+    if n > nw:
+        raise ValueError(f"step writes {n} pages > kv_write_capacity {nw}")
+    write_page = np.full((nw,), num_pages, np.int32)
+    write_page[:n] = pages
+    write_row = np.zeros(write_slot.shape, np.int32)
+    write_row[live] = rows
+    return write_page, write_row
+
+
+def write_layer_pages(k_pools, v_pools, layer: int, k_new, v_new,
+                      write_page, write_row, write_off, valid):
+    """Write one layer's new K/V rows into the layer-stacked, donated
+    ``(L, P, KH, page, D)`` pools, page by page: gather the ``NW`` touched
+    pages, scatter the rows into that small buffer (``write_kv_pages``
+    with ``write_row`` for slots), and scatter the whole pages back.
+
+    A row scatter straight into the stacked pool leaves the same bytes,
+    but its index dimensions (slot, offset) straddle the KV-head axis, and
+    XLA on TPU then moves the whole pool into another layout and back on
+    every layer; a scatter whose window is whole ``(KH, page, D)`` pages
+    stays in place.  Padding entries of ``write_page`` (slot P) gather a
+    clamped page and their write-back is dropped."""
+    k_pages, v_pages = write_kv_pages(
+        k_pools[layer, write_page], v_pools[layer, write_page], k_new, v_new,
+        write_row, write_off, valid)
+    return (k_pools.at[layer, write_page].set(k_pages, mode="drop"),
+            v_pools.at[layer, write_page].set(v_pages, mode="drop"))
+
+
+def write_and_attend(k_pools, v_pools, layer: int, q, k_new, v_new,
+                     f: Dict[str, jax.Array], worklist, *, window: int,
+                     softcap: float, q_tile: int, impl: str):
+    """A fused single-device layer's KV write and its ONE ``msa_fused``
+    dispatch, which reads ``layer`` of the stacked pools in place: no
+    layer's pool is ever sliced out into a buffer of its own.  ``f``
+    holds one fused iteration's step fields.  ``Engine._step_impl`` and
+    ``Engine._fused_pass`` both run this, so a k-token decode repeats the
+    single step's ops exactly.  Returns the pools and the attention
+    output."""
+    with jax.named_scope("kv_write"):
+        k_pools, v_pools = write_layer_pages(
+            k_pools, v_pools, layer, k_new, v_new, f["write_page"],
+            f["write_row"], f["write_off"], f["valid"])
+    with jax.named_scope("attention"):
+        attn = msa_fused(q, k_pools, v_pools, f["bt"], f["ctx"],
+                         f["positions"], f["seq_ids"], f["valid"],
+                         q_start=f["qstart"], q_len=f["qlen"],
+                         worklist=worklist, layer=layer, window=window,
+                         softcap=softcap, q_tile=q_tile, impl=impl)
+    return k_pools, v_pools, attn
+
+
 def derive_bucket_lattice(ecfg: "EngineConfig"
                           ) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
     """``(token_buckets, np_buckets)`` implied by an :class:`EngineConfig`.
@@ -182,7 +263,9 @@ def pack_layout_for(ecfg: "EngineConfig", n_shards: int, t_bucket: int,
         t, n, k = t_bucket, R + B, n_iter
         fields = [("tokens", k * t), ("positions", k * t),
                   ("valid", k * t), ("write_slot", k * t),
-                  ("write_off", k * t), ("seq_ids", t),
+                  ("write_off", k * t),
+                  ("write_page", k * kv_write_capacity(e, t)),
+                  ("write_row", k * t), ("seq_ids", t),
                   ("sel", R + B), ("qstart", n), ("qlen", k * n),
                   ("ctx", k * n), ("bt", n * np_bucket)]
         # wl_slot carries block_pages(page) pool slots per item
@@ -461,6 +544,9 @@ class Engine:
         # buffers (codes + scales in q8 mode) — the wire-level half of
         # the bytes_swapped_* accounting the block manager keeps
         self.swap_bytes_shipped = 0
+        # distinct pool pages written by fused steps, summed over steps
+        # (and iterations): each layer's KV write moves this many pages
+        self.kv_pages_written = 0
         # multi-token decode dispatch + decode-phase accounting
         # (benchmarks/control_plane_stress.py gates the ≥3x dispatch
         # drop on decode-dominated segments with these)
@@ -613,23 +699,20 @@ class Engine:
                 with jax.named_scope("mlp"):
                     x = self._mlp_sublayer(x, blk)
                 continue
-            with jax.named_scope("kv_write"):
-                kp, vp = write_kv_pages(
-                    k_pools[l], v_pools[l], k_new, v_new,
-                    inp["write_slot"], inp["write_off"], inp["valid"])
-                k_pools = k_pools.at[l].set(kp)
-                v_pools = v_pools.at[l].set(vp)
-
-            with jax.named_scope("attention"):
-                if fused:
-                    # ONE varlen dispatch over the whole mixed stream
-                    attn = msa_fused(
-                        q, kp, vp, inp["bt"], inp["ctx"], pos,
-                        inp["seq_ids"], inp["valid"], q_start=inp["qstart"],
-                        q_len=inp["qlen"], worklist=worklist, window=window,
-                        softcap=cfg.attn_logit_softcap, q_tile=tq,
-                        impl=impl)
-                else:
+            if fused:
+                # ONE varlen dispatch over the whole mixed stream
+                k_pools, v_pools, attn = write_and_attend(
+                    k_pools, v_pools, l, q, k_new, v_new, inp, worklist,
+                    window=window, softcap=cfg.attn_logit_softcap,
+                    q_tile=tq, impl=impl)
+            else:
+                with jax.named_scope("kv_write"):
+                    kp, vp = write_kv_pages(
+                        k_pools[l], v_pools[l], k_new, v_new,
+                        inp["write_slot"], inp["write_off"], inp["valid"])
+                    k_pools = k_pools.at[l].set(kp)
+                    v_pools = v_pools.at[l].set(vp)
+                with jax.named_scope("attention"):
                     qp_ = q[:RQP].reshape(R, QP, cfg.n_heads, cfg.head_dim)
                     op = msa_prefill(
                         qp_, kp, vp, inp["bt_pre"], inp["ctx_pre"], qpos_pre,
@@ -643,6 +726,7 @@ class Engine:
                     attn = jnp.concatenate(
                         [op.reshape(RQP, cfg.n_heads, cfg.head_dim), od],
                         axis=0)
+            with jax.named_scope("attention"):
                 x = x + jnp.einsum("thk,hkd->td", attn, blk["wo"])
             with jax.named_scope("mlp"):
                 x = self._mlp_sublayer(x, blk)
@@ -689,17 +773,18 @@ class Engine:
             y = swiglu_mlp(h2, blk["w1"], blk["w3"], blk["w2"])
         return x + y
 
-    def _fused_pass(self, params, k_pools, v_pools, tokens, pos, valid,
-                    write_slot, write_off, ctx, bt, qstart, qlen, seq_ids,
-                    worklist, t_bucket: int):
-        """One fused single-device forward over a varlen token stream:
-        per-layer KV page write + ONE ``msa_fused`` dispatch each — the
-        body a multi-token decode iteration repeats, op-for-op the same
-        math as the ``n_iter == 1`` fused branch of ``_step_impl`` (the
-        k-vs-1 byte-identity the benchmarks gate depends on it).
-        Returns the updated pools and the pre-final-norm residual."""
+    def _fused_pass(self, params, k_pools, v_pools, tokens, f, worklist,
+                    t_bucket: int):
+        """One fused single-device forward over a varlen token stream
+        (``f``: one iteration's step fields): per-layer
+        ``write_and_attend`` — the body a multi-token decode iteration
+        repeats, op-for-op the same math as the ``n_iter == 1`` fused
+        branch of ``_step_impl`` (the k-vs-1 byte-identity the
+        benchmarks gate depends on it).  Returns the updated pools and
+        the pre-final-norm residual."""
         cfg, e = self.cfg, self.ecfg
         tq = min(e.q_tile, t_bucket)
+        pos = f["positions"]
         with jax.named_scope("embed"):
             x = params["embed"][tokens]
         for l in range(cfg.n_layers):
@@ -715,17 +800,11 @@ class Engine:
                     k_new = apply_rope(k_new, pos, cfg.rope_theta)
                 k_new = self._snap(k_new)
                 v_new = self._snap(v_new)
-            with jax.named_scope("kv_write"):
-                kp, vp = write_kv_pages(k_pools[l], v_pools[l], k_new,
-                                        v_new, write_slot, write_off, valid)
-                k_pools = k_pools.at[l].set(kp)
-                v_pools = v_pools.at[l].set(vp)
+            k_pools, v_pools, attn = write_and_attend(
+                k_pools, v_pools, l, q, k_new, v_new, f, worklist,
+                window=window, softcap=cfg.attn_logit_softcap, q_tile=tq,
+                impl=e.attn_impl)
             with jax.named_scope("attention"):
-                attn = msa_fused(q, kp, vp, bt, ctx, pos, seq_ids, valid,
-                                 q_start=qstart, q_len=qlen,
-                                 worklist=worklist, window=window,
-                                 softcap=cfg.attn_logit_softcap, q_tile=tq,
-                                 impl=e.attn_impl)
                 x = x + jnp.einsum("thk,hkd->td", attn, blk["wo"])
             with jax.named_scope("mlp"):
                 x = self._mlp_sublayer(x, blk)
@@ -753,13 +832,12 @@ class Engine:
             tok = inp["tokens"][i]
             if prev is not None:
                 tok = jnp.where(tok >= 0, tok, prev[seq_ids])
+            f = {name: (a[i] if name in PER_ITER_FIELDS else a)
+                 for name, a in inp.items()}
             k_pools, v_pools, x = self._fused_pass(
-                params, k_pools, v_pools, jnp.maximum(tok, 0),
-                inp["positions"][i], inp["valid"][i],
-                inp["write_slot"][i], inp["write_off"][i], inp["ctx"][i],
-                inp["bt"], inp["qstart"], inp["qlen"][i], seq_ids,
+                params, k_pools, v_pools, jnp.maximum(tok, 0), f,
                 None if e.attn_impl == "xla"
-                else tuple(inp[f][i] for f in WL_FIELDS),
+                else tuple(f[name] for name in WL_FIELDS),
                 t_bucket)
             with jax.named_scope("logits"):
                 x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -805,6 +883,8 @@ class Engine:
                 fields = self._assemble_fused_multi(plan, t_b, np_b, n_it)
             else:
                 fields = self._assemble_fused(plan, t_b, np_b)
+            self.kv_pages_written += int(np.count_nonzero(
+                fields["write_page"] < self.ecfg.num_pages))
             if self.ecfg.attn_impl != "xla":
                 # one work-list per fused iteration (n_it == 1: exactly
                 # the single-step list), all padded to one shared W so
@@ -878,14 +958,8 @@ class Engine:
             out["bt"] = out["bt"].reshape(self.n_seqs, np_bucket)
             if n_iter > 1:
                 # per-iteration fields fold out to (k, ·)
-                for f in ("tokens", "positions", "valid",
-                          "write_slot", "write_off"):
-                    out[f] = out[f].reshape(n_iter, t_bucket)
-                out["qlen"] = out["qlen"].reshape(n_iter, self.n_seqs)
-                out["ctx"] = out["ctx"].reshape(n_iter, self.n_seqs)
-                if w_bucket:
-                    for f in WL_FIELDS:
-                        out[f] = out[f].reshape(n_iter, -1)
+                for f in PER_ITER_FIELDS:
+                    out[f] = out[f].reshape(n_iter, -1)
         else:
             R, B, NP = e.max_prefills, e.max_decodes, e.max_blocks_per_seq
             out["bt_pre"] = out["bt_pre"].reshape(R, NP)
@@ -970,8 +1044,11 @@ class Engine:
             sel[R:R + nd] = rows
             off += nd
         assert off <= t_bucket, (off, t_bucket)
+        write_page, write_row = touched_pages(
+            write_slot, valid, kv_write_capacity(e, t), e.num_pages)
         return dict(tokens=tokens, positions=positions, valid=valid,
                     write_slot=write_slot, write_off=write_off,
+                    write_page=write_page, write_row=write_row,
                     seq_ids=seq_ids, sel=sel, qstart=qstart, qlen=qlen,
                     ctx=ctx, bt=bt)
 
@@ -1035,8 +1112,15 @@ class Engine:
                 if act[j]:
                     tokens[i, j] = req.output_script[gen[j] - 1 + i]
                     write_slot[i, j] = req.slot_array()[p[j] // bs]
+        nw = kv_write_capacity(e, t)
+        write_page = np.zeros((k, nw), np.int32)
+        write_row = np.zeros((k, t), np.int32)
+        for i in range(k):
+            write_page[i], write_row[i] = touched_pages(
+                write_slot[i], valid[i], nw, e.num_pages)
         return dict(tokens=tokens, positions=positions, valid=valid,
                     write_slot=write_slot, write_off=write_off,
+                    write_page=write_page, write_row=write_row,
                     seq_ids=seq_ids, sel=sel, qstart=qstart, qlen=qlen,
                     ctx=ctx, bt=bt)
 
@@ -1468,6 +1552,8 @@ class Engine:
             "instep_swaps": self.instep_swaps,
             "eager_swaps": self.eager_swaps,
             "swap_bytes_shipped": self.swap_bytes_shipped,
+            # pool pages the fused steps' KV writes touched (per layer)
+            "kv_pages_written": self.kv_pages_written,
             # multi-token decode dispatch (schema frozen by
             # tests/test_perf_counters.py — benchmark gates read these)
             "engine_dispatches": self.steps_executed,
@@ -1494,6 +1580,7 @@ class Engine:
         self.instep_copies = self.eager_copies = 0
         self.instep_swaps = self.eager_swaps = 0
         self.swap_bytes_shipped = 0
+        self.kv_pages_written = 0
         self.decode_only_dispatches = 0
         self.decode_tokens_emitted = 0
         self.multi_token_dispatches = 0

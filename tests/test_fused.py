@@ -311,6 +311,27 @@ def test_fused_kernel_page_blocks(name, page):
     assert np.all(np.asarray(o)[~np.asarray(valid)] == 0.0)
 
 
+@pytest.mark.parametrize("layer", [0, 2])
+def test_fused_kernel_reads_its_layer_of_stacked_pools(layer):
+    """Handed the layer-stacked pools and a layer index, the kernel gives
+    bitwise what it gives on that layer's pool alone; every other layer
+    is NaN, so a page read from any of them would show."""
+    runs, window, softcap, _ = PAGE_BLOCK_CASES["chunk_and_decodes_share_tile"]
+    c = _runs_case(runs, page=16, seed=layer)
+    wl = _worklist_for(c, page=16, q_tile=8, window=window)
+    stack = [jnp.full_like(c["k_pages"], jnp.nan)] * 3
+    k_pools = jnp.stack(stack[:layer] + [c["k_pages"]] + stack[layer + 1:])
+    v_pools = jnp.stack(stack[:layer] + [c["v_pages"]] + stack[layer + 1:])
+    args = (c["bt"], c["ctx"], c["q_pos"], c["seq_ids"], c["valid"])
+    kw = dict(q_start=c["q_start"], q_len=c["q_len"], worklist=wl,
+              window=window, softcap=softcap, q_tile=8,
+              impl="pallas_interpret")
+    want = msa_fused(c["q"], c["k_pages"], c["v_pages"], *args, **kw)
+    got = msa_fused(c["q"], k_pools, v_pools, *args, layer=layer, **kw)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert np.all(np.isfinite(np.asarray(got)))
+
+
 def _needed_pages(c, *, page, q_tile, n_tiles, window):
     """Page-granular rule, one page at a time: (seq, tile) -> needed
     logical pages."""

@@ -36,6 +36,7 @@ ENGINE_COUNTER_KEYS = frozenset({
     "instep_swaps",
     "eager_swaps",
     "swap_bytes_shipped",
+    "kv_pages_written",
     "engine_dispatches",
     "decode_only_dispatches",
     "decode_tokens_emitted",
@@ -59,6 +60,7 @@ MONOTONIC_KEYS = (
     "instep_swaps",
     "eager_swaps",
     "swap_bytes_shipped",
+    "kv_pages_written",
 )
 
 # the sim engine mirrors this subset so stress-benchmark gates read the
@@ -168,6 +170,8 @@ def test_engine_counter_schema(served):
     assert pc["multi_token_dispatches"] > 0
     assert pc["multi_token_iterations"] > pc["multi_token_dispatches"]
     assert pc["decode_only_dispatches"] > 0
+    # every dispatch writes the KV rows of its tokens: one page at least
+    assert pc["engine_dispatches"] <= pc["kv_pages_written"]
     assert all(k.startswith("k") for k in pc["k_counts"])
 
 

@@ -9,15 +9,19 @@ The topology is described inside module-scoped fixtures of this one file
 and never at import time: only one process may load the TPU library, and
 every test worker imports every test file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.kernels.msa import WL_FIELDS
 from repro.kernels.msa.msa_decode import msa_decode_pallas
 from repro.kernels.msa.msa_fused import block_pages, msa_fused_pallas
 from repro.kernels.msa.msa_prefill import msa_prefill_pallas
+from repro.serving.engine import write_and_attend
 
 # Llama-3.1-8B attention widths; the engine's page size and q tile
 H, KH, D, PAGE, Q_TILE = 32, 8, 128, 16, 128
@@ -68,23 +72,100 @@ def _compiles(fn, args):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# (token bucket, work-list items, KV heads): a decode-only bucket and the
-# largest prefill bucket of the benchmark's cells (2064 tokens = 17 q
-# tiles), at G=4 (8 KV heads) and at G=16 (2 KV heads, whose
+# (token bucket, work-list items, KV heads, layers): a decode-only bucket
+# and the largest prefill bucket of the benchmark's cells (2064 tokens =
+# 17 q tiles), at G=4 (8 KV heads) and at G=16 (2 KV heads, whose
 # (G*TQ, D) = (2048, 128) operands hold the most VMEM); and the largest
 # work-list the engine runs for ChatGLM3's 512-page bucket
-# (``wl_capacity``), whose scalar-prefetched slots hold the most SMEM
-@pytest.mark.parametrize("t,w,kh", [(16, 256, KH), (2064, 512, KH),
-                                    (16, 256, 2), (2064, 512, 2),
-                                    (2064, 4096, 2)])
-def test_msa_fused_compiles_for_v5e(spec, no_compile_cache, t, w, kh):
-    pool = spec((POOL_PAGES, kh, PAGE, D), jnp.bfloat16)
+# (``wl_capacity``), whose scalar-prefetched slots hold the most SMEM.
+# layers 0: one layer's pool; else the engine's layer-stacked pools (the
+# cells' 20 and 28 layers) and a layer index.
+FUSED_CASES = [(16, 256, KH, 0), (2064, 512, KH, 0), (16, 256, 2, 0),
+               (2064, 512, 2, 0), (2064, 4096, 2, 0),
+               (16, 256, KH, 20), (2064, 512, KH, 20), (2064, 512, 2, 28),
+               (2064, 4096, 2, 28)]
+
+
+@pytest.mark.parametrize(
+    "t,w,kh,layers", FUSED_CASES,
+    ids=[f"{t}-{w}-{kh}" + (f"-stacked{n}" if n else "")
+         for t, w, kh, n in FUSED_CASES])
+def test_msa_fused_compiles_for_v5e(spec, no_compile_cache, t, w, kh,
+                                    layers):
+    pool = spec((layers,) * bool(layers) + (POOL_PAGES, kh, PAGE, D),
+                jnp.bfloat16)
     slots = w * block_pages(PAGE)
+    layer = (spec(()),) if layers else ()
     _compiles(lambda *a: msa_fused_pallas(*a, q_tile=Q_TILE),
               (spec((t, H, D), jnp.bfloat16), pool, pool,
                spec((N_SEQS,)), spec((N_SEQS,)), spec((t,)), spec((N_SEQS,)),
                spec((w,)), spec((w,)), spec((slots,)),
-               *(spec((w,)) for _ in range(3))))
+               *(spec((w,)) for _ in range(3)), *layer))
+
+
+def _entry_ops(hlo: str):
+    """(name, opcode, result element counts) of each instruction of the
+    compiled module's entry computation."""
+    lines = hlo[hlo.index("\nENTRY"):].splitlines()[1:]
+    for line in lines:
+        if line.startswith("}"):
+            return
+        if " = " not in line:
+            continue
+        name, rhs = line.strip().removeprefix("ROOT ").split(" = ", 1)
+        if rhs.startswith("("):             # a tuple-shaped result
+            depth = 0
+            for end, ch in enumerate(rhs):
+                depth += {"(": 1, ")": -1}.get(ch, 0)
+                if depth == 0:
+                    break
+            rtype, rest = rhs[:end + 1], rhs[end + 1:]
+        else:
+            rtype, rest = rhs.split(" ", 1)
+        sizes = [math.prod(int(x) for x in dims.split(",") if x)
+                 for dims in re.findall(r"\w+\[([\d,]*)\]", rtype)]
+        yield name.lstrip("%"), rest.strip().split("(", 1)[0], sizes
+
+
+def test_engine_layer_writes_pools_in_place_for_v5e(spec, no_compile_cache):
+    """Two of the engine's fused layer bodies (``write_and_attend``: the
+    page write, then ``msa_fused`` on the stacked pools) over donated
+    ChatGLM3-width pools of 5120 pages, in the 2064-token bucket.  Each
+    layer's input depends on the layer before, as in the step.  No
+    temporary the size of one layer's pool may appear, and no copy, slice
+    or dynamic-update-slice of the stacked or one layer's pool: a row
+    scatter straight into the pools, or a kernel operand sliced to one
+    layer, puts whole-pool copies of that kind here (and on the chip on
+    every layer of every step)."""
+    n_layers, pages, kh, t, w, np_b, n = 2, 5120, 2, 2064, 512, 512, 18
+    nw = 149                        # kv_write_capacity: 129 + 2 * 2 + 16
+
+    def layers(k_pools, v_pools, q, k_new, v_new, f, wl):
+        for layer in range(n_layers):
+            k_pools, v_pools, attn = write_and_attend(
+                k_pools, v_pools, layer, q, k_new, v_new, f, wl, window=0,
+                softcap=0.0, q_tile=Q_TILE, impl="pallas")
+            q, k_new, v_new = attn, attn[:, :kh], attn[:, kh:2 * kh]
+        return k_pools, v_pools, q
+
+    pool = spec((n_layers, pages, kh, PAGE, D), jnp.bfloat16)
+    f = {"write_page": spec((nw,)), "write_row": spec((t,)),
+         "write_off": spec((t,)), "valid": spec((t,), jnp.bool_),
+         "bt": spec((n, np_b)), "ctx": spec((n,)), "positions": spec((t,)),
+         "seq_ids": spec((t,)), "qstart": spec((n,)), "qlen": spec((n,))}
+    wl = tuple(spec((w * block_pages(PAGE),) if name == "wl_slot" else (w,))
+               for name in WL_FIELDS)
+    rows = spec((t, kh, D), jnp.bfloat16)
+    compiled = jax.jit(layers, donate_argnums=(0, 1)).lower(
+        pool, pool, spec((t, H, D), jnp.bfloat16), rows, rows, f,
+        wl).compile()
+    layer_elems = pages * kh * PAGE * D
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer_elems
+    copies = [(name, op) for name, op, sizes in _entry_ops(compiled.as_text())
+              if max(sizes, default=0) >= layer_elems
+              and any(k in (op if op != "fusion" else name)
+                      for k in ("copy", "slice", "dynamic-update-slice"))]
+    assert not copies, copies
 
 
 def test_msa_prefill_compiles_for_v5e(spec, no_compile_cache):
