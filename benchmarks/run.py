@@ -5,14 +5,11 @@ Prints ``name,us_per_call,derived`` CSV rows.
   Fig. 11/12  e2e_serving        policy x dispersion x dataset TTFT/TPOT
   Table 2     evictor_complexity O(log n) vs O(n) vs LRU end-to-end
   Fig. 9      evictor_scaling    control-plane time vs cache size
-  Fig. 13     msa_kernel         MSA vs 2-call vs prefix-only
   Fig. 14     sensitivity        lifespan / reuse-prob / slope sweeps
   Fig. 15     agentic            Continuum integration, QPS sweep
   Fig. 3/7    workload_stats     hit-position + reuse-interval PDFs
   (ours)      roofline_report    dry-run three-term roofline table
   (ours)      prefix_sharing     cross-request sharing vs no-sharing
-  (ours)      pipeline           overlapped pipeline vs synchronous loop
-  Fig. 13     kernel_fusion      fused varlen dispatch vs two-dispatch
   (ours)      sharded_serving    N-way sequence-sharded engine vs single
   §6.5/§8     agentic_online     closed-loop Continuum frontend + prefetch
   (ours)      control_plane_stress  k-step decode dispatch + 5k-session O(·)
@@ -28,15 +25,12 @@ MODULES = [
     ("e2e_serving", {}),
     ("evictor_complexity", {}),
     ("evictor_scaling", {}),
-    ("msa_kernel", {}),
     ("sensitivity", {}),
     ("agentic", {}),
     ("workload_stats", {}),
     ("offload", {}),
     ("roofline_report", {}),
     ("prefix_sharing", {}),
-    ("pipeline", {}),
-    ("kernel_fusion", {}),
     # runs its measurement in a child process with 4 forced host devices,
     # so it is insensitive to this process's jax device-count lock
     ("sharded_serving", {}),

@@ -14,10 +14,10 @@ the engine itself compiles from — and, per bucket:
   and scheduler run; the engine is the Eq.-6 cost model) and mapping
   every dispatched plan through a replica of ``Engine.buckets_for``.
 
-The runtime benchmarks close the loop: ``benchmarks/kernel_fusion.py``
-and ``benchmarks/sharded_serving.py`` assert measured ``jit_traces``
-equals the prediction, so the compile-once-per-bucket invariant is
-checked from both sides of the compile boundary.
+The runtime side closes the loop: ``benchmarks/sharded_serving.py``
+asserts measured ``jit_traces`` equals the prediction, so the
+compile-once-per-bucket invariant is checked from both sides of the
+compile boundary.
 
 Prediction scope: the ``attn_impl="xla"`` engines the CI gates run
 (``w_bucket == 0``).  Pallas work-list buckets are data-dependent
@@ -52,9 +52,7 @@ def enumerate_lattice(ecfg, n_shards: int = 1,
         w_buckets = ()
         w_note = (f"data-dependent powers of two >= WL_BUCKET="
                   f"{WL_BUCKET} (not statically enumerable)")
-    multi_token_ok = (ecfg.attn_mode == "fused" and n_shards == 1
-                      and ecfg.assembly != "legacy")
-    kmax = max_decode_steps if multi_token_ok else 1
+    kmax = max_decode_steps if n_shards == 1 else 1
     k_values = tuple(1 << i for i in range(max(1, kmax).bit_length())
                      if (1 << i) <= max(1, kmax))
     return {
@@ -161,9 +159,6 @@ def _key_for_plan(ecfg, token_buckets, np_buckets, n_shards, plan
             "trace-key prediction covers attn_impl='xla' engines "
             "(Pallas work-list buckets are data-dependent)")
     k = plan.decode_steps
-    if ecfg.attn_mode != "fused":
-        return (ecfg.max_prefills * ecfg.max_chunk + ecfg.max_decodes,
-                ecfg.max_blocks_per_seq, 0, k)
     need_t = plan.n_compute_tokens
     t_b = next((b for b in token_buckets if b >= need_t),
                token_buckets[-1])
@@ -205,16 +200,14 @@ def predict_trace_keys(cfg, scfg, workloads: Sequence,
             num_pages=scfg.num_blocks, page_size=scfg.block_size,
             max_chunk=scfg.scheduler.max_chunk,
             max_prefills=scfg.scheduler.max_prefills,
-            max_decodes=scfg.scheduler.max_decodes,
-            attn_mode=scfg.attn_mode)
+            max_decodes=scfg.scheduler.max_decodes)
     token_buckets, np_buckets = derive_bucket_lattice(ecfg)
     srv = AsymCacheServer(cfg, None, scfg, ecfg=None)
     # mirror the real server's scheduler wiring (__init__ only applies
     # it on the execute_model path)
     srv.sched.cfg.token_buckets = token_buckets
     srv.sched.cfg.page_buckets = np_buckets
-    if (n_shards > 1 or ecfg.attn_mode != "fused"
-            or ecfg.assembly == "legacy"):
+    if n_shards > 1:
         srv.sched.cfg.max_decode_steps = 1
 
     keys: List[Tuple[int, int, int, int]] = []
@@ -250,36 +243,34 @@ def collective_probe(cfg, params, scfg, ecfg=None) -> Dict[str, Dict]:
 
 
 # ---------------------------------------------------------------------------
-# the audit the CLI runs: the kernel-fusion gate configuration
+# the audit the CLI runs: a smoke-scale single-device configuration
 
 #: default audit budget: smoke-scale serving must fit a 2 GiB device
 DEFAULT_DEVICE_BUDGET = 2 << 30
 
 
 def _gate_setup():
-    """The fused single-device gate configuration of
-    benchmarks/kernel_fusion.py (smoke scale), rebuilt here so the audit
-    covers exactly the lattice CI compiles."""
+    """The smoke-scale single-device configuration the audit enumerates
+    and replays."""
     from repro.configs import get_smoke_config, scaled_config
     from repro.serving import SchedulerConfig, ServerConfig
     from repro.serving.engine import EngineConfig
     cfg = scaled_config(get_smoke_config("llama31-8b"), dtype="float32")
     scfg = ServerConfig(
         policy="asymcache", num_blocks=256, block_size=16,
-        clock="model", pipeline_depth=1, attn_mode="fused",
+        clock="model", pipeline_depth=1,
         scheduler=SchedulerConfig(token_budget=256, max_chunk=96,
                                   max_prefills=2, max_decodes=24,
                                   decode_threshold=4, max_running=64))
     ecfg = EngineConfig(
         num_pages=256, page_size=16, max_prefills=2, max_chunk=96,
-        max_decodes=24, max_blocks_per_seq=32, attn_mode="fused")
+        max_decodes=24, max_blocks_per_seq=32)
     return cfg, scfg, ecfg
 
 
 def _gate_workloads(smoke: bool = True):
-    """The exact workload sequence the kernel-fusion gate serves on its
-    depth-1 fused server (warmup + identity run + counter run +
-    segments x perf run)."""
+    """The agentic workload sequence the audit replays on its depth-1
+    server (warmup + two runs + repeated segments)."""
     from repro.serving import AgenticConfig, agentic_workload
 
     def wl(n_jobs, seed):

@@ -1,4 +1,4 @@
-"""Jit'd dispatch wrappers for the MSA kernels.
+"""Dispatch wrappers for the fused MSA kernel, plus the in-step page ops.
 
 ``impl`` selects the backend:
   * "pallas"            — compiled Pallas (TPU)
@@ -7,41 +7,13 @@
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.msa import ref
-from repro.kernels.msa.msa_decode import msa_decode_pallas
 from repro.kernels.msa.msa_fused import msa_fused_pallas
-from repro.kernels.msa.msa_prefill import msa_prefill_pallas
 
 DEFAULT_IMPL = "xla"  # the oracle; the served TPU path passes "pallas"
-
-
-def msa_prefill(q, k_pages, v_pages, block_tables, context_lens, q_pos,
-                q_lens, *, window: int = 0, softcap: float = 0.0,
-                q_tile: int = 128, impl: str = DEFAULT_IMPL) -> jax.Array:
-    if impl == "xla":
-        return ref.msa_prefill_ref(q, k_pages, v_pages, block_tables,
-                                   context_lens, q_pos, q_lens,
-                                   window=window, softcap=softcap)
-    interpret = impl == "pallas_interpret"
-    qp = q.shape[1]
-    q_tile = min(q_tile, qp)
-    qp_pad = -(-qp // q_tile) * q_tile
-    if qp_pad != qp:
-        # ragged QP is legal: round up to the tile with masked padding
-        # rows (qpos 0, beyond q_lens — the kernel zeroes them) and slice
-        # the pad back off
-        q = jnp.pad(q, ((0, 0), (0, qp_pad - qp), (0, 0), (0, 0)))
-        q_pos = jnp.pad(q_pos, ((0, 0), (0, qp_pad - qp)))
-    out = msa_prefill_pallas(q, k_pages, v_pages, block_tables, context_lens,
-                             q_pos, q_lens, window=window, softcap=softcap,
-                             q_tile=q_tile, interpret=interpret)
-    return out[:, :qp]
 
 
 def msa_fused(q, k_pages, v_pages, block_tables, context_lens, q_pos,
@@ -89,18 +61,6 @@ def msa_fused_partial(q, k_pages, v_pages, block_tables, context_lens,
                                      context_lens, q_pos, seq_ids, q_valid,
                                      page_valid, window=window,
                                      softcap=softcap)
-
-
-def msa_decode(q, k_pages, v_pages, block_tables, context_lens, *,
-               window: int = 0, softcap: float = 0.0,
-               impl: str = DEFAULT_IMPL) -> jax.Array:
-    if impl == "xla":
-        return ref.msa_decode_ref(q, k_pages, v_pages, block_tables,
-                                  context_lens, window=window, softcap=softcap)
-    interpret = impl == "pallas_interpret"
-    return msa_decode_pallas(q, k_pages, v_pages, block_tables, context_lens,
-                             window=window, softcap=softcap,
-                             interpret=interpret)
 
 
 write_kv_pages = ref.write_kv_pages

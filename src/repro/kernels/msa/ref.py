@@ -1,10 +1,10 @@
-"""Pure-jnp oracles for the Multi-Segment Attention kernels.
+"""Pure-jnp oracles for the Multi-Segment Attention kernel.
 
-These define the exact contract both Pallas kernels implement:
+These define the exact contract the fused Pallas kernel implements:
 
 Paged KV layout: ``k_pages``/``v_pages`` are head-major (P, KH, page, D)
 pools, so one (page, D) tile of one KV head is contiguous — the block
-shape the TPU kernels stream (Mosaic tiles the last two dims).  A
+shape the TPU kernel streams (Mosaic tiles the last two dims).  A
 request's logical KV space is mapped to pool pages through its row of
 ``block_tables`` (R, NP): logical block j lives in pool page
 ``block_tables[r, j]``.  *Multi-segment* contexts need no special casing —
@@ -13,12 +13,12 @@ dense, and the causal mask compares logical positions.  Gaps being
 recomputed have had their K/V written into freshly allocated pages before
 the attention call, so attention always reads a fully materialized context.
 
-MSA prefill: q is (R, QP, H, D) — each request's *compute* tokens (padded
-to QP).  ``q_pos`` (R, QP) gives each compute token's logical position —
-these may be non-contiguous runs (the chunk can span several cache gaps).
-
-Decode: q is (B, H, D), one new token per sequence at logical position
-``context_lens[b] - 1``.
+``msa_prefill_ref``: q is (R, QP, H, D) — each request's *compute* tokens
+(padded to QP).  ``q_pos`` (R, QP) gives each compute token's logical
+position — these may be non-contiguous runs (the chunk can span several
+cache gaps).  ``msa_fused_ref`` is the same contract over the flattened
+varlen (T, H, D) stream the engine serves, where a decode row is a run
+of one token at logical position ``context_lens[s] - 1``.
 """
 from __future__ import annotations
 
@@ -106,11 +106,11 @@ def msa_fused_ref(
     Prefill chunks and decode rows share one flattened ``(T, H, D)``
     stream; each token resolves its paged context through its sequence's
     row of ``block_tables``.  Implemented by delegation to
-    :func:`msa_prefill_ref` viewed as T single-token requests — every
-    per-token reduction (scores over D, softmax over S, weighted sum
-    over S) runs over identical operands in identical order, so the
-    fused stream is *bitwise* equal to the padded two-dispatch layout
-    on every valid row (invalid rows are zeros, as in the padded ref)."""
+    :func:`msa_prefill_ref` viewed as T single-token requests, so every
+    valid row equals a per-sequence :func:`msa_prefill_ref` call over the
+    same operands up to float32 rounding (XLA may order a reduction
+    differently for the two shapes); invalid rows are zeros, as in the
+    padded ref."""
     out = msa_prefill_ref(
         q[:, None], k_pages, v_pages,
         block_tables[seq_ids], context_lens[seq_ids],
@@ -189,24 +189,6 @@ def msa_fused_partial_ref(
     # cross-shard merge gives them zero weight
     lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
     return o.reshape(t, h, d), lse.reshape(t, h)
-
-
-def msa_decode_ref(
-    q: jax.Array,              # (B, H, D)
-    k_pages: jax.Array,        # (P, KH, page, D)
-    v_pages: jax.Array,        # (P, KH, page, D)
-    block_tables: jax.Array,   # (B, NP)
-    context_lens: jax.Array,   # (B,) — includes the new token
-    *,
-    window: int = 0,
-    softcap: float = 0.0,
-) -> jax.Array:
-    b, h, d = q.shape
-    q_pos = (context_lens - 1)[:, None]
-    out = msa_prefill_ref(
-        q[:, None], k_pages, v_pages, block_tables, context_lens,
-        q_pos, jnp.ones((b,), jnp.int32), window=window, softcap=softcap)
-    return out[:, 0]
 
 
 def write_kv_pages(

@@ -1,20 +1,16 @@
 """Inference engine: one jitted device step executing a mixed batch of
 multi-segment prefill chunks and decode tokens (paper §4.1/§5.3).
 
-All prefill chunks and decode rows share one token stream for the
-non-attention layers (paper: "hidden states of two segments can directly
-be concatenated when computing MLP and LayerNorm"), and — in the default
-``attn_mode="fused"`` — attention runs as **one** kernel dispatch per
-layer over the same paged KV pool: the flattened varlen ``(T, H, D)``
-stream with per-sequence ``q_start``/``q_len`` runs replaces the padded
-``(R, QP, H, D)`` prefill layout, and decode rows are simply runs of
-length 1 (the single fused dispatch the paper identifies as essential,
-Fig. 13).  ``attn_mode="split"`` keeps the original two-dispatch layout
-(padded MSA prefill + paged flash-decode) as the tested baseline.
+All prefill chunks and decode rows share one flattened varlen ``(T, H,
+D)`` token stream (paper: "hidden states of two segments can directly be
+concatenated when computing MLP and LayerNorm"): prefill chunks pack
+densely at its head with per-sequence ``q_start``/``q_len`` runs, decode
+rows follow as runs of length 1, and attention runs as **one**
+``msa_fused`` dispatch per layer over the paged KV pool (the single fused
+dispatch the paper identifies as essential, Fig. 13).
 
-Step shapes are static per **occupancy bucket**: instead of one maximal
-``(R, QP, B, NP)`` compile shape, the fused layout compiles once per
-``(t_bucket, np_bucket)`` drawn from a small lattice (default
+Step shapes are static per **occupancy bucket**: the step compiles once
+per ``(t_bucket, np_bucket)`` drawn from a small lattice (default
 ``T ∈ {B, Tmax/16, Tmax/8, Tmax/4, Tmax/2, Tmax}`` ×
 ``NP ∈ {NPmax/4, NPmax}``), selected
 per step by the scheduler from its §5.1 chunk decision — decode-only
@@ -40,11 +36,9 @@ Overlapped pipeline support (one-step-deep, see docs/ARCHITECTURE.md):
     step as padded ``(src, dst)`` index arrays; overflow past the static
     buckets falls back to the eager paths so shapes stay static.
 
-Deterministic accounting (host wall-clock drifts on shared CPU
-containers, so the fused-dispatch win is gated on exact counters, see
-``benchmarks/kernel_fusion.py``): the engine counts attention dispatches
-(``L`` fused vs ``2L`` split per step), valid vs total token rows
-(padded-token fraction), and per-bucket step counts.
+Deterministic accounting: the engine counts attention dispatches (``L``
+per fused iteration), valid vs total token rows (padded-token fraction),
+and per-bucket step counts.
 
 Sharded multi-device mode (``mesh`` argument): the KV page pools shard
 over the mesh's ``model`` axis into contiguous runs of ``num_pages / n``
@@ -56,14 +50,15 @@ shard scatters the new tokens it owns, computes the attention partial
 over its local pages only, and the partials merge through the exact
 log-sum-exp combine (``repro.distributed.flash_decode``) — the
 distributed generalization of Multi-Segment Attention, each shard's
-pages being one segment subset.  In-step COW copies and swap-ins carry
-per-shard queues (cross-shard copies fall back to the eager global-view
-path).  The occupancy-bucket jit cache is unchanged:
+pages being one segment subset.  The layer body is the single-device
+one; only its write-and-attend step differs.  In-step COW copies and
+swap-ins carry per-shard queues (cross-shard copies fall back to the
+eager global-view path).  The occupancy-bucket jit cache is unchanged:
 ``jit_traces == len(buckets_used)`` holds under ``shard_map`` too.
 
 Engine scope: decoder-only token LMs (dense / MoE / sliding-window mixes).
-SSM-family archs have no evictable KV cache (DESIGN.md §Arch-applicability)
-and are served by the dense decode path in ``repro.models`` instead.
+SSM-family archs have no evictable KV cache and are served by the dense
+decode path in ``repro.models`` instead.
 """
 from __future__ import annotations
 
@@ -82,9 +77,7 @@ from repro.kernels.msa import (
     apply_swap_ins,
     block_pages,
     build_worklist,
-    msa_decode,
     msa_fused,
-    msa_prefill,
     pad_worklist,
     write_kv_pages,
 )
@@ -185,10 +178,8 @@ def write_and_attend(k_pools, v_pools, layer: int, q, k_new, v_new,
     """A fused single-device layer's KV write and its ONE ``msa_fused``
     dispatch, which reads ``layer`` of the stacked pools in place: no
     layer's pool is ever sliced out into a buffer of its own.  ``f``
-    holds one fused iteration's step fields.  ``Engine._step_impl`` and
-    ``Engine._fused_pass`` both run this, so a k-token decode repeats the
-    single step's ops exactly.  Returns the pools and the attention
-    output."""
+    holds one fused iteration's step fields.  Returns the pools and the
+    attention output."""
     with jax.named_scope("kv_write"):
         k_pools, v_pools = write_layer_pages(
             k_pools, v_pools, layer, k_new, v_new, f["write_page"],
@@ -212,16 +203,13 @@ def derive_bucket_lattice(ecfg: "EngineConfig"
     pools — the two must never disagree, or the auditor's predicted
     trace-key set stops matching ``jit_traces``.
 
-    Fused mode: a decode-full bucket (decode-only steps are the
+    Token buckets: a decode-full bucket (decode-only steps are the
     continuous-batching common case — at full decode occupancy that
     bucket carries no padding at all) plus power-of-two fractions of
-    Tmax down to Tmax/16; split mode compiles exactly once at
-    ``(t_max, NP)``."""
+    Tmax down to Tmax/16."""
     R, QP, B, NP = (ecfg.max_prefills, ecfg.max_chunk,
                     ecfg.max_decodes, ecfg.max_blocks_per_seq)
     t_max = R * QP + B
-    if ecfg.attn_mode != "fused":
-        return (t_max,), (NP,)
     tb = ecfg.token_buckets or (
         max(8, _round_up(B, 8)),
         max(8, _round_up(t_max // 16, 8)),
@@ -247,7 +235,7 @@ def pack_layout_for(ecfg: "EngineConfig", n_shards: int, t_bucket: int,
     bucket's host->device transfer without an :class:`Engine`;
     ``Engine.pack_layout`` delegates here (with a per-engine cache).
 
-    Multi-token decode plans (``n_iter > 1``, fused layout only) carry
+    Multi-token decode plans (``n_iter > 1``) carry
     PER-ITERATION copies of the fields that change between the fused
     decode iterations (tokens/positions/valid/write coords/ctx/qlen and
     the Pallas work-list); the sequence-row structure
@@ -259,30 +247,21 @@ def pack_layout_for(ecfg: "EngineConfig", n_shards: int, t_bucket: int,
     # (shard-LOCAL page indices); single-device keeps the flat layout
     C = n_shards * e.max_instep_copies
     S = n_shards * e.max_instep_swaps
-    if e.attn_mode == "fused":
-        t, n, k = t_bucket, R + B, n_iter
-        fields = [("tokens", k * t), ("positions", k * t),
-                  ("valid", k * t), ("write_slot", k * t),
-                  ("write_off", k * t),
-                  ("write_page", k * kv_write_capacity(e, t)),
-                  ("write_row", k * t), ("seq_ids", t),
-                  ("sel", R + B), ("qstart", n), ("qlen", k * n),
-                  ("ctx", k * n), ("bt", n * np_bucket)]
-        # wl_slot carries block_pages(page) pool slots per item
-        nb = block_pages(e.page_size)
-        w = min(w_bucket, wl_capacity(e, t_bucket, np_bucket))
-        fields += [(f, k * w * (nb if f == "wl_slot" else 1))
-                   for f in WL_FIELDS]
-        fields += [("copy_src", C), ("copy_dst", C),
-                   ("swap_k_dst", S), ("swap_v_dst", S)]
-    else:
-        t, NP = R * e.max_chunk + B, e.max_blocks_per_seq
-        fields = [("tokens", t), ("positions", t), ("valid", t),
-                  ("write_slot", t), ("write_off", t), ("sel", R + B),
-                  ("qlens", R), ("ctx_pre", R), ("ctx_dec", B),
-                  ("bt_pre", R * NP), ("bt_dec", B * NP),
-                  ("copy_src", C), ("copy_dst", C),
-                  ("swap_k_dst", S), ("swap_v_dst", S)]
+    t, n, k = t_bucket, R + B, n_iter
+    fields = [("tokens", k * t), ("positions", k * t),
+              ("valid", k * t), ("write_slot", k * t),
+              ("write_off", k * t),
+              ("write_page", k * kv_write_capacity(e, t)),
+              ("write_row", k * t), ("seq_ids", t),
+              ("sel", R + B), ("qstart", n), ("qlen", k * n),
+              ("ctx", k * n), ("bt", n * np_bucket)]
+    # wl_slot carries block_pages(page) pool slots per item
+    nb = block_pages(e.page_size)
+    w = min(w_bucket, wl_capacity(e, t_bucket, np_bucket))
+    fields += [(f, k * w * (nb if f == "wl_slot" else 1))
+               for f in WL_FIELDS]
+    fields += [("copy_src", C), ("copy_dst", C),
+               ("swap_k_dst", S), ("swap_v_dst", S)]
     layout: List[Tuple[str, int, int]] = []
     off = 0
     for name, size in fields:
@@ -293,6 +272,13 @@ def pack_layout_for(ecfg: "EngineConfig", n_shards: int, t_bucket: int,
 
 @dataclass(frozen=True)
 class EngineConfig:
+    """Static shape and behaviour of one engine.
+
+    Losslessness scope: dense and dropless MoE models.  MoE with
+    dropless=False derives expert capacity from the step's TOTAL row count
+    (padding included), so its drop decisions depend on the occupancy
+    bucket (``moe_ffn_local`` documents dropless=True as required for
+    lossless serving; the model zoo complies)."""
     num_pages: int                 # KV pool pages (= block manager blocks)
     page_size: int = 16
     max_prefills: int = 4          # R
@@ -302,18 +288,7 @@ class EngineConfig:
     # "pallas" (TPU only) | "xla" (oracle) | "pallas_interpret" (CPU)
     attn_impl: str = "xla"
     q_tile: int = 128
-    # "fused": one varlen attention dispatch per layer over the flattened
-    # (T, H, D) mixed stream, with the occupancy bucket lattice.
-    # "split": the original padded two-dispatch layout (prefill + decode),
-    # kept as the byte-identical baseline benchmarks compare against.
-    # Byte-identity scope: dense and dropless MoE models.  MoE with
-    # dropless=False derives expert capacity from the step's TOTAL row
-    # count (padding included), so its drop decisions depend on the
-    # compile shape — already lossy under the split layout, and
-    # bucket-dependent under fused (moe_ffn_local documents dropless=True
-    # as required for lossless serving; the model zoo complies).
-    attn_mode: str = "fused"
-    # occupancy bucket lattices (fused mode).  Empty tuples derive the
+    # occupancy bucket lattices.  Empty tuples derive the
     # defaults {B, Tmax//16, Tmax//8, Tmax//4, Tmax//2, Tmax} (B = a
     # decode-full bucket) and {NPmax//4, NPmax}; the maximal bucket is
     # always included so every legal plan fits.
@@ -340,17 +315,11 @@ class EngineConfig:
     # exactly because the snap is part of the deterministic write path).
     snap: str = "off"
     snap_scale: float = 0.0
-    # "vectorized": numpy scatters over per-request cached arrays;
-    # "legacy": the original per-token Python loops, kept as the reference
-    # implementation the vectorized path is tested against and as the
-    # synchronous-baseline control plane in benchmarks/pipeline.py.
-    # Legacy assembly implies the split attention layout.
-    assembly: str = "vectorized"
-    # True restores the pre-pipeline device interface: the step returns
-    # the full (R+B, V) logits and StepHandle.block() transfers them all
-    # to the host — the per-step sync the paper's §5.3 overlap removes.
-    # False (default) keeps sampling on device: only (R+B,) token ids and
-    # the (R, V) prefill rows ever leave it.
+    # True makes the step return the full (R+B, V) logits and
+    # StepHandle.block() transfer them all to the host (the server sets
+    # it for record_decode_logits).  False (default) keeps sampling on
+    # device: only (R+B,) token ids and the (R, V) prefill rows ever
+    # leave it.
     return_full_logits: bool = False
     # buffer-donate the KV pools into the step.  Donation halves pool
     # memory (XLA aliases input to output) and avoids a full pool copy at
@@ -371,9 +340,8 @@ class StepHandle:
     Holds device arrays; nothing is transferred until the ``*_np``
     accessors run, so the server can keep assembling the next step while
     this one executes.  ``block`` waits for the device — and when the
-    engine runs with ``return_full_logits`` (the synchronous baseline
-    interface) it also performs the full (R+B, V) host transfer the
-    pre-pipeline loop paid every step."""
+    engine runs with ``return_full_logits`` it also transfers the full
+    (R+B, V) logits to the host."""
     token_ids: jax.Array           # (R+B,) device-side greedy samples
     prefill_logits: jax.Array      # (R, V) rows ((R+B, V) full-logits mode)
     full_logits: bool = False
@@ -385,7 +353,7 @@ class StepHandle:
 
     def block(self) -> None:
         if self.full_logits:
-            self.prefill_logits_np()   # the legacy full-vocab transfer
+            self.prefill_logits_np()   # the full-vocab transfer
             self.token_ids_np()
         else:
             jax.block_until_ready((self.token_ids, self.prefill_logits))
@@ -406,7 +374,6 @@ class Engine:
                  mesh=None):
         assert cfg.family in ("dense", "moe", "vlm"), cfg.family
         assert not cfg.enc_dec
-        assert ecfg.attn_mode in ("fused", "split"), ecfg.attn_mode
         assert ecfg.attn_impl in ("xla", "pallas", "pallas_interpret"), \
             ecfg.attn_impl
         if ecfg.attn_impl == "pallas" and jax.default_backend() != "tpu":
@@ -414,8 +381,6 @@ class Engine:
             raise ValueError(
                 "attn_impl='pallas' compiles the TPU kernel; this backend is "
                 f"{jax.default_backend()!r} (use 'xla' or 'pallas_interpret')")
-        if ecfg.assembly == "legacy" and ecfg.attn_mode != "split":
-            raise ValueError("legacy assembly implies attn_mode='split'")
         self.cfg = cfg
         self.ecfg = ecfg
         self.mesh = mesh
@@ -451,15 +416,11 @@ class Engine:
         self.v_pools = jnp.zeros_like(self.k_pools)
         in_shardings = None
         if self.n_shards > 1:
-            # sharded serving: fused varlen layout only (the split padded
-            # layout predates the work-list/seq_ids metadata the per-shard
-            # partial needs), xla oracle impl (Pallas-on-mesh is a TPU
+            # sharded serving: xla oracle impl (Pallas-on-mesh is a TPU
             # deployment concern, not a CPU-host-device validation one)
-            assert ecfg.attn_mode == "fused", "sharded engine requires fused"
             assert self._payload_fmt == "fp" and self._snap_mode == "off", \
                 "quantized offload requires the single-device engine"
             assert ecfg.attn_impl == "xla", "sharded engine requires xla impl"
-            assert ecfg.assembly == "vectorized"
             assert ecfg.num_pages % self.n_shards == 0, \
                 (ecfg.num_pages, self.n_shards)
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -491,7 +452,7 @@ class Engine:
         # engine): dispatch marks its assemble and launch phases
         self.tracer: Optional[ServeTracer] = None
         # trace counter: must equal len(buckets_used) — the
-        # compile-once-per-bucket invariant (== 1 in split mode)
+        # compile-once-per-bucket invariant
         self.jit_traces = 0
         self.buckets_used: set = set()
         self._pending_copies: List[Tuple[int, int]] = []
@@ -527,7 +488,7 @@ class Engine:
         self.token_buckets, self.np_buckets = derive_bucket_lattice(ecfg)
         self._t_bucket_set = set(self.token_buckets)
         self._np_bucket_set = set(self.np_buckets)
-        # deterministic accounting (benchmarks/kernel_fusion.py gates)
+        # deterministic accounting
         self.attn_dispatches = 0       # per-layer attention kernel launches
         self.valid_token_rows = 0      # real compute tokens executed
         self.total_token_rows = 0      # token rows incl. bucket padding
@@ -548,17 +509,15 @@ class Engine:
         # (and iterations): each layer's KV write moves this many pages
         self.kv_pages_written = 0
         # multi-token decode dispatch + decode-phase accounting
-        # (benchmarks/control_plane_stress.py gates the ≥3x dispatch
-        # drop on decode-dominated segments with these)
         self.decode_only_dispatches = 0    # dispatches with no prefill chunk
         self.decode_tokens_emitted = 0     # decode tokens across iterations
         self.multi_token_dispatches = 0    # dispatches with k > 1
         self.multi_token_iterations = 0    # sum of k over those
         self.multi_token_rollbacks = 0     # masked (unconsumed) iterations
         self.k_counts: Dict[int, int] = {}
-        # packed-input layouts (vectorized assembly): every int32 input in
-        # one flat host buffer -> ONE device_put per step instead of ~14;
-        # one layout per (t_bucket, np_bucket, w_bucket, n_iter)
+        # packed-input layouts: every int32 input in one flat host buffer
+        # -> ONE device_put per step instead of ~14; one layout per
+        # (t_bucket, np_bucket, w_bucket, n_iter)
         self._layouts: Dict[Tuple[int, int, int, int],
                             Tuple[List[Tuple[str, int, int]], int]] = {}
 
@@ -586,8 +545,6 @@ class Engine:
         shared SchedulerConfig, a stale too-small bucket) falls back to
         the smallest fitting own-lattice entry — so the jit cache can
         never grow off-lattice variants and a legal plan always fits."""
-        if self.ecfg.attn_mode != "fused":
-            return self.t_max, self.ecfg.max_blocks_per_seq
         need_t = plan.n_compute_tokens
         tb = plan.t_bucket
         if tb not in self._t_bucket_set or tb < need_t:
@@ -615,16 +572,26 @@ class Engine:
     def _step_impl(self, params, k_pools, v_pools, inp,
                    t_bucket: int, np_bucket: int, w_bucket: int,
                    n_iter: int = 1):
+        """One step: the in-step page ops, then ``n_iter`` fused forward
+        passes (one for a mixed prefill/decode step, k for a multi-token
+        decode plan), each followed by its device-side greedy sample.
+
+        Iteration ``i > 0`` of a k-token decode feeds each row the
+        host-forced id when ≥ 0, else (sentinel -1) iteration ``i-1``'s
+        sample for that row — device sampling feeding the next token
+        without leaving the device.  The scripted serving loop always
+        forces, so runs stay teacher-forced and byte-comparable to k=1.
+        Iterations at or past a request's ``decode_iters`` are masked out
+        on device (valid 0: no KV write; qlen 0: no attention row) and
+        their sampled ids are rolled back on the host by never being
+        consumed."""
         # repro: allow(jit-hazard) — intentional trace-time-only side
         # effect: counts compiled step variants for the
         # compile-once-per-bucket gate; never traced into the graph
         self.jit_traces += 1
         cfg, e = self.cfg, self.ecfg
-        if e.assembly != "legacy":
-            # trace-time slicing of the pack into named views
-            inp = self._unpack(inp, t_bucket, np_bucket, w_bucket, n_iter)
-        R, QP, B = e.max_prefills, e.max_chunk, e.max_decodes
-        fused = e.attn_mode == "fused"
+        # trace-time slicing of the pack into named views
+        inp = self._unpack(inp, t_bucket, np_bucket, w_bucket, n_iter)
 
         # in-step page maintenance: swap-ins land first (they commit pages
         # a COW fork in the same round may use as its donor), then copies;
@@ -646,102 +613,32 @@ class Engine:
                 k_pools, v_pools = apply_page_copies(
                     k_pools, v_pools, inp["copy_src"], inp["copy_dst"])
 
-        if n_iter > 1:
-            # multi-token decode dispatch: k fused decode iterations
-            # inside this one jitted call (single-device fused layout
-            # only — build_inputs enforces it)
-            return self._multi_decode_steps(
-                params, k_pools, v_pools, inp, t_bucket, n_iter)
-
-        with jax.named_scope("embed"):
-            x = params["embed"][inp["tokens"]]      # (T, d)
-        pos = inp["positions"]
-
-        impl = e.attn_impl
-        if fused:
-            worklist = None
-            if impl != "xla":
-                worklist = tuple(inp[f] for f in WL_FIELDS)
-            tq = min(e.q_tile, t_bucket)
-        else:
-            RQP = R * QP
-            qpos_pre = pos[:RQP].reshape(R, QP)
-        for l in range(cfg.n_layers):
-            blk = jax.tree_util.tree_map(lambda a: a[l], params["blocks"])
-            window = self.windows[l]
-            with jax.named_scope("qkv"):
-                h = rms_norm(x, blk["attn_norm"], cfg.norm_eps)
-                q = jnp.einsum("td,dhk->thk", h, blk["wq"])
-                k_new = jnp.einsum("td,dhk->thk", h, blk["wk"])
-                v_new = jnp.einsum("td,dhk->thk", h, blk["wv"])
-                if cfg.rope_theta > 0:
-                    q = apply_rope(q, pos, cfg.rope_theta)
-                    k_new = apply_rope(k_new, pos, cfg.rope_theta)
-                k_new = self._snap(k_new)
-                v_new = self._snap(v_new)
-            if self.n_shards > 1:
-                # per-shard KV write + attention partial + exact LSE
-                # merge, one shard_map per layer (still ONE logical
-                # attention dispatch — each shard computes its segment
-                # subset of the same fused varlen stream)
-                from repro.distributed.flash_decode import sharded_msa_fused
-                with jax.named_scope("attention"):
-                    kp, vp, attn = sharded_msa_fused(
-                        q, k_pools[l], v_pools[l], k_new, v_new,
-                        inp["write_slot"], inp["write_off"], inp["valid"],
-                        inp["bt"], inp["ctx"], pos, inp["seq_ids"],
-                        mesh=self.mesh, window=window,
-                        softcap=cfg.attn_logit_softcap)
-                    x = x + jnp.einsum("thk,hkd->td", attn, blk["wo"])
-                with jax.named_scope("kv_write"):
-                    k_pools = k_pools.at[l].set(kp)
-                    v_pools = v_pools.at[l].set(vp)
-                with jax.named_scope("mlp"):
-                    x = self._mlp_sublayer(x, blk)
-                continue
-            if fused:
-                # ONE varlen dispatch over the whole mixed stream
-                k_pools, v_pools, attn = write_and_attend(
-                    k_pools, v_pools, l, q, k_new, v_new, inp, worklist,
-                    window=window, softcap=cfg.attn_logit_softcap,
-                    q_tile=tq, impl=impl)
-            else:
-                with jax.named_scope("kv_write"):
-                    kp, vp = write_kv_pages(
-                        k_pools[l], v_pools[l], k_new, v_new,
-                        inp["write_slot"], inp["write_off"], inp["valid"])
-                    k_pools = k_pools.at[l].set(kp)
-                    v_pools = v_pools.at[l].set(vp)
-                with jax.named_scope("attention"):
-                    qp_ = q[:RQP].reshape(R, QP, cfg.n_heads, cfg.head_dim)
-                    op = msa_prefill(
-                        qp_, kp, vp, inp["bt_pre"], inp["ctx_pre"], qpos_pre,
-                        inp["qlens"], window=window,
-                        softcap=cfg.attn_logit_softcap,
-                        q_tile=min(e.q_tile, QP), impl=impl)
-                    od = msa_decode(
-                        q[RQP:], kp, vp, inp["bt_dec"], inp["ctx_dec"],
-                        window=window, softcap=cfg.attn_logit_softcap,
-                        impl=impl)
-                    attn = jnp.concatenate(
-                        [op.reshape(RQP, cfg.n_heads, cfg.head_dim), od],
-                        axis=0)
-            with jax.named_scope("attention"):
-                x = x + jnp.einsum("thk,hkd->td", attn, blk["wo"])
-            with jax.named_scope("mlp"):
-                x = self._mlp_sublayer(x, blk)
-
-        with jax.named_scope("logits"):
-            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-            head = (params["embed"].T if cfg.tie_embeddings
-                    else params["lm_head"])
-            logits = x[inp["sel"]] @ head            # (R+B, V)
-            # device-side greedy sampling: only (R+B,) ids and the R
-            # prefill rows (losslessness checks) ever leave the device —
-            # unless the legacy full-logits interface is requested for
-            # A/B baselines
-            token_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out_logits = logits if e.return_full_logits else logits[:R]
+        ids_steps = []
+        ids = out_logits = None
+        for i in range(n_iter):
+            f = inp if n_iter == 1 else {
+                name: (a[i] if name in PER_ITER_FIELDS else a)
+                for name, a in inp.items()}
+            tokens = f["tokens"]
+            if ids is not None:
+                tokens = jnp.maximum(
+                    jnp.where(tokens >= 0, tokens, ids[inp["seq_ids"]]), 0)
+            k_pools, v_pools, x = self._fused_pass(
+                params, k_pools, v_pools, tokens, f, t_bucket)
+            with jax.named_scope("logits"):
+                x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+                head = (params["embed"].T if cfg.tie_embeddings
+                        else params["lm_head"])
+                logits = x[inp["sel"]] @ head            # (R+B, V)
+                # device-side greedy sampling: only (R+B,) ids and the R
+                # prefill rows (losslessness checks) leave the device,
+                # unless full logits are requested
+                ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                out_logits = (logits if e.return_full_logits
+                              else logits[:e.max_prefills])
+            ids_steps.append(ids)
+        # (R+B,) for one iteration, (n_iter, R+B) for a k-token decode
+        token_ids = ids if n_iter == 1 else jnp.stack(ids_steps)
         return token_ids, out_logits, k_pools, v_pools
 
     def _snap(self, x):
@@ -773,20 +670,20 @@ class Engine:
             y = swiglu_mlp(h2, blk["w1"], blk["w3"], blk["w2"])
         return x + y
 
-    def _fused_pass(self, params, k_pools, v_pools, tokens, f, worklist,
+    def _fused_pass(self, params, k_pools, v_pools, tokens, f,
                     t_bucket: int):
-        """One fused single-device forward over a varlen token stream
-        (``f``: one iteration's step fields): per-layer
-        ``write_and_attend`` — the body a multi-token decode iteration
-        repeats, op-for-op the same math as the ``n_iter == 1`` fused
-        branch of ``_step_impl`` (the k-vs-1 byte-identity the
-        benchmarks gate depends on it).  Returns the updated pools and
-        the pre-final-norm residual."""
+        """One forward over a varlen token stream (``f``: one fused
+        iteration's step fields): per layer the projections, the KV write
+        and the ONE attention dispatch, then the MLP.  A single device
+        writes and attends with ``write_and_attend``, a mesh with
+        ``_write_and_attend_sharded``.  Returns the updated pools and the
+        pre-final-norm residual."""
         cfg, e = self.cfg, self.ecfg
-        tq = min(e.q_tile, t_bucket)
         pos = f["positions"]
+        worklist = (None if e.attn_impl == "xla"
+                    else tuple(f[name] for name in WL_FIELDS))
         with jax.named_scope("embed"):
-            x = params["embed"][tokens]
+            x = params["embed"][tokens]                 # (T, d)
         for l in range(cfg.n_layers):
             blk = jax.tree_util.tree_map(lambda a: a[l], params["blocks"])
             window = self.windows[l]
@@ -800,132 +697,102 @@ class Engine:
                     k_new = apply_rope(k_new, pos, cfg.rope_theta)
                 k_new = self._snap(k_new)
                 v_new = self._snap(v_new)
-            k_pools, v_pools, attn = write_and_attend(
-                k_pools, v_pools, l, q, k_new, v_new, f, worklist,
-                window=window, softcap=cfg.attn_logit_softcap, q_tile=tq,
-                impl=e.attn_impl)
+            if self.n_shards > 1:
+                k_pools, v_pools, attn = self._write_and_attend_sharded(
+                    k_pools, v_pools, l, q, k_new, v_new, f, window)
+            else:
+                k_pools, v_pools, attn = write_and_attend(
+                    k_pools, v_pools, l, q, k_new, v_new, f, worklist,
+                    window=window, softcap=cfg.attn_logit_softcap,
+                    q_tile=min(e.q_tile, t_bucket), impl=e.attn_impl)
             with jax.named_scope("attention"):
                 x = x + jnp.einsum("thk,hkd->td", attn, blk["wo"])
             with jax.named_scope("mlp"):
                 x = self._mlp_sublayer(x, blk)
         return k_pools, v_pools, x
 
-    def _multi_decode_steps(self, params, k_pools, v_pools, inp,
-                            t_bucket: int, n_iter: int):
-        """k sequential fused decode iterations inside ONE jitted call
-        (trace-time Python loop → one XLA program, one host dispatch).
-
-        Each iteration's input token is the host-forced id when ≥ 0, else
-        (sentinel -1) the previous iteration's device-side greedy sample
-        for that row — device sampling feeding the next token without
-        leaving the device.  The scripted serving loop always forces, so
-        runs stay teacher-forced and byte-comparable to k=1.  Iterations
-        at or past a request's ``decode_iters`` are masked out on device
-        (valid 0: no KV write; qlen 0: no attention row) and their
-        sampled ids are rolled back on the host by never being consumed."""
-        cfg, e = self.cfg, self.ecfg
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        seq_ids = inp["seq_ids"]
-        ids_steps = []
-        prev = logits = None
-        for i in range(n_iter):
-            tok = inp["tokens"][i]
-            if prev is not None:
-                tok = jnp.where(tok >= 0, tok, prev[seq_ids])
-            f = {name: (a[i] if name in PER_ITER_FIELDS else a)
-                 for name, a in inp.items()}
-            k_pools, v_pools, x = self._fused_pass(
-                params, k_pools, v_pools, jnp.maximum(tok, 0), f,
-                None if e.attn_impl == "xla"
-                else tuple(f[name] for name in WL_FIELDS),
-                t_bucket)
-            with jax.named_scope("logits"):
-                x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-                logits = x[inp["sel"]] @ head        # (R+B, V)
-                ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            ids_steps.append(ids)
-            prev = ids
-        token_ids = jnp.stack(ids_steps)             # (n_iter, R+B)
-        out_logits = (logits if e.return_full_logits
-                      else logits[:e.max_prefills])
-        return token_ids, out_logits, k_pools, v_pools
+    def _write_and_attend_sharded(self, k_pools, v_pools, layer: int, q,
+                                  k_new, v_new, f: Dict[str, jax.Array],
+                                  window: int):
+        """The mesh's write-and-attend: per-shard KV write, attention
+        partial over the shard's local pages and exact LSE merge, in one
+        ``shard_map`` per layer (still ONE logical attention dispatch —
+        each shard computes its segment subset of the same fused varlen
+        stream)."""
+        from repro.distributed.flash_decode import sharded_msa_fused
+        with jax.named_scope("attention"):
+            kp, vp, attn = sharded_msa_fused(
+                q, k_pools[layer], v_pools[layer], k_new, v_new,
+                f["write_slot"], f["write_off"], f["valid"], f["bt"],
+                f["ctx"], f["positions"], f["seq_ids"], mesh=self.mesh,
+                window=window, softcap=self.cfg.attn_logit_softcap)
+        with jax.named_scope("kv_write"):
+            k_pools = k_pools.at[layer].set(kp)
+            v_pools = v_pools.at[layer].set(vp)
+        return k_pools, v_pools, attn
 
     # ------------------------------------------------------------------
     def build_inputs(self, plan: StepPlan):
         """Host-side assembly of the padded device arrays for one step.
 
         Returns ``(inp, (t_bucket, np_bucket, w_bucket))`` — the static
-        bucket dims select the jit variant.  The vectorized path
-        assembles every int32 field directly into named views of ONE
-        flat host buffer and transfers it with a single ``device_put``
-        (plus the two swap-payload buffers); the per-field transfers of
-        the legacy path cost more host time per step than the arrays
-        they move."""
+        bucket dims select the jit variant.  Every int32 field is
+        assembled directly into named views of ONE flat host buffer and
+        transferred with a single ``device_put`` (plus the two
+        swap-payload buffers)."""
         t_b, np_b = self.buckets_for(plan)
         n_it = plan.decode_steps
-        if n_it > 1 and (self.ecfg.attn_mode != "fused"
-                         or self.n_shards > 1
-                         or self.ecfg.assembly == "legacy"):
+        if n_it > 1 and self.n_shards > 1:
             raise ValueError("multi-token decode dispatch requires the "
-                             "fused single-device vectorized layout")
-        if self.ecfg.assembly == "legacy":
-            out = self._assemble_legacy(plan)
-            out.update(self._fold_page_ops())
-            return ({k: jnp.asarray(v) for k, v in out.items()},
-                    (t_b, np_b, 0))
-        fused = self.ecfg.attn_mode == "fused"
+                             "single-device engine")
+        # one derivation of the varlen metadata feeds BOTH the packed
+        # buffer and (Pallas impls) the work-list builder
+        if n_it > 1:
+            fields = self._assemble_fused_multi(plan, t_b, np_b, n_it)
+        else:
+            fields = self._assemble_fused(plan, t_b, np_b)
+        self.kv_pages_written += int(np.count_nonzero(
+            fields["write_page"] < self.ecfg.num_pages))
         w_b = 0
-        fields = wls = None
-        if fused:
-            # one derivation of the varlen metadata feeds BOTH the packed
-            # buffer and (Pallas impls) the work-list builder
-            if n_it > 1:
-                fields = self._assemble_fused_multi(plan, t_b, np_b, n_it)
-            else:
-                fields = self._assemble_fused(plan, t_b, np_b)
-            self.kv_pages_written += int(np.count_nonzero(
-                fields["write_page"] < self.ecfg.num_pages))
-            if self.ecfg.attn_impl != "xla":
-                # one work-list per fused iteration (n_it == 1: exactly
-                # the single-step list), all padded to one shared W so
-                # the bucket key stays (t, np, w, k)
-                tq = min(self.ecfg.q_tile, t_b)
-                per_it = (lambda a, i: a[i] if n_it > 1 else a)
-                wls = []
-                items = pages = 0
-                with (self.tracer.worklist() if self.tracer is not None
-                      else contextlib.nullcontext()):
-                    for i in range(n_it):
-                        wl, n_items, n_pages = build_worklist(
-                            fields["qstart"], per_it(fields["qlen"], i),
-                            per_it(fields["ctx"], i), fields["bt"],
-                            per_it(fields["positions"], i),
-                            page=self.ecfg.page_size, q_tile=tq,
-                            n_tiles=-(-t_b // tq), window=0)
-                        wls.append(wl)
-                        items += n_items
-                        pages += n_pages
-                if self.tracer is not None:
-                    self.tracer.worklist_built(items, pages)
-                # power-of-two W buckets keep the per-W jit variants at
-                # most log2(Wmax) many
-                w_b = max(WL_BUCKET, 1 << (max(
-                    wl["wl_seq"].shape[0] for wl in wls) - 1).bit_length())
-                wls = [pad_worklist(wl, w_b, sentinel_seq=self.n_seqs)
-                       for wl in wls]
+        wls = None
+        if self.ecfg.attn_impl != "xla":
+            # one work-list per fused iteration (n_it == 1: exactly the
+            # single-step list), all padded to one shared W so the bucket
+            # key stays (t, np, w, k)
+            tq = min(self.ecfg.q_tile, t_b)
+            per_it = (lambda a, i: a[i] if n_it > 1 else a)
+            wls = []
+            items = pages = 0
+            with (self.tracer.worklist() if self.tracer is not None
+                  else contextlib.nullcontext()):
+                for i in range(n_it):
+                    wl, n_items, n_pages = build_worklist(
+                        fields["qstart"], per_it(fields["qlen"], i),
+                        per_it(fields["ctx"], i), fields["bt"],
+                        per_it(fields["positions"], i),
+                        page=self.ecfg.page_size, q_tile=tq,
+                        n_tiles=-(-t_b // tq), window=0)
+                    wls.append(wl)
+                    items += n_items
+                    pages += n_pages
+            if self.tracer is not None:
+                self.tracer.worklist_built(items, pages)
+            # power-of-two W buckets keep the per-W jit variants at most
+            # log2(Wmax) many
+            w_b = max(WL_BUCKET, 1 << (max(
+                wl["wl_seq"].shape[0] for wl in wls) - 1).bit_length())
+            wls = [pad_worklist(wl, w_b, sentinel_seq=self.n_seqs)
+                   for wl in wls]
         layout, size = self.pack_layout(t_b, np_b, w_b, n_it)
         buf = np.zeros((size,), np.int32)
         views = {name: buf[off:off + size_] for name, off, size_ in layout}
-        if fused:
-            for name, arr in fields.items():
-                views[name][:] = arr.reshape(-1)
-            if wls is not None:
-                for f in WL_FIELDS:
-                    dst = views[f].reshape(n_it, -1)
-                    for i, wl in enumerate(wls):
-                        dst[i] = wl[f]
-        else:
-            self._assemble_vectorized(plan, views)
+        for name, arr in fields.items():
+            views[name][:] = arr.reshape(-1)
+        if wls is not None:
+            for f in WL_FIELDS:
+                dst = views[f].reshape(n_it, -1)
+                for i, wl in enumerate(wls):
+                    dst[i] = wl[f]
         ops = self._fold_page_ops(views)
         inp = {"pack": jnp.asarray(buf),
                "swap_k": jnp.asarray(ops["swap_k"]),
@@ -954,16 +821,11 @@ class Engine:
                 ns, e.max_instep_swaps)
             out["swap_v_dst"] = out["swap_v_dst"].reshape(
                 ns, e.max_instep_swaps)
-        if e.attn_mode == "fused":
-            out["bt"] = out["bt"].reshape(self.n_seqs, np_bucket)
-            if n_iter > 1:
-                # per-iteration fields fold out to (k, ·)
-                for f in PER_ITER_FIELDS:
-                    out[f] = out[f].reshape(n_iter, -1)
-        else:
-            R, B, NP = e.max_prefills, e.max_decodes, e.max_blocks_per_seq
-            out["bt_pre"] = out["bt_pre"].reshape(R, NP)
-            out["bt_dec"] = out["bt_dec"].reshape(B, NP)
+        out["bt"] = out["bt"].reshape(self.n_seqs, np_bucket)
+        if n_iter > 1:
+            # per-iteration fields fold out to (k, ·)
+            for f in PER_ITER_FIELDS:
+                out[f] = out[f].reshape(n_iter, -1)
         out["swap_k"] = inp["swap_k"]
         out["swap_v"] = inp["swap_v"]
         if "swap_k_scale" in inp:          # q8 wire format
@@ -1124,135 +986,13 @@ class Engine:
                     seq_ids=seq_ids, sel=sel, qstart=qstart, qlen=qlen,
                     ctx=ctx, bt=bt)
 
-    def _assemble_vectorized(self, plan: StepPlan,
-                             v: Dict[str, np.ndarray]) -> None:
-        """Vectorized assembly of the split (two-dispatch) layout: numpy
-        scatter/gather over per-request arrays cached on ``Request``
-        (``token_array`` / ``slot_array``) into the packed-buffer views
-        ``v``; Python loops run only over requests (≤ R prefills + B
-        decodes), never over tokens."""
-        e = self.ecfg
-        bs = e.page_size
-        R, QP, B, NP = e.max_prefills, e.max_chunk, e.max_decodes, \
-            e.max_blocks_per_seq
-        tokens = v["tokens"]
-        positions = v["positions"]
-        valid = v["valid"]
-        write_slot = v["write_slot"]
-        write_off = v["write_off"]
-        bt_pre = v["bt_pre"].reshape(R, NP)
-        ctx_pre = v["ctx_pre"]
-        qlens = v["qlens"]
-        bt_dec = v["bt_dec"].reshape(B, NP)
-        ctx_dec = v["ctx_dec"]
-        ctx_dec[:] = 1
-        sel = v["sel"]
-
-        assert len(plan.prefills) <= R and len(plan.decodes) <= B
-        for r, chunk in enumerate(plan.prefills):
-            req = chunk.req
-            pos = np.asarray(chunk.positions, np.int32)
-            n = pos.shape[0]
-            assert n <= QP, (n, QP)
-            base = r * QP
-            slots = req.slot_array()
-            tokens[base:base + n] = req.token_array()[pos]
-            positions[base:base + n] = pos
-            valid[base:base + n] = True
-            write_slot[base:base + n] = slots[pos // bs]
-            write_off[base:base + n] = pos % bs
-            qlens[r] = n
-            ctx_pre[r] = pos[-1] + 1
-            k = min(NP, slots.shape[0])
-            bt_pre[r, :k] = slots[:k]
-            sel[r] = base + n - 1
-
-        nd = len(plan.decodes)
-        if nd:
-            p = np.fromiter(
-                (req.prompt_len + len(req.generated) - 1
-                 for req in plan.decodes), np.int32, nd)
-            tokens[R * QP:R * QP + nd] = np.fromiter(
-                (req.generated[-1] for req in plan.decodes), np.int32, nd)
-            positions[R * QP:R * QP + nd] = p
-            valid[R * QP:R * QP + nd] = True
-            write_slot[R * QP:R * QP + nd] = np.fromiter(
-                (req.slot_array()[pi // bs]
-                 for req, pi in zip(plan.decodes, p)), np.int32, nd)
-            write_off[R * QP:R * QP + nd] = p % bs
-            ctx_dec[:nd] = p + 1
-            for i, req in enumerate(plan.decodes):
-                slots = req.slot_array()
-                k = min(NP, slots.shape[0])
-                bt_dec[i, :k] = slots[:k]
-            sel[R:R + nd] = R * QP + np.arange(nd, dtype=np.int32)
-
-    def _assemble_legacy(self, plan: StepPlan) -> Dict[str, np.ndarray]:
-        """Original per-token Python-loop assembly (reference / baseline;
-        split attention layout only)."""
-        e = self.ecfg
-        bs = e.page_size
-        R, QP, B, NP = e.max_prefills, e.max_chunk, e.max_decodes, \
-            e.max_blocks_per_seq
-        T = R * QP + B
-        tokens = np.zeros((T,), np.int32)
-        positions = np.zeros((T,), np.int32)
-        valid = np.zeros((T,), bool)
-        write_slot = np.zeros((T,), np.int32)
-        write_off = np.zeros((T,), np.int32)
-        bt_pre = np.zeros((R, NP), np.int32)
-        ctx_pre = np.zeros((R,), np.int32)
-        qlens = np.zeros((R,), np.int32)
-        bt_dec = np.zeros((B, NP), np.int32)
-        ctx_dec = np.ones((B,), np.int32)
-        sel = np.zeros((R + B,), np.int32)
-
-        assert len(plan.prefills) <= R and len(plan.decodes) <= B
-        for r, chunk in enumerate(plan.prefills):
-            req = chunk.req
-            toks = req.all_tokens
-            n = len(chunk.positions)
-            assert n <= QP, (n, QP)
-            base = r * QP
-            for i, p in enumerate(chunk.positions):
-                tokens[base + i] = toks[p]
-                positions[base + i] = p
-                valid[base + i] = True
-                write_slot[base + i] = req.block_slots[p // bs]
-                write_off[base + i] = p % bs
-            qlens[r] = n
-            ctx_pre[r] = chunk.positions[-1] + 1
-            for b, s in enumerate(req.block_slots[:NP]):
-                bt_pre[r, b] = 0 if s is None else s
-            sel[r] = base + n - 1
-
-        for i, req in enumerate(plan.decodes):
-            p = req.prompt_len + len(req.generated) - 1
-            row = R * QP + i
-            tokens[row] = req.generated[-1]
-            positions[row] = p
-            valid[row] = True
-            write_slot[row] = req.block_slots[p // bs]
-            write_off[row] = p % bs
-            ctx_dec[i] = p + 1
-            for b, s in enumerate(req.block_slots[:NP]):
-                bt_dec[i, b] = 0 if s is None else s
-            sel[R + i] = row
-
-        return dict(
-            tokens=tokens, positions=positions, valid=valid,
-            write_slot=write_slot, write_off=write_off,
-            bt_pre=bt_pre, ctx_pre=ctx_pre, qlens=qlens,
-            bt_dec=bt_dec, ctx_dec=ctx_dec, sel=sel)
-
     def _fold_page_ops(
-            self, views: Optional[Dict[str, np.ndarray]] = None,
-    ) -> Dict[str, np.ndarray]:
+            self, views: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
         """Drain queued COW copies / host-tier swap-ins into padded index
         arrays for the jitted step (swap padding: dst == num_pages,
-        dropped by the scatter); overflow past the static buckets goes
-        eager.  With ``views`` the index fields are written in place into
-        the packed buffer (vectorized path)."""
+        dropped by the scatter), written in place into the packed
+        buffer's ``views``; overflow past the static buckets goes
+        eager."""
         if self.n_shards > 1:
             return self._fold_page_ops_sharded(views)
         e = self.ecfg
@@ -1272,13 +1012,9 @@ class Engine:
         # padding repeats the last real copy (idempotent: sources never
         # alias destinations) or is the identity 0 -> 0 on copy-free steps
         pad_src, pad_dst = copies[-1] if copies else (0, 0)
-        if views is not None:
-            copy_src, copy_dst = views["copy_src"], views["copy_dst"]
-            copy_src[:] = pad_src
-            copy_dst[:] = pad_dst
-        else:
-            copy_src = np.full((C,), pad_src, np.int32)
-            copy_dst = np.full((C,), pad_dst, np.int32)
+        copy_src, copy_dst = views["copy_src"], views["copy_dst"]
+        copy_src[:] = pad_src
+        copy_dst[:] = pad_dst
         for j, (src, dst) in enumerate(copies):
             copy_src[j] = src
             copy_dst[j] = dst
@@ -1320,11 +1056,8 @@ class Engine:
             queue = queue[:S]
         self.instep_swaps += len(queue)
         dst_name = f"swap_{name}_dst"
-        if views is not None:
-            dst = views[dst_name]
-            dst[:] = P
-        else:
-            dst = np.full((S,), P, np.int32)
+        dst = views[dst_name]
+        dst[:] = P
         out = {dst_name: dst}
         key_p, key_s = f"swap_{name}", f"swap_{name}_scale"
         if not queue:
@@ -1366,7 +1099,6 @@ class Engine:
         eager global-view fallback, same as the single-device overflow
         path (the block manager's shard-affine COW placement makes
         cross-shard forks rare, not impossible)."""
-        assert views is not None        # sharded implies vectorized assembly
         e = self.ecfg
         ns = self.n_shards
         ploc = e.num_pages // ns
@@ -1535,9 +1267,8 @@ class Engine:
 
     # ------------------------------------------------------------------
     def perf_counters(self) -> Dict[str, object]:
-        """Deterministic hot-path accounting (gated in
-        benchmarks/kernel_fusion.py — host wall-clock alone is too noisy
-        on shared containers to measure the fused-dispatch win)."""
+        """Deterministic hot-path accounting: counts, not times (host
+        wall clock is too noisy on shared containers)."""
         steps = max(self.steps_executed, 1)
         total = max(self.total_token_rows, 1)
         return {
@@ -1644,11 +1375,10 @@ class Engine:
             tr.launched((t_b, np_b, w_b, k), self.jit_traces > traces)
         self.steps_executed += 1
         self.buckets_used.add((t_b, np_b, w_b, k))
-        fused = self.ecfg.attn_mode == "fused"
-        self.attn_dispatches += self.cfg.n_layers * (k if fused else 2)
+        self.attn_dispatches += self.cfg.n_layers * k
         emitted = plan.emitted_tokens
         self.valid_token_rows += emitted
-        self.total_token_rows += t_b * k if fused else self.t_max
+        self.total_token_rows += t_b * k
         key = (t_b, np_b)
         self.bucket_counts[key] = self.bucket_counts.get(key, 0) + 1
         if plan.decodes and not plan.prefills:

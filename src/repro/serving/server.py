@@ -184,11 +184,6 @@ class ServerConfig:
     # and losslessness tests); 1 = schedule/assemble step N+1 while step N
     # executes (one-step-deep, the paper's §5.3 overlap assumption).
     pipeline_depth: int = 1
-    # attention layout of the default-constructed engine: "fused" = one
-    # varlen dispatch per layer with occupancy-bucketed compile shapes,
-    # "split" = the original padded prefill + decode two-dispatch layout
-    # (the baseline benchmarks/kernel_fusion.py compares against).
-    attn_mode: str = "fused"
     # attention backend of the default-constructed engine: "pallas" is the
     # compiled TPU kernel (raises on any other backend), "xla" the pure-jnp
     # oracle (tests, CPU launcher), "pallas_interpret" the kernel under the
@@ -281,7 +276,7 @@ class AsymCacheServer:
                 max_chunk=scfg.scheduler.max_chunk,
                 max_prefills=scfg.scheduler.max_prefills,
                 max_decodes=scfg.scheduler.max_decodes,
-                attn_mode=scfg.attn_mode, attn_impl=scfg.attn_impl)
+                attn_impl=scfg.attn_impl)
             if scfg.record_decode_logits:
                 ecfg = dataclasses.replace(ecfg, return_full_logits=True)
                 scfg.scheduler.max_decode_steps = 1
@@ -305,10 +300,9 @@ class AsymCacheServer:
             # §5.1 chunk decision — both sides must share one lattice
             self.sched.cfg.token_buckets = self.engine.token_buckets
             self.sched.cfg.page_buckets = self.engine.np_buckets
-            # multi-token decode dispatch is a fused single-device
-            # vectorized-assembly path; other layouts force k = 1
-            if (scfg.n_shards > 1 or ecfg.attn_mode != "fused"
-                    or ecfg.assembly == "legacy"):
+            # multi-token decode dispatch is single-device only; a mesh
+            # forces k = 1
+            if scfg.n_shards > 1:
                 scfg.scheduler.max_decode_steps = 1
             # a queued COW copy / host-tier swap-in targets ONE step
             # boundary's pool state — k-step plans wait for empty queues

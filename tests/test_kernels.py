@@ -1,6 +1,6 @@
-"""Per-kernel validation: Pallas (interpret=True) vs pure-jnp oracles,
-swept over shapes/dtypes, plus hypothesis property tests on the MSA
-contract (multi-segment causal masking)."""
+"""Per-kernel validation: the fused MSA kernel (Pallas interpret=True) vs
+its pure-jnp oracle, swept over shapes/dtypes, plus hypothesis property
+tests on the MSA contract (multi-segment causal masking)."""
 import math
 
 import jax
@@ -9,7 +9,8 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.kernels.msa import msa_decode, msa_prefill, write_kv_pages
+from repro.kernels.msa import (WL_FIELDS, build_worklist, msa_fused,
+                               write_kv_pages)
 from repro.kernels.msa import ref as msa_ref
 from repro.models.layers import (causal_conv1d, causal_conv1d_step,
                                  decode_attention, flash_attention,
@@ -27,65 +28,109 @@ def _tol(dtype):
 
 
 # ---------------------------------------------------------------------------
-# MSA prefill kernel: shape/dtype sweep vs oracle
+# fused MSA kernel: shape/dtype sweeps vs the varlen oracle
 # ---------------------------------------------------------------------------
+
+def _fused_both(q, k_pages, v_pages, bt, ctx, runs, *, t_bucket, page,
+                q_tile, window=0, softcap=0.0):
+    """``msa_fused`` through the oracle and through the Pallas kernel
+    (interpret) over one varlen stream: ``runs`` lists each sequence's
+    logical q positions in stream order, ``t_bucket`` rows in all (rows
+    past the runs are bucket slack)."""
+    q_len = np.asarray([len(p) for p in runs], np.int32)
+    q_start = np.concatenate([[0], np.cumsum(q_len)[:-1]]).astype(np.int32)
+    t = int(q_len.sum())
+    q_pos = np.zeros((t_bucket,), np.int32)
+    q_pos[:t] = np.concatenate(runs)
+    seq_ids = np.zeros((t_bucket,), np.int32)
+    seq_ids[:t] = np.repeat(np.arange(len(runs)), q_len)
+    valid = np.arange(t_bucket) < t
+    tq = min(q_tile, t_bucket)
+    wl, _, _ = build_worklist(q_start, q_len, np.asarray(ctx),
+                              np.asarray(bt), q_pos, page=page, q_tile=tq,
+                              n_tiles=-(-t_bucket // tq), window=window)
+    args = (q, k_pages, v_pages, bt, ctx, jnp.asarray(q_pos),
+            jnp.asarray(seq_ids), jnp.asarray(valid))
+    o_ref = msa_fused(*args, window=window, softcap=softcap, impl="xla")
+    o_pal = msa_fused(*args, q_start=jnp.asarray(q_start),
+                      q_len=jnp.asarray(q_len),
+                      worklist=tuple(jnp.asarray(wl[f]) for f in WL_FIELDS),
+                      window=window, softcap=softcap, q_tile=tq,
+                      impl="pallas_interpret")
+    return o_ref, o_pal
+
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("h,kh,d", [(4, 2, 32), (4, 4, 16), (8, 2, 64)])
 @pytest.mark.parametrize("page,q_tile", [(8, 8), (16, 4)])
 @pytest.mark.parametrize("window,softcap", [(0, 0.0), (12, 0.0), (0, 30.0)])
-def test_msa_prefill_sweep(dtype, h, kh, d, page, q_tile, window, softcap):
-    R, QP, NP, P = 2, 16, 5, 32
+def test_msa_fused_prefill_sweep(dtype, h, kh, d, page, q_tile, window,
+                                 softcap):
+    """Two prefill chunks in one stream: the first runs over two
+    non-contiguous position ranges of a five-page context, the second is
+    ragged (13 of 16 rows) over a short one."""
+    QP, NP, P = 16, 5, 32
     ks = jax.random.split(KEY, 4)
-    q = _rand((R, QP, h, d), ks[0], dtype)
+    runs = [np.r_[3:3 + QP // 2, NP * page - QP // 2:NP * page],
+            np.arange(QP - 3)]
+    t = sum(len(r) for r in runs)
+    q = _rand((t, h, d), ks[0], dtype)
     k_pages = _rand((P, kh, page, d), ks[1], dtype)
     v_pages = _rand((P, kh, page, d), ks[2], dtype)
-    bt = jax.random.randint(ks[3], (R, NP), 0, P).astype(jnp.int32)
+    bt = jax.random.randint(ks[3], (2, NP), 0, P).astype(jnp.int32)
     ctx = jnp.array([NP * page, 2 * page + 3], jnp.int32)
-    q_pos = jnp.stack([
-        jnp.concatenate([jnp.arange(3, 3 + QP // 2),
-                         jnp.arange(NP * page - QP // 2, NP * page)]),
-        jnp.arange(QP),
-    ]).astype(jnp.int32)
-    q_lens = jnp.array([QP, QP - 3], jnp.int32)
-
-    o_ref = msa_prefill(q, k_pages, v_pages, bt, ctx, q_pos, q_lens,
-                        window=window, softcap=softcap, impl="xla")
-    o_pal = msa_prefill(q, k_pages, v_pages, bt, ctx, q_pos, q_lens,
-                        window=window, softcap=softcap, q_tile=q_tile,
-                        impl="pallas_interpret")
-    valid = (jnp.arange(QP)[None, :] < q_lens[:, None])[..., None, None]
-    err = float(jnp.max(jnp.abs(jnp.where(
-        valid, o_ref.astype(jnp.float32) - o_pal.astype(jnp.float32), 0))))
+    o_ref, o_pal = _fused_both(q, k_pages, v_pages, bt, ctx, runs,
+                               t_bucket=t, page=page, q_tile=q_tile,
+                               window=window, softcap=softcap)
+    err = float(jnp.max(jnp.abs(o_ref.astype(jnp.float32)
+                                - o_pal.astype(jnp.float32))))
     assert err < _tol(dtype), err
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("h,kh,d", [(4, 2, 32), (8, 8, 16), (8, 1, 64)])
 @pytest.mark.parametrize("window", [0, 10])
-def test_msa_decode_sweep(dtype, h, kh, d, window):
-    B, NP, P, page = 3, 6, 24, 8
+def test_msa_fused_decode_sweep(dtype, h, kh, d, window):
+    """Three decode rows (runs of one token at ``ctx - 1``) in an
+    8-row decode bucket; the five slack rows come out as zeros."""
+    B, NP, P, page, T = 3, 6, 24, 8, 8
     ks = jax.random.split(KEY, 4)
-    q = _rand((B, h, d), ks[0], dtype)
+    q = _rand((T, h, d), ks[0], dtype)
     k_pages = _rand((P, kh, page, d), ks[1], dtype)
     v_pages = _rand((P, kh, page, d), ks[2], dtype)
     bt = jax.random.randint(ks[3], (B, NP), 0, P).astype(jnp.int32)
-    ctx = jnp.array([NP * page, 17, 1], jnp.int32)
-    o_ref = msa_decode(q, k_pages, v_pages, bt, ctx, window=window, impl="xla")
-    o_pal = msa_decode(q, k_pages, v_pages, bt, ctx, window=window,
-                       impl="pallas_interpret")
+    ctx = np.asarray([NP * page, 17, 1], np.int32)
+    o_ref, o_pal = _fused_both(q, k_pages, v_pages, jnp.asarray(bt),
+                               jnp.asarray(ctx), [[c - 1] for c in ctx],
+                               t_bucket=T, page=page, q_tile=128,
+                               window=window)
     err = float(jnp.max(jnp.abs(o_ref.astype(jnp.float32)
                                 - o_pal.astype(jnp.float32))))
     assert err < _tol(dtype), err
+    assert np.all(np.asarray(o_pal[B:]) == 0)
 
 
 # ---------------------------------------------------------------------------
 # MSA semantics: the paper's Eq. 2 — multi-segment == concatenated attention
 # ---------------------------------------------------------------------------
 
+def _scatter_pages(k_full, v_full, perm, n_slots, page):
+    """Write a contiguous (S, KH, D) context into pool pages ``perm``."""
+    kh, d = k_full.shape[-2:]
+    k_pages = jnp.zeros((n_slots, kh, page, d))
+    v_pages = jnp.zeros((n_slots, kh, page, d))
+    for j, slot in enumerate(perm):
+        k_pages = k_pages.at[slot].set(
+            k_full[0, j * page:(j + 1) * page].transpose(1, 0, 2))
+        v_pages = v_pages.at[slot].set(
+            v_full[0, j * page:(j + 1) * page].transpose(1, 0, 2))
+    return k_pages, v_pages
+
+
 def test_msa_equals_contiguous_attention():
-    """A paged multi-segment context must give bit-identical semantics to
-    ordinary causal attention over the logically contiguous sequence."""
+    """A paged multi-segment context must give the semantics of ordinary
+    causal attention over the logically contiguous sequence, through the
+    varlen oracle and the fused kernel that serves."""
     S, H, KH, D, page = 48, 4, 2, 32, 8
     ks = jax.random.split(KEY, 3)
     k_full = _rand((1, S, KH, D), ks[0], jnp.float32)
@@ -99,17 +144,13 @@ def test_msa_equals_contiguous_attention():
     # paged: scatter KV into shuffled pool pages
     NP = S // page
     perm = np.random.RandomState(0).permutation(16)[:NP]
-    k_pages = jnp.zeros((16, KH, page, D))
-    v_pages = jnp.zeros((16, KH, page, D))
-    for j in range(NP):
-        k_pages = k_pages.at[perm[j]].set(k_full[0, j * page:(j + 1) * page].transpose(1, 0, 2))
-        v_pages = v_pages.at[perm[j]].set(v_full[0, j * page:(j + 1) * page].transpose(1, 0, 2))
+    k_pages, v_pages = _scatter_pages(k_full, v_full, perm, 16, page)
     bt = jnp.asarray(perm)[None, :].astype(jnp.int32)
-    o_paged = msa_prefill(q_full, k_pages, v_pages, bt,
-                          jnp.array([S], jnp.int32), pos,
-                          jnp.array([S], jnp.int32), impl="xla")
-    np.testing.assert_allclose(np.asarray(o_dense), np.asarray(o_paged),
-                               atol=1e-5)
+    for o_paged in _fused_both(q_full[0], k_pages, v_pages, bt,
+                               jnp.array([S], jnp.int32), [np.arange(S)],
+                               t_bucket=S, page=page, q_tile=16):
+        np.testing.assert_allclose(np.asarray(o_dense[0]),
+                                   np.asarray(o_paged), atol=1e-5)
 
 
 @settings(max_examples=25, deadline=None)
@@ -120,7 +161,8 @@ def test_msa_equals_contiguous_attention():
 def test_msa_segment_merge_property(n_seg, seed):
     """Property: attention over q tokens split across arbitrary gap
     structures equals attention computed over the same logical positions
-    contiguously (Eq. 2 generalized to any segment count)."""
+    contiguously (Eq. 2 generalized to any segment count), through the
+    varlen oracle and the fused kernel."""
     rng = np.random.RandomState(seed)
     page, KH, H, D = 4, 2, 4, 16
     S = 40
@@ -138,16 +180,13 @@ def test_msa_segment_merge_property(n_seg, seed):
 
     NP = S // page
     perm = rng.permutation(NP + 4)[:NP]
-    k_pages = jnp.zeros((NP + 4, KH, page, D))
-    v_pages = jnp.zeros((NP + 4, KH, page, D))
-    for j in range(NP):
-        k_pages = k_pages.at[perm[j]].set(k_full[0, j * page:(j + 1) * page].transpose(1, 0, 2))
-        v_pages = v_pages.at[perm[j]].set(v_full[0, j * page:(j + 1) * page].transpose(1, 0, 2))
+    k_pages, v_pages = _scatter_pages(k_full, v_full, perm, NP + 4, page)
     bt = jnp.asarray(perm)[None, :].astype(jnp.int32)
-    o_paged = msa_prefill(q, k_pages, v_pages, bt, jnp.array([S], jnp.int32),
-                          q_pos, jnp.array([len(idx)], jnp.int32), impl="xla")
-    np.testing.assert_allclose(np.asarray(o_dense), np.asarray(o_paged),
-                               atol=1e-5)
+    for o_paged in _fused_both(q[0], k_pages, v_pages, bt,
+                               jnp.array([S], jnp.int32), [idx],
+                               t_bucket=len(idx), page=page, q_tile=8):
+        np.testing.assert_allclose(np.asarray(o_dense[0]),
+                                   np.asarray(o_paged), atol=1e-5)
 
 
 def test_write_kv_pages_roundtrip():

@@ -1,6 +1,6 @@
 """Overlapped execution pipeline: compile-once invariant, pipelined-vs-
-synchronous equivalence, vectorized-vs-legacy assembly equivalence, and
-in-step page-op folding (COW copies + host-tier swap-ins)."""
+synchronous equivalence, and in-step page-op folding (COW copies +
+host-tier swap-ins)."""
 import numpy as np
 import jax
 import pytest
@@ -27,17 +27,15 @@ def small_model():
     return cfg, params
 
 
-def _mk_server(cfg, params, depth, assembly="vectorized", num_blocks=64,
-               host_blocks=0, attn_mode="fused", **ecfg_kw):
+def _mk_server(cfg, params, depth, num_blocks=64, host_blocks=0,
+               **ecfg_kw):
     scfg = ServerConfig(
         policy="asymcache", num_blocks=num_blocks, block_size=16,
         clock="model", pipeline_depth=depth, host_blocks=host_blocks,
-        attn_mode=attn_mode,
         scheduler=SchedulerConfig(token_budget=128, max_chunk=64,
                                   max_prefills=2, max_decodes=8))
     ecfg = EngineConfig(num_pages=num_blocks, page_size=16, max_prefills=2,
-                        max_chunk=64, max_decodes=8, assembly=assembly,
-                        attn_mode=attn_mode, **ecfg_kw)
+                        max_chunk=64, max_decodes=8, **ecfg_kw)
     return AsymCacheServer(cfg, params, scfg, ecfg=ecfg)
 
 
@@ -49,30 +47,23 @@ def _wl(n_sessions=3, seed=0, **kw):
 
 
 def test_step_compiles_exactly_once(small_model):
-    """The static-bucket invariant the pipeline depends on: in the split
-    layout the jitted step traces exactly once across a multi-step run
-    mixing prefill chunks (several per prefill: prompts > max_chunk) and
-    decodes; in the fused layout it traces exactly once PER occupancy
-    bucket used (the compile-once-per-bucket cache)."""
+    """The static-bucket invariant the pipeline depends on: across a
+    multi-step run mixing prefill chunks (several per prefill: prompts >
+    max_chunk) and decodes, the jitted step traces exactly once PER
+    occupancy bucket used (the compile-once-per-bucket cache)."""
     cfg, params = small_model
-    srv = _mk_server(cfg, params, depth=1, attn_mode="split")
-    wl = _wl(n_sessions=3, first_ctx_len=(100, 180))
-    res = srv.run(wl)
+    srv = _mk_server(cfg, params, depth=1)
+    res = srv.run(_wl(n_sessions=3, first_ctx_len=(100, 180)))
     assert res["steps"] > 10
     assert srv.engine.steps_executed == res["steps"]
-    assert srv.engine.jit_traces == 1, (
-        f"jitted step retraced {srv.engine.jit_traces} times")
-
-    srv_f = _mk_server(cfg, params, depth=1)          # fused default
-    srv_f.run(_wl(n_sessions=3, first_ctx_len=(100, 180)))
-    used = len(srv_f.engine.buckets_used)
-    assert 1 <= used <= (len(srv_f.engine.token_buckets)
-                         * len(srv_f.engine.np_buckets))
-    assert srv_f.engine.jit_traces == used, (
-        srv_f.engine.jit_traces, sorted(srv_f.engine.buckets_used))
+    used = len(srv.engine.buckets_used)
+    assert 1 <= used <= (len(srv.engine.token_buckets)
+                         * len(srv.engine.np_buckets))
+    assert srv.engine.jit_traces == used, (
+        srv.engine.jit_traces, sorted(srv.engine.buckets_used))
     # a second identical run re-uses every per-bucket compilation
-    srv_f.run(_wl(n_sessions=3, first_ctx_len=(100, 180)))
-    assert srv_f.engine.jit_traces == used
+    srv.run(_wl(n_sessions=3, first_ctx_len=(100, 180)))
+    assert srv.engine.jit_traces == used
 
 
 def test_pipelined_matches_synchronous(small_model):
@@ -88,47 +79,6 @@ def test_pipelined_matches_synchronous(small_model):
         assert a.generated == b.generated
         assert a.sampled_ids == b.sampled_ids and a.sampled_ids
         assert np.array_equal(a.first_logits, b.first_logits)
-
-
-def test_legacy_and_vectorized_assembly_agree(small_model):
-    """The fused vectorized path must reproduce the legacy per-token /
-    two-dispatch reference bit-for-bit — this crosses BOTH the assembly
-    rewrite and the fused-vs-split attention layouts."""
-    cfg, params = small_model
-    srv_v = _mk_server(cfg, params, depth=1, assembly="vectorized")
-    srv_l = _mk_server(cfg, params, depth=0, assembly="legacy",
-                       attn_mode="split", return_full_logits=True,
-                       max_instep_copies=0)
-    wl_v, wl_l = _wl(seed=7), _wl(seed=7)
-    rv, rl = srv_v.run(wl_v), srv_l.run(wl_l)
-    assert rv["steps"] == rl["steps"]
-    for a, b in zip(wl_v, wl_l):
-        assert a.generated == b.generated
-        assert a.sampled_ids == b.sampled_ids
-        assert np.array_equal(a.first_logits, b.first_logits)
-
-
-def test_assembly_paths_build_identical_inputs(small_model):
-    """Field-level check: one (split-layout) engine, one plan, both
-    assembly paths fill the same packed fields."""
-    cfg, params = small_model
-    srv = _mk_server(cfg, params, depth=1, attn_mode="split")
-    wl = _wl(n_sessions=2, seed=1)
-    for r in wl:
-        srv._on_arrival(r)
-    plan = srv.sched.schedule(now=1e9)
-    assert plan.prefills
-    eng = srv.engine
-    packed, (t_b, np_b, w_b) = eng.build_inputs(plan)
-    legacy = eng._assemble_legacy(plan)
-    buf = np.asarray(packed["pack"])
-    layout, _ = eng.pack_layout(t_b, np_b, w_b)
-    for name, off, size in layout:
-        if name not in legacy:          # page-op fields have no legacy twin
-            continue
-        got = buf[off:off + size]
-        want = np.asarray(legacy[name]).reshape(-1).astype(np.int32)
-        assert np.array_equal(got, want), name
 
 
 def test_host_tier_swaps_fold_into_step(small_model):

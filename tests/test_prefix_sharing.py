@@ -240,11 +240,17 @@ def test_sharing_disabled_recomputes_everything(small_model):
     res, srv = _serve(cfg, params, wl, sharing=False)
     assert all(r.n_prefill_compute == r.prompt_len for r in wl)
     assert res["cow_forks"] == 0 and res["prefix_matched_tokens"] == 0
-    # identical outputs either way (sharing is lossless)
+    # identical tokens either way (sharing is lossless); the first-token
+    # logits come from differently shaped steps (a full prefill against a
+    # suffix chunk), which XLA may reduce in another order, so they agree
+    # within float32 rounding (rtol 1e-5, atol 1e-6)
     wl_s = mk()
     _serve(cfg, params, wl_s, sharing=True)
     for a, b in zip(wl, wl_s):
-        assert np.array_equal(a.first_logits, b.first_logits)
+        assert a.generated == b.generated
+        assert a.sampled_ids == b.sampled_ids and a.sampled_ids
+        np.testing.assert_allclose(a.first_logits, b.first_logits,
+                                   rtol=1e-5, atol=1e-6)
 
 
 def test_shared_prefix_workload_properties():

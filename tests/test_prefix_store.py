@@ -342,8 +342,11 @@ def test_sim_restart_round_trip(tmp_path):
 def test_engine_restart_round_trip(tmp_path):
     """Real-engine cross-restart round trip: snapshot after a shared-
     prefix serve, boot a FRESH AsymCacheServer from the snapshot, and
-    require byte-identical greedy outputs (generated, sampled_ids,
-    first_logits) plus a strictly lower prefill-token counter."""
+    require identical greedy outputs (generated, sampled_ids) and
+    first-token logits within float32 rounding (rtol 1e-5, atol 1e-6: a
+    restored prefix makes the first token's step a differently shaped
+    program, which XLA may reduce in another order), plus a strictly
+    lower prefill-token counter."""
     import jax
     from repro.configs import get_smoke_config, scaled_config
     from repro.models import init_params
@@ -376,7 +379,8 @@ def test_engine_restart_round_trip(tmp_path):
     for a, b in zip(wl_a, wl_b):
         assert a.generated == b.generated
         assert a.sampled_ids == b.sampled_ids
-        assert np.array_equal(a.first_logits, b.first_logits)
+        np.testing.assert_allclose(a.first_logits, b.first_logits,
+                                   rtol=1e-5, atol=1e-6)
     assert res_b["prefill_compute_tokens"] < res_a["prefill_compute_tokens"]
     # the store path must not widen the compile-shape lattice
     assert warm.engine.jit_traces == len(warm.engine.buckets_used)

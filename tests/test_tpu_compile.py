@@ -1,4 +1,4 @@
-"""The MSA kernels compile for a TPU v5e at Llama-3.1-8B widths.
+"""The fused MSA kernel and the engine's layer body compile for a TPU v5e.
 
 No chip is needed: the TPU compiler is given a described ``v5e:2x2``
 topology and compiles for one of its devices, so Mosaic refuses here what
@@ -18,16 +18,12 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.msa import WL_FIELDS
-from repro.kernels.msa.msa_decode import msa_decode_pallas
 from repro.kernels.msa.msa_fused import block_pages, msa_fused_pallas
-from repro.kernels.msa.msa_prefill import msa_prefill_pallas
 from repro.serving.engine import write_and_attend
 
 # Llama-3.1-8B attention widths; the engine's page size and q tile
 H, KH, D, PAGE, Q_TILE = 32, 8, 128, 16, 128
 POOL_PAGES, N_SEQS = 2048, 12
-# split-layout batch: prefill rows x chunk, decode rows, pages per sequence
-R, QP, B, NP = 2, 1024, 16, 128
 
 
 @pytest.fixture(scope="module")
@@ -166,19 +162,3 @@ def test_engine_layer_writes_pools_in_place_for_v5e(spec, no_compile_cache):
               and any(k in (op if op != "fusion" else name)
                       for k in ("copy", "slice", "dynamic-update-slice"))]
     assert not copies, copies
-
-
-def test_msa_prefill_compiles_for_v5e(spec, no_compile_cache):
-    """The split layout's prefill kernel (``attn_mode="split"``)."""
-    pool = spec((POOL_PAGES, KH, PAGE, D), jnp.bfloat16)
-    _compiles(lambda *a: msa_prefill_pallas(*a, q_tile=Q_TILE),
-              (spec((R, QP, H, D), jnp.bfloat16), pool, pool,
-               spec((R, NP)), spec((R,)), spec((R, QP)), spec((R,))))
-
-
-def test_msa_decode_compiles_for_v5e(spec, no_compile_cache):
-    """The split layout's decode kernel (``attn_mode="split"``)."""
-    pool = spec((POOL_PAGES, KH, PAGE, D), jnp.bfloat16)
-    _compiles(msa_decode_pallas,
-              (spec((B, H, D), jnp.bfloat16), pool, pool,
-               spec((B, NP)), spec((B,))))
