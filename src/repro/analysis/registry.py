@@ -6,6 +6,7 @@ both sides of any disagreement:
 
 1. **Emitter vs frozen schema** — the literal dict keys returned by
    ``Engine.perf_counters()``, ``_SimEngine.perf_counters()``,
+   ``ChunkingScheduler.perf_counters()``, ``PrefixStore.counters()``,
    ``BlockManager.counters()`` and ``BlockManager.control_plane_counts()``
    (seeded from ``policy_op_counts`` in ``core/evictor.py``) must equal
    the frozensets in ``tests/test_perf_counters.py`` in *both*
@@ -54,6 +55,8 @@ EMITTER_SCHEMAS: Tuple[Tuple[str, str, str, str], ...] = (
      "BM_COUNTER_KEYS"),
     ("src/repro/core/prefix_store.py", "PrefixStore", "counters",
      "STORE_COUNTER_KEYS"),
+    ("src/repro/serving/scheduler.py", "ChunkingScheduler", "perf_counters",
+     "SCHEDULER_COUNTER_KEYS"),
 )
 
 DOC_FILES = ("README.md", "docs/ARCHITECTURE.md", "docs/SERVING.md",

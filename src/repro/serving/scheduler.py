@@ -11,12 +11,20 @@ Adaptive chunk sizing: when the number of co-scheduled decodes exceeds
 ``decode_threshold`` the per-request chunk shrinks (never below
 ``min_chunk``) so decode TPOT is protected; prefill total latency is
 roughly unchanged because prefill is compute-bound (§5.1).
+
+On an occupancy bucket lattice the step's token bucket, not its token
+count, sets its matmul cost, so the §5.1 shrink bounds the step's
+bucket.  Once the shrunk plan has picked its bucket, the rows between
+the plan and ``min(t_bucket, token_budget)`` go to its prefill chunks
+(bucket fill): the step costs the same and the prompts finish in fewer
+steps.  The fill keeps the step's token and page buckets, so it stays a
+variant already compiled; with no lattice the plan is the §5.1 one.
 """
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -119,6 +127,10 @@ class ChunkingScheduler:
         self.waiting: List[Request] = []
         self.running: List[Request] = []
         self.swaps_this_round = 0
+        # bucket fill (see _fill_bucket): steps it lengthened a chunk in,
+        # and the prefill tokens it added
+        self.bucket_fill_steps = 0
+        self.bucket_fill_tokens = 0
         # multi-token gating hook: the server points this at the engine's
         # pending page-op queues — a queued COW copy or host-tier swap-in
         # must land in an ordinary k=1 step (the op indices target the
@@ -301,7 +313,9 @@ class ChunkingScheduler:
             # The shrink divides by the number of co-scheduled prefill
             # chunks too: TPOT is bounded by the step's *total* prefill
             # tokens, so k concurrent chunks each get a k-times-smaller
-            # share of the same per-step prefill allowance.
+            # share of the same per-step prefill allowance.  On a bucket
+            # lattice this bounds the step's token bucket: _fill_bucket
+            # hands the bucket's remaining rows back to the chunks.
             shrink = max(1, n_decodes - c.decode_threshold)
             size = c.max_chunk // ((1 + shrink // 4) * max(1, n_prefills))
             return max(c.min_chunk, size)
@@ -368,7 +382,12 @@ class ChunkingScheduler:
 
         self._select_decode_steps(plan)
         self._select_buckets(plan)
+        self._fill_bucket(plan)
         return plan
+
+    def perf_counters(self) -> Dict[str, int]:
+        return {"bucket_fill_steps": self.bucket_fill_steps,
+                "bucket_fill_tokens": self.bucket_fill_tokens}
 
     def _select_decode_steps(self, plan: StepPlan) -> None:
         """Multi-token decode dispatch (§5.1 decode-dominated detection).
@@ -428,6 +447,43 @@ class ChunkingScheduler:
                 need = max(need, -(-ctx // bs))
             plan.np_bucket = next((b for b in c.page_buckets if b >= need),
                                   c.page_buckets[-1])
+
+    def _fill_bucket(self, plan: StepPlan) -> None:
+        """Bucket fill: a step with prefill pays for every row of its
+        token bucket, so the rows between the plan and
+        ``min(t_bucket, token_budget)`` lengthen its prefill chunks, in
+        plan order.  Each chunk stays within ``max_chunk``, its request's
+        compute list and the step's page bucket; what one chunk cannot
+        take flows on to the next.  ``t_bucket`` and ``np_bucket`` do not
+        change, and no chunk is added."""
+        c = self.cfg
+        if plan.t_bucket is None or not plan.prefills:
+            return
+        room = min(plan.t_bucket, c.token_budget) - plan.n_compute_tokens
+        # positions at or past this would deepen the step's page table
+        end = (plan.np_bucket * c.block_size if plan.np_bucket is not None
+               else None)
+        added = 0
+        for ch in plan.prefills:
+            if room <= 0:
+                break
+            req = ch.req
+            start = req.compute_ptr - len(ch.positions)
+            stop = min(start + c.max_chunk, req.compute_ptr + room,
+                       len(req.compute_list))
+            if end is not None:
+                stop = min(stop, int(np.searchsorted(req.compute_list, end)))
+            extra = stop - req.compute_ptr
+            if extra <= 0:
+                continue
+            room -= extra
+            added += extra
+            req.compute_ptr = stop
+            ch.positions = req.compute_list[start:stop]
+            ch.completes_prefill = req.prefill_done
+        if added:
+            self.bucket_fill_steps += 1
+            self.bucket_fill_tokens += added
 
     # ------------------------------------------------------------------
     def finish(self, req: Request, now: float) -> None:

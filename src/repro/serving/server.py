@@ -350,6 +350,11 @@ class AsymCacheServer:
         return (self.tracer.totals[SCHEDULE]
                 + self.tracer.totals[ASSEMBLE])
 
+    def perf_counters(self) -> Dict:
+        """The engine's hot-path counters and the scheduler's bucket-fill
+        counters, as every server result carries them."""
+        return {**self.engine.perf_counters(), **self.sched.perf_counters()}
+
     # ------------------------------------------------------------------
     def _hashes_for(self, req: Request, n_blocks: int):
         """Incrementally extended per-request chain-hash cache (O(1)/block)."""
@@ -619,8 +624,8 @@ class AsymCacheServer:
             out["n_shards"] = self.bm.n_shards
             out["per_shard_used"] = self.bm.per_shard_used()
         # deterministic hot-path accounting (fused-dispatch + occupancy
-        # buckets; empty for the simulated engine)
-        out.update(self.engine.perf_counters())
+        # buckets + bucket fill)
+        out.update(self.perf_counters())
         # failure-semantics accounting: terminal fault-domain counts +
         # degradation counters, and the fault plan's armed/fired tallies
         # when one is attached (all zeros on a fault-free run)

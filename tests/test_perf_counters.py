@@ -75,6 +75,13 @@ SIM_ENGINE_KEYS = frozenset({
     "k_counts",
 })
 
+# frozen key set of ChunkingScheduler.perf_counters() — the bucket fill's
+# accounting, merged with the engine's into every server result
+SCHEDULER_COUNTER_KEYS = frozenset({
+    "bucket_fill_steps",
+    "bucket_fill_tokens",
+})
+
 CONTROL_PLANE_KEYS = frozenset({
     "treap_ops",
     "evictor_adds",
@@ -216,6 +223,43 @@ def test_sim_engine_counter_parity():
     pc = eng.perf_counters()
     assert set(pc) == SIM_ENGINE_KEYS
     assert SIM_ENGINE_KEYS <= ENGINE_COUNTER_KEYS
+
+
+def test_scheduler_counter_schema(served):
+    """The scheduler's bucket-fill counters are frozen and reach the
+    server's counters beside the engine's.  The served burst never has
+    more decode rows than the §5.1 threshold, so no chunk was shrunk and
+    the fill added nothing."""
+    srv, _ = served
+    pc = srv.sched.perf_counters()
+    assert set(pc) == SCHEDULER_COUNTER_KEYS
+    assert pc == {"bucket_fill_steps": 0, "bucket_fill_tokens": 0}
+    assert set(srv.perf_counters()) == \
+        ENGINE_COUNTER_KEYS | SCHEDULER_COUNTER_KEYS
+
+
+@pytest.mark.parametrize("lattice", [(16, 144), ()])
+def test_bucket_fill_counters_under_decode_pressure(lattice):
+    """A served burst with 16 decode rows beside long prompts: on a token
+    lattice the fill lengthens the shrunk chunks and counts it; with no
+    lattice the counters stay 0."""
+    cfg = get_config("llama31-8b")
+    cm = analytic_cost_model(cfg, H20)
+    scfg = ServerConfig(
+        policy="asymcache", num_blocks=512, block_size=BLOCK,
+        clock="model", execute_model=False,
+        scheduler=SchedulerConfig(token_budget=144, max_chunk=64,
+                                  max_prefills=2, max_decodes=16,
+                                  token_buckets=lattice))
+    sim = AsymCacheServer(cfg, None, scfg, cost_model=cm, sim_cost_model=cm)
+    res = sim.run(decode_burst_workload(n_requests=24, prompt_len=(200, 400),
+                                        output_len=(40, 60), seed=6))
+    assert SCHEDULER_COUNTER_KEYS <= set(res)
+    steps, tokens = res["bucket_fill_steps"], res["bucket_fill_tokens"]
+    if lattice:
+        assert 0 < steps <= tokens
+    else:
+        assert steps == tokens == 0
 
 
 def test_bm_counter_schema_and_server_result(served):

@@ -15,6 +15,7 @@ from repro.serving import (
     ServerConfig,
     WorkloadConfig,
     agentic_workload,
+    decode_burst_workload,
     multi_turn_workload,
     reference_logits,
 )
@@ -210,6 +211,46 @@ def test_adaptive_chunking_shrinks():
     assert sc._chunk_size(0, 1) == 128
     assert sc._chunk_size(20, 1) < 128
     assert sc._chunk_size(1000, 1) >= 16     # lower bound (§5.1)
+
+
+def test_bucket_fill_outputs_token_identical(small_model):
+    """Bucket fill changes how many steps a prompt takes, not what is
+    computed: on a burst with more decode rows than the §5.1 threshold,
+    the greedy samples are token-identical with the fill engaged (the
+    engine's lattice [16, 144]) and disengaged (no lattice: the §5.1
+    plan, buckets picked by the engine)."""
+    cfg, params = small_model
+
+    def serve(lattice: bool):
+        scfg = ServerConfig(
+            policy="asymcache", num_blocks=192, block_size=16,
+            clock="model",
+            scheduler=SchedulerConfig(token_budget=144, max_chunk=64,
+                                      max_prefills=2, max_decodes=16))
+        ecfg = EngineConfig(num_pages=192, page_size=16, max_prefills=2,
+                            max_chunk=64, max_decodes=16,
+                            max_blocks_per_seq=32, token_buckets=(16, 144),
+                            np_buckets=(32,))
+        srv = AsymCacheServer(cfg, params, scfg, ecfg=ecfg)
+        if not lattice:
+            srv.sched.cfg.token_buckets = ()
+            srv.sched.cfg.page_buckets = ()
+        wl = decode_burst_workload(n_requests=20, prompt_len=(100, 200),
+                                   output_len=(16, 24), seed=7)
+        res = srv.run(wl)
+        assert res["n_requests"] == len(wl)
+        return wl, res
+
+    wl_on, res_on = serve(True)
+    wl_off, res_off = serve(False)
+    assert res_on["bucket_fill_steps"] > 0
+    assert res_on["bucket_fill_tokens"] >= res_on["bucket_fill_steps"]
+    assert res_off["bucket_fill_steps"] == res_off["bucket_fill_tokens"] == 0
+    # the same prompts in fewer steps
+    assert res_on["steps"] < res_off["steps"]
+    for a, b in zip(wl_on, wl_off):
+        assert len(a.sampled_ids) == len(a.output_script)
+        assert a.sampled_ids == b.sampled_ids, a.rid
 
 
 def test_continuum_pinning_improves_agentic_hits(small_model):
