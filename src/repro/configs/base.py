@@ -40,6 +40,20 @@ class MoEConfig:
     # capacity-factor drops would make outputs depend on batch composition.
     # Training keeps capacity-factor dropping (standard, bounded buffers).
     dropless: bool = False
+    # DeepSeek-V3 routing (arXiv:2412.19437, §2.1.2): "sigmoid" scores
+    # each expert on its own; a layer's ``router_bias`` parameter, where
+    # it has one, is added to the scores for choosing the top-k only; the
+    # chosen experts' unbiased scores are normalised over the top-k and
+    # scaled by ``routed_scale``.  The defaults are softmax routing.
+    score_fn: str = "softmax"
+    routed_scale: float = 1.0
+    # width of the shared experts that every token passes through, as
+    # one SwiGLU (0 = none)
+    shared_d_ff: int = 0
+    # the first expert this chip holds: a layer whose expert weights hold
+    # fewer experts than the router scores holds ``[expert_offset,
+    # expert_offset + E_held)``, one chip's share of expert parallelism
+    expert_offset: int = 0
 
     @property
     def num_physical_experts(self) -> int:
@@ -78,8 +92,21 @@ class ModelConfig:
     sliding_window: int = 0          # 0 -> full attention
     local_global_ratio: int = 0      # gemma3: N local layers per 1 global
     attn_logit_softcap: float = 0.0
+    # --- latent attention (MLA, DeepSeek-V2/V3): each token caches one
+    # vector [RMSNorm(c_kv) (kv_lora_rank) | RoPE(k_pe) (qk_rope_head_dim)]
+    # for all heads; 0 = ordinary K/V heads ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- MoE / SSM / hybrid ---
     moe: Optional[MoEConfig] = None
+    # leading layers whose MLP is a dense SwiGLU of width ``dense_d_ff``
+    # before the MoE layers (DeepSeek-V3's ``first_k_dense_replace``); the
+    # engine reads their weights from ``dense_blocks``, the rest from
+    # ``blocks``
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
     ssm: Optional[SSMConfig] = None
     hybrid_attn_ssm: bool = False    # hymba: parallel attention + SSM heads
     # --- enc-dec (whisper) ---
@@ -109,6 +136,27 @@ class ModelConfig:
         return self.family == "ssm"
 
     @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def cache_heads(self) -> int:
+        """Heads of the paged cache: one latent head under MLA."""
+        return 1 if self.latent else self.n_kv_heads
+
+    @property
+    def cache_dim(self) -> int:
+        """Width of one cached head: the latent and its RoPE part under
+        MLA, else ``head_dim``."""
+        return (self.kv_lora_rank + self.qk_rope_head_dim if self.latent
+                else self.head_dim)
+
+    def mlp_kind(self, layer: int) -> str:
+        """The MLP kind of ``layer``: "moe" or "dense"."""
+        return ("moe" if self.moe is not None and layer >= self.first_k_dense
+                else "dense")
+
+    @property
     def q_dim(self) -> int:
         return self.n_heads * self.head_dim
 
@@ -128,11 +176,17 @@ class ModelConfig:
         d, h = self.d_model, self.head_dim
         embed = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         per_layer = 0
-        if self.family != "ssm":
+        if self.latent:
+            r, dr, H = self.kv_lora_rank, self.qk_rope_head_dim, self.n_heads
+            dn, dv = self.qk_nope_head_dim, self.v_head_dim
+            per_layer += (d * H * (dn + dr) + d * (r + dr)
+                          + r * H * (dn + dv) + H * dv * d)
+        elif self.family != "ssm":
             per_layer += d * self.q_dim + d * self.kv_dim * 2 + self.q_dim * d
         if self.moe is not None:
             per_layer += d * self.moe.num_experts  # router
             per_layer += self.moe.num_experts * 3 * d * self.d_ff
+            per_layer += 3 * d * self.moe.shared_d_ff
         elif self.d_ff > 0:
             per_layer += 3 * d * self.d_ff
         if self.ssm is not None:
@@ -143,6 +197,11 @@ class ModelConfig:
             per_layer += self.ssm.d_conv * (di + 2 * self.ssm.n_groups * self.ssm.d_state)
         per_layer += 2 * d  # norms
         total = embed + self.n_layers * per_layer
+        if self.first_k_dense and self.moe is not None:
+            # leading dense layers: a SwiGLU in place of the expert layer
+            moe_mlp = d * self.moe.num_experts * (1 + 3 * self.d_ff) \
+                + 3 * d * self.moe.shared_d_ff
+            total += self.first_k_dense * (3 * d * self.dense_d_ff - moe_mlp)
         if self.enc_dec:
             enc_per_layer = 4 * d * d + 3 * d * self.d_ff + 2 * d
             dec_cross = 4 * d * d + d
@@ -155,8 +214,9 @@ class ModelConfig:
             return self.param_count()
         d = self.d_model
         full = self.param_count()
-        moe_all = self.n_layers * self.moe.num_experts * 3 * d * self.d_ff
-        moe_active = self.n_layers * self.moe.top_k * 3 * d * self.d_ff
+        n_moe = self.n_layers - self.first_k_dense
+        moe_all = n_moe * self.moe.num_experts * 3 * d * self.d_ff
+        moe_active = n_moe * self.moe.top_k * 3 * d * self.d_ff
         return full - moe_all + moe_active
 
 

@@ -120,7 +120,8 @@ class BlockManager:
                  payload_half_bytes: Optional[Tuple[int, int]] = None,
                  pcie_bw: float = 1.2e10,
                  faults: Optional[FaultPlan] = None,
-                 store: Optional[PrefixStore] = None):
+                 store: Optional[PrefixStore] = None,
+                 k_only: bool = False):
         self.num_blocks = num_blocks
         self.block_size = block_size
         # ---- KV sharding (sharded serving engine): the device page pool
@@ -164,6 +165,12 @@ class BlockManager:
         self.swap_out_fn = swap_out_fn
         self.swap_in_fn = swap_in_fn        # (slot, (k|None, v|None)) -> None
         self.offload = offload or OffloadConfig()
+        # a latent cache (one cached vector per token) has only the K
+        # half: host entries hold K alone and spills move no V bytes
+        self.k_only = k_only
+        assert not (k_only and (self.offload.keep_k_half
+                                or self.offload.k_early_prefetch)), \
+            "K/V half policies need a V half"
         # full-precision per-half bytes (budget unit) and the configured
         # wire-format per-half bytes (sim accounting when payloads are
         # never materialized); (1, 1) keeps unit-test BlockManagers on
@@ -519,7 +526,8 @@ class BlockManager:
             # zero bytes AND skips the synchronous device pool read.  A
             # v_pending block's V half never left the host.
             need_k = e is None or e.k is None
-            need_v = (e is None or e.v is None) and not was_v_pending
+            need_v = (e is None or e.v is None) and not was_v_pending \
+                and not self.k_only
             k_raw = v_raw = None
             if self.swap_out_fn is not None:
                 # always called even when nothing is needed: besides
@@ -529,7 +537,7 @@ class BlockManager:
                 # clobber the reallocated page
                 k_raw, v_raw = self.swap_out_fn(slot, need_k, need_v)
             if e is None:
-                e = HostEntry(block_pos=blk.block_pos)
+                e = HostEntry(block_pos=blk.block_pos, k_only=self.k_only)
                 self.host_tier[key] = e
             if need_k:
                 e.k = self._encode_half(k_raw, key, "k")
@@ -541,7 +549,7 @@ class BlockManager:
                 e.v = self._encode_half(v_raw, key, "v")
                 self.bytes_swapped_out_v += e.v.nbytes
                 self.host_resident_bytes += e.v.nbytes
-            else:
+            elif not self.k_only:
                 self.n_clean_half_spills += 1
             if ck is not None and e.complete:
                 # content is restart-stable: deposit under the content
@@ -555,12 +563,7 @@ class BlockManager:
             # no host tier configured, but the global store is on: read
             # both halves and deposit straight to the store (the read
             # also purges any still-queued swap-in halves for the slot)
-            k_raw = v_raw = None
-            if self.swap_out_fn is not None:
-                k_raw, v_raw = self.swap_out_fn(slot, True, True)
-            e = HostEntry(block_pos=blk.block_pos,
-                          k=self._encode_half(k_raw, key, "k"),
-                          v=self._encode_half(v_raw, key, "v"))
+            e = self._read_entry(slot, blk.block_pos, key)
             self.store.deposit(ck, e, self._owner_of(ck), now)
         blk.key = None
 
@@ -654,12 +657,7 @@ class BlockManager:
             blk = self.blocks[slot]
             if ck is None or blk.v_pending:
                 continue
-            k_raw = v_raw = None
-            if self.swap_out_fn is not None:
-                k_raw, v_raw = self.swap_out_fn(slot, True, True)
-            e = HostEntry(block_pos=blk.block_pos,
-                          k=self._encode_half(k_raw, key, "k"),
-                          v=self._encode_half(v_raw, key, "v"))
+            e = self._read_entry(slot, blk.block_pos, key)
             if self.store.deposit(ck, e, self._owner_of(ck), now):
                 n += 1
         for key, e in list(self.host_tier.items()):
@@ -668,6 +666,19 @@ class BlockManager:
                     self.store.deposit(ck, e, self._owner_of(ck), now):
                 n += 1
         return n
+
+    def _read_entry(self, slot: int, block_pos: int, key: int) -> HostEntry:
+        """A whole host entry of the block in ``slot``, read through
+        ``swap_out_fn`` (which also purges any still-queued swap-in
+        halves for the slot)."""
+        k_raw = v_raw = None
+        if self.swap_out_fn is not None:
+            k_raw, v_raw = self.swap_out_fn(slot, True, not self.k_only)
+        return HostEntry(block_pos=block_pos,
+                         k=self._encode_half(k_raw, key, "k"),
+                         v=(None if self.k_only
+                            else self._encode_half(v_raw, key, "v")),
+                         k_only=self.k_only)
 
     def _encode_half(self, raw, key: int, which: str) -> HostHalf:
         """Wire-encode one spilled half.  ``raw`` is None (simulation /
@@ -907,11 +918,12 @@ class BlockManager:
             return False
         if not self._survive_acquire(key, e):
             return False
-        if self.swap_in_fn is not None and \
-                (e.k.data is not None or e.v.data is not None):
+        if self.swap_in_fn is not None and (e.k.data is not None or (
+                e.v is not None and e.v.data is not None)):
             self.swap_in_fn(slot, (e.k, e.v))
         self.bytes_swapped_in_k += e.k.nbytes
-        self.bytes_swapped_in_v += e.v.nbytes
+        if e.v is not None:
+            self.bytes_swapped_in_v += e.v.nbytes
         self.commit(slot, key, block_pos)
         self.n_swap_ins += 1
         if self.offload.retain_host:

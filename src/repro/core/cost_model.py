@@ -173,9 +173,14 @@ def analytic_cost_model(cfg: ModelConfig, hw: Hardware = TPU_V5E,
     eff = hw.flops * hw.mfu * n_chips
 
     n_attn_layers = cfg.n_layers if cfg.family != "ssm" else 0
-    attn_flops_per_ctx_tok = 4.0 * n_attn_layers * cfg.n_heads * cfg.head_dim
-
-    kv_bytes_per_tok = 2 * 2 * n_attn_layers * cfg.n_kv_heads * cfg.head_dim
+    # QK^T over the cached width and PV over V's (latent attention: the
+    # latent and its RoPE part, then the latent itself; one cached half)
+    v_dim = cfg.kv_lora_rank if cfg.latent else cfg.head_dim
+    attn_flops_per_ctx_tok = (2.0 * n_attn_layers * cfg.n_heads
+                              * (cfg.cache_dim + v_dim))
+    halves = 1 if cfg.latent else 2
+    kv_bytes_per_tok = (2 * halves * n_attn_layers * cfg.cache_heads
+                        * cfg.cache_dim)
 
     k2 = gemm_flops_per_tok / eff                  # per new token (GEMM)
     k5 = attn_flops_per_ctx_tok / eff              # per (new × context) pair

@@ -166,14 +166,17 @@ def verify_half(half: Optional[HostHalf]) -> bool:
 
 @dataclass
 class HostEntry:
-    """Per-half host-tier residency of one evicted block."""
+    """Per-half host-tier residency of one evicted block.  A latent
+    cache's block (``k_only``) has only the K half: its ``v`` stays
+    None."""
     block_pos: int
     k: Optional[HostHalf] = None
     v: Optional[HostHalf] = None
+    k_only: bool = False
 
     @property
     def complete(self) -> bool:
-        return self.k is not None and self.v is not None
+        return self.k is not None and (self.k_only or self.v is not None)
 
     @property
     def nbytes(self) -> int:
@@ -209,13 +212,14 @@ def half_from_wire(d: Optional[dict]) -> Optional[HostHalf]:
 
 def entry_to_wire(e: HostEntry) -> dict:
     """Plain-dict form of a host entry (both halves)."""
-    return {"block_pos": int(e.block_pos),
+    return {"block_pos": int(e.block_pos), "k_only": e.k_only,
             "k": half_to_wire(e.k), "v": half_to_wire(e.v)}
 
 
 def entry_from_wire(d: dict) -> HostEntry:
     return HostEntry(block_pos=int(d["block_pos"]),
-                     k=half_from_wire(d["k"]), v=half_from_wire(d["v"]))
+                     k=half_from_wire(d["k"]), v=half_from_wire(d["v"]),
+                     k_only=bool(d.get("k_only", False)))
 
 
 def _f8_dtype():

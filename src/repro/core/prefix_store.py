@@ -156,7 +156,8 @@ def clone_entry(e: HostEntry) -> HostEntry:
     (half drops, corruption injection), so the store never shares its
     master containers with the tier — only the immutable arrays."""
     return HostEntry(block_pos=e.block_pos,
-                     k=_clone_half(e.k), v=_clone_half(e.v))
+                     k=_clone_half(e.k), v=_clone_half(e.v),
+                     k_only=e.k_only)
 
 
 def _seal(e: HostEntry) -> None:

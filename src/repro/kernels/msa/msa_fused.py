@@ -57,6 +57,14 @@ score, probability and upcast-q temporaries of ``(2048, 128)`` 1 MiB
 each: about 10 MiB by this count, and it compiles for v5e inside the
 default scoped VMEM limit (``tests/test_tpu_compile.py``); at G=4 a
 quarter of it.
+
+Latent attention (``latent``, a ``LatentLayout``): one cached vector per
+token serves every query head as key and, in its first ``rank`` lanes,
+as value.  The pool holds one ``(rows, 128)`` tile a page and no V pool
+(``latent.py``); the kernel DMAs K tiles only, assembles an item's keys
+from whole 8-row, 128-lane slices of them, and scores a query widened
+by ``LatentLayout.kernel_q``.  At G=16, TQ=64 and 768 query lanes the q,
+output and accumulator blocks and the upcast q take about 12 MiB of VMEM.
 """
 from __future__ import annotations
 
@@ -69,6 +77,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.msa.latent import LatentLayout
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 # lanes of a vreg: a work-list item covers enough pages to fill a score
@@ -217,29 +227,34 @@ def _msa_fused_kernel(
     q_pos_ref,        # (G, TQ, 1) int32 — logical positions of this q tile
     q_ref,            # (1, G, TQ, D) — the G query heads of one KV head
     k_hbm,            # (L, P, KH, page, D) stacked pools, left in HBM
-    v_hbm,            # (L, P, KH, page, D)
-    # outputs
-    o_ref,            # (1, G, TQ, D)
-    # scratch
-    k_buf,            # (2, NB, page, D) — this item's pages and the next's
-    v_buf,            # (2, NB, page, D)
-    sem,              # DMA semaphores (2 [K, V], 2 buffers)
-    acc_ref,          # (G*TQ, D) f32
-    m_ref,            # (G*TQ, 1) f32
-    l_ref,            # (G*TQ, 1) f32
-    *,
+    *refs,
     nb: int,
+    page: int,
+    scale: float,
     window: int,
     softcap: float,
     q_tile: int,
+    latent: Optional[LatentLayout],
     interpret: bool,
 ):
+    """``refs``: ``v_hbm`` (L, P, KH, page, D) unless ``latent``, then
+    the output ``o_ref`` (1, G, TQ, DV), and the scratch: ``k_buf`` (2,
+    NB, page, D) — this item's pages and the next's —, ``v_buf`` like it
+    unless ``latent``, the DMA semaphores (2 [K, V], 2 buffers), the f32
+    accumulator ``acc_ref`` (G*TQ, DV) and running max and sum
+    ``m_ref``/``l_ref`` (G*TQ, 1).  Under ``latent`` a page is one
+    ``(rows, 128)`` latent tile (``LatentLayout``), K and V both come
+    from it, and no V page moves."""
+    if latent is not None:
+        o_ref, k_buf, sem, acc_ref, m_ref, l_ref = refs
+        v_hbm = v_buf = None
+    else:
+        v_hbm, o_ref, k_buf, v_buf, sem, acc_ref, m_ref, l_ref = refs
     g = pl.program_id(0)
     w = pl.program_id(1)
     s = wl_seq[w]
     lyr = layer[0]
     _, grp, tq, d = q_ref.shape
-    page = k_buf.shape[2]
     rows_g = grp * tq
 
     def each_page(item, buf, op):
@@ -249,8 +264,10 @@ def _msa_fused_kernel(
             slot = wl_slot[item * nb + i]
             op(pltpu.make_async_copy(
                 k_hbm.at[lyr, slot, g], k_buf.at[buf, i], sem.at[0, buf]))
-            op(pltpu.make_async_copy(
-                v_hbm.at[lyr, slot, g], v_buf.at[buf, i], sem.at[1, buf]))
+            if v_buf is not None:
+                op(pltpu.make_async_copy(
+                    v_hbm.at[lyr, slot, g], v_buf.at[buf, i],
+                    sem.at[1, buf]))
             return carry
         # unrolled on the chip, so the scalar core issues the descriptors
         # back to back; rolled under the interpreter, where unrolling
@@ -277,12 +294,14 @@ def _msa_fused_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    scale = 1.0 / math.sqrt(d)
     # the G heads' (TQ, D) tiles stacked into one (G*TQ, D) operand; the
     # NB gathered pages stacked into one (NB*page, D) block of keys
     qt = q_ref[0].astype(jnp.float32).reshape(rows_g, d) * scale
-    kt = k_buf[buf].astype(jnp.float32).reshape(nb * page, d)
-    vt = v_buf[buf].astype(jnp.float32).reshape(nb * page, d)
+    if latent is not None:
+        kt, vt = _latent_kv(k_buf[buf].astype(jnp.float32), latent)
+    else:
+        kt = k_buf[buf].astype(jnp.float32).reshape(nb * page, d)
+        vt = v_buf[buf].astype(jnp.float32).reshape(nb * page, d)
 
     sc = jax.lax.dot_general(qt, kt, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -298,8 +317,10 @@ def _msa_fused_kernel(
     # logical key positions of the block: slots past the needed range
     # land beyond the horizon or the context and are masked here
     ctx = context_lens[s]
-    kv_pos = wl_kvbase[w] + jax.lax.broadcasted_iota(
-        jnp.int32, (1, nb * page), 1)                            # (1, K)
+    kv_off = jax.lax.broadcasted_iota(jnp.int32, (1, nb * page), 1)
+    if latent is not None:
+        kv_off = _latent_key_offsets(kv_off, nb, latent)
+    kv_pos = wl_kvbase[w] + kv_off                               # (1, K)
     rel = q_pos_ref[...].reshape(rows_g, 1) - kv_pos            # (G*TQ, K)
     mask = row_ok & (rel >= 0) & (kv_pos < ctx)
     if window > 0:
@@ -323,18 +344,57 @@ def _msa_fused_kernel(
         # emitted by their items' earlier tiles never reach here with
         # l == 0 except true padding, which emits exact zeros like the ref
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = out.reshape(grp, tq, d).astype(o_ref.dtype)
+        o_ref[0] = out.reshape(grp, tq, o_ref.shape[-1]).astype(o_ref.dtype)
+
+
+def _latent_kv(tiles, lay: LatentLayout):
+    """The item's K (NB*page, q_width) and V (NB*page, rank) from its NB
+    latent page tiles (NB, rows, 128), keys in (group, page,
+    token-in-group) order: a group's K rows are its tokens' latent
+    chunks, then ``pe`` in the group's 128-lane block and zeros in the
+    others (``LatentLayout.kernel_q`` puts ``q_pe`` at the group's lanes
+    of that block).  Every slice is a whole 8-row, 128-lane tile."""
+    nb = tiles.shape[0]
+    pg, n = lay.per_group, lay.chunks * lay.page
+    pe = tiles[:, n:n + pg, :]
+    zeros = jnp.zeros_like(pe)
+    ks, vs = [], []
+    for grp in range(lay.groups):
+        c = [tiles[:, j * lay.page + grp * pg:j * lay.page + (grp + 1) * pg, :]
+             for j in range(lay.chunks)]
+        blocks = [pe if h == grp else zeros for h in range(lay.groups)]
+        ks.append(jnp.concatenate(c + blocks, axis=-1).reshape(
+            nb * pg, lay.q_width))
+        vs.append(jnp.concatenate(c, axis=-1).reshape(nb * pg, lay.rank))
+    return jnp.concatenate(ks, axis=0), jnp.concatenate(vs, axis=0)
+
+
+def _latent_key_offsets(j, nb: int, lay: LatentLayout):
+    """Column ``j``'s key offset from the item's first page under the
+    (group, page, token-in-group) order of ``_latent_kv``, by shifts:
+    every extent is a power of two."""
+    bits = [int(x).bit_length() - 1 for x in (nb * lay.per_group,
+                                               lay.per_group, lay.page)]
+    assert all(1 << b == x for b, x in zip(
+        bits, (nb * lay.per_group, lay.per_group, lay.page))), lay
+    grp = jax.lax.shift_right_logical(j, bits[0])
+    rem = j & ((1 << bits[0]) - 1)
+    pg = jax.lax.shift_right_logical(rem, bits[1])
+    i = rem & ((1 << bits[1]) - 1)
+    return (pg << bits[2]) + (grp << bits[1]) + i
 
 
 # jitted so that a step's layers share one trace and one Mosaic lowering
 # of the kernel per shape, instead of lowering it again for every layer
 # (the layer index is an operand, not a static argument, for that reason)
 @functools.partial(jax.jit,
-                   static_argnames=("window", "softcap", "q_tile", "interpret"))
+                   static_argnames=("window", "softcap", "q_tile", "latent",
+                                    "interpret"))
 def msa_fused_pallas(
     q: jax.Array,              # (T, H, D) flattened mixed token stream
     k_pages: jax.Array,        # (L, P, KH, page, D) stacked, or (P, KH,
-    v_pages: jax.Array,        # page, D) one layer's head-major pool
+    v_pages: Optional[jax.Array],  # page, D) one layer's head-major pool
+                               # (None with ``latent``)
     q_start: jax.Array,        # (N,) int32
     q_len: jax.Array,          # (N,) int32
     q_pos: jax.Array,          # (T,) int32
@@ -350,13 +410,25 @@ def msa_fused_pallas(
     window: int = 0,
     softcap: float = 0.0,
     q_tile: int = 128,
+    latent: Optional[LatentLayout] = None,
     interpret: bool = False,
 ) -> jax.Array:
+    """(T, H, DV) attention output at scale 1/sqrt(D), DV = D.  Under
+    ``latent`` q is (T, H, rank + rope), the pools hold latent page
+    tiles ``(.., 1, rows, 128)``, ``v_pages`` is not read (None), and DV
+    is ``rank``: V is each cached latent."""
     if k_pages.ndim == 4:      # one layer's pool: a stack of one
-        k_pages, v_pages = k_pages[None], v_pages[None]
+        k_pages = k_pages[None]
+        v_pages = None if latent is not None else v_pages[None]
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     t, h, d = q.shape
-    _, _, kh, page, _ = k_pages.shape
+    scale = 1.0 / math.sqrt(d)
+    if latent is not None:
+        q = latent.kernel_q(q)
+        page, d, dv = latent.page, latent.q_width, latent.rank
+    else:
+        page, dv = k_pages.shape[3], d
+    kh = k_pages.shape[2]
     grp = h // kh
     n_items = wl_seq.shape[0]
     nb = block_pages(page)
@@ -388,32 +460,30 @@ def msa_fused_pallas(
     def q_index(g_, w_, wl_seq_, wl_qtile_, *refs):
         return (g_, 0, wl_qtile_[w_], 0)
 
+    pools = [k_pages] if latent is not None else [k_pages, v_pages]
+    page_bufs = [pltpu.VMEM((2, nb) + p.shape[3:], p.dtype) for p in pools]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=10,
         grid=(kh, n_items),
         in_specs=[
             pl.BlockSpec((grp, q_tile, 1), qpos_index),
             pl.BlockSpec((1, grp, q_tile, d), q_index),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, grp, q_tile, d), q_index),
-        scratch_shapes=[
-            pltpu.VMEM((2, nb, page, d), k_pages.dtype),
-            pltpu.VMEM((2, nb, page, d), v_pages.dtype),
+        ] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=pl.BlockSpec((1, grp, q_tile, dv), q_index),
+        scratch_shapes=page_bufs + [
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((grp * q_tile, d), jnp.float32),
+            pltpu.VMEM((grp * q_tile, dv), jnp.float32),
             pltpu.VMEM((grp * q_tile, 1), jnp.float32),
             pltpu.VMEM((grp * q_tile, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _msa_fused_kernel, nb=nb, window=window, softcap=softcap,
-        q_tile=q_tile, interpret=interpret)
+        _msa_fused_kernel, nb=nb, page=page, scale=scale, window=window,
+        softcap=softcap, q_tile=q_tile, latent=latent, interpret=interpret)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((kh, grp, t_pad, dv), q.dtype),
         # the page prefetch of item w+1 runs during item w, so both axes
         # run in order on one core
         compiler_params=pltpu.CompilerParams(
@@ -423,5 +493,5 @@ def msa_fused_pallas(
     )(wl_seq.astype(jnp.int32), wl_qtile.astype(jnp.int32),
       wl_slot.astype(jnp.int32), wl_kvbase.astype(jnp.int32),
       wl_init.astype(jnp.int32), wl_last.astype(jnp.int32),
-      qs, ql, ctx, layer, qpos, qg, k_pages, v_pages)
-    return out.transpose(2, 0, 1, 3).reshape(t_pad, h, d)[:t]
+      qs, ql, ctx, layer, qpos, qg, *pools)
+    return out.transpose(2, 0, 1, 3).reshape(t_pad, h, dv)[:t]

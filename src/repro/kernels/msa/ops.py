@@ -19,20 +19,26 @@ DEFAULT_IMPL = "xla"  # the oracle; the served TPU path passes "pallas"
 def msa_fused(q, k_pages, v_pages, block_tables, context_lens, q_pos,
               seq_ids, q_valid, *, q_start=None, q_len=None, worklist=None,
               layer=None, window: int = 0, softcap: float = 0.0,
-              q_tile: int = 128, impl: str = DEFAULT_IMPL) -> jax.Array:
+              q_tile: int = 128, latent=None,
+              impl: str = DEFAULT_IMPL) -> jax.Array:
     """One fused dispatch over the flattened (T, H, D) mixed token stream
     (prefill chunks + decode rows).  The xla oracle resolves each token's
     context through ``seq_ids``; the Pallas kernel iterates the compacted
     work-list (``msa_fused.build_worklist``) with per-sequence
     ``q_start``/``q_len`` runs.  With ``layer`` the pools are the
     layer-stacked ``(L, P, KH, page, D)`` ones: the kernel DMAs that
-    layer's pages straight from them, the oracle slices the layer out."""
+    layer's pages straight from them, the oracle slices the layer out.
+    Under ``latent`` (a ``LatentLayout``: latent attention) the pools
+    hold latent page tiles, ``v_pages`` is not read, V is each cached
+    latent and the output is ``latent.rank`` wide."""
     if impl == "xla":
         if layer is not None:
-            k_pages, v_pages = k_pages[layer], v_pages[layer]
+            k_pages = k_pages[layer]
+            v_pages = None if latent is not None else v_pages[layer]
         return ref.msa_fused_ref(q, k_pages, v_pages, block_tables,
                                  context_lens, q_pos, seq_ids, q_valid,
-                                 window=window, softcap=softcap)
+                                 window=window, softcap=softcap,
+                                 latent=latent)
     if q_start is None or q_len is None or worklist is None:
         raise ValueError("pallas msa_fused needs q_start/q_len + worklist")
     interpret = impl == "pallas_interpret"
@@ -40,7 +46,7 @@ def msa_fused(q, k_pages, v_pages, block_tables, context_lens, q_pos,
                             context_lens, *worklist,
                             0 if layer is None else layer, window=window,
                             softcap=softcap, q_tile=q_tile,
-                            interpret=interpret)
+                            latent=latent, interpret=interpret)
 
 
 def msa_fused_partial(q, k_pages, v_pages, block_tables, context_lens,
@@ -90,18 +96,18 @@ def apply_page_copies(k_pools: jax.Array, v_pools: jax.Array,
     A scatter whose update operand gathers from the scattered array itself
     would force XLA to materialize a full defensive pool copy per step;
     the gather-then-update form keeps the update operand independent so
-    the writes happen in place in the donated pools."""
+    the writes happen in place in the donated pools.  A latent cache
+    (``v_pools`` None) copies its one half."""
     c = copy_src.shape[0]
     if c == 0:
         return k_pools, v_pools
-    k_pages = k_pools[:, copy_src]      # (L, C, KH, page, D) — small
-    v_pages = v_pools[:, copy_src]
+    pools = [k_pools] if v_pools is None else [k_pools, v_pools]
+    pages = [p[:, copy_src] for p in pools]  # (L, C, KH, page, D) — small
     for j in range(c):
-        k_pools = jax.lax.dynamic_update_slice_in_dim(
-            k_pools, k_pages[:, j:j + 1], copy_dst[j], axis=1)
-        v_pools = jax.lax.dynamic_update_slice_in_dim(
-            v_pools, v_pages[:, j:j + 1], copy_dst[j], axis=1)
-    return k_pools, v_pools
+        pools = [jax.lax.dynamic_update_slice_in_dim(
+            p, pg[:, j:j + 1], copy_dst[j], axis=1)
+            for p, pg in zip(pools, pages)]
+    return pools[0], (None if v_pools is None else pools[1])
 
 
 def _dequant_payload(payload: jax.Array, scale, dtype) -> jax.Array:

@@ -28,6 +28,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.msa.latent import LatentLayout
+
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -50,14 +52,18 @@ def msa_prefill_ref(
     *,
     window: int = 0,           # 0 = full causal
     softcap: float = 0.0,
+    latent: Optional[LatentLayout] = None,
 ) -> jax.Array:
     r, qp, h, d = q.shape
     kh = k_pages.shape[1]
     n_rep = h // kh
     scale = 1.0 / math.sqrt(d)
 
+    if latent is not None:                  # latent tiles -> cached vectors
+        k_pages = latent.from_tile(k_pages)
     k = _gather_kv(k_pages, block_tables)   # (R, S, KH, D)
-    v = _gather_kv(v_pages, block_tables)
+    v = (k[..., :latent.rank] if latent is not None
+         else _gather_kv(v_pages, block_tables))
     s_len = k.shape[1]
 
     # GQA via grouped heads: fold the query-head replication into the
@@ -85,7 +91,7 @@ def msa_prefill_ref(
     p = jax.nn.softmax(scores, axis=-1)
     p = jnp.where(mask, p, 0.0)             # fully-masked rows -> 0
     out = jnp.einsum("rhgqs,rshd->rqhgd", p, vf)
-    return out.reshape(r, qp, h, d).astype(q.dtype)
+    return out.reshape(r, qp, h, vf.shape[-1]).astype(q.dtype)
 
 
 def msa_fused_ref(
@@ -100,6 +106,7 @@ def msa_fused_ref(
     *,
     window: int = 0,
     softcap: float = 0.0,
+    latent: Optional[LatentLayout] = None,
 ) -> jax.Array:
     """Varlen oracle for the fused mixed-batch MSA dispatch.
 
@@ -110,12 +117,15 @@ def msa_fused_ref(
     valid row equals a per-sequence :func:`msa_prefill_ref` call over the
     same operands up to float32 rounding (XLA may order a reduction
     differently for the two shapes); invalid rows are zeros, as in the
-    padded ref."""
+    padded ref.  Under ``latent`` (latent attention) the pool holds
+    latent page tiles (``LatentLayout``), ``v_pages`` is not read, K is
+    each cached vector and V its first ``rank`` lanes, and the output is
+    ``rank`` wide."""
     out = msa_prefill_ref(
         q[:, None], k_pages, v_pages,
         block_tables[seq_ids], context_lens[seq_ids],
         q_pos[:, None], q_valid.astype(jnp.int32),
-        window=window, softcap=softcap)
+        window=window, softcap=softcap, latent=latent)
     return out[:, 0]
 
 
@@ -204,9 +214,11 @@ def write_kv_pages(
 
     Invalid (padding) rows are routed out of range and dropped by the
     scatter itself — no read-modify-write, stays a pure scatter.  The
-    (T, KH, D) rows land at ``[slot, :, offset]`` of the head-major pool."""
+    (T, KH, D) rows land at ``[slot, :, offset]`` of the head-major pool.
+    A latent cache has no V half: ``v_pages`` None is passed through."""
     p = k_pages.shape[0]
     oob = jnp.where(valid, slot_ids, p)     # out-of-range -> dropped
     k_pages = k_pages.at[oob, :, slot_offsets].set(k_new, mode="drop")
-    v_pages = v_pages.at[oob, :, slot_offsets].set(v_new, mode="drop")
+    if v_pages is not None:
+        v_pages = v_pages.at[oob, :, slot_offsets].set(v_new, mode="drop")
     return k_pages, v_pages

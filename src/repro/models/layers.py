@@ -302,10 +302,27 @@ def swiglu_mlp(x: jax.Array, w1: jax.Array, w3: jax.Array, w2: jax.Array) -> jax
 # MoE: top-k routing with sort-based capacity dispatch (no one-hot einsum)
 # ---------------------------------------------------------------------------
 
-def topk_route(logits: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
-    """logits (T, E) -> (weights (T,k), idx (T,k)); weights softmaxed over top-k."""
-    topv, topi = lax.top_k(logits, k)
-    return jax.nn.softmax(topv.astype(jnp.float32), axis=-1), topi
+def topk_route(logits: jax.Array, k: int, *, score_fn: str = "softmax",
+               bias: Optional[jax.Array] = None,
+               scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+    """logits (T, E) -> (weights (T,k) f32, idx (T,k)).
+
+    Softmax routing (the default, which takes no bias or scale): weights
+    softmaxed over the top-k logits.  Sigmoid routing (DeepSeek-V3): each
+    expert's score is ``sigmoid(logit)``; the top-k of ``score + bias``
+    are chosen (the bias steers the choice only); their unbiased scores,
+    normalised over the k chosen, times ``scale`` are the weights."""
+    if score_fn == "softmax":
+        assert bias is None and scale == 1.0
+        topv, topi = lax.top_k(logits, k)
+        return jax.nn.softmax(topv.astype(jnp.float32), axis=-1), topi
+    assert score_fn == "sigmoid", score_fn
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, topi = lax.top_k(choice, k)
+    w = jnp.take_along_axis(scores, topi, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * scale, topi
 
 
 def capacity_dispatch(flat_expert: jax.Array, num_experts: int, capacity: int):
@@ -345,24 +362,54 @@ def expand_virtual_experts(weights: jax.Array, topi: jax.Array,
 def moe_ffn_local(
     x: jax.Array,           # (T, d) token activations (local shard)
     router_w: jax.Array,    # (d, E_physical)
-    we1: jax.Array,         # (E_virtual, d, f)
+    we1: jax.Array,         # (E_virtual, d, f) — the experts held here
     we3: jax.Array,         # (E_virtual, d, f)
     we2: jax.Array,         # (E_virtual, f, d)
     top_k: int,
     capacity_factor: float = 1.25,
     dropless: bool = False,
     expert_split: int = 1,
+    *,
+    score_fn: str = "softmax",
+    router_bias: Optional[jax.Array] = None,
+    routed_scale: float = 1.0,
+    expert_offset: int = 0,
 ) -> jax.Array:
-    """Single-program MoE: tokens stay put, all experts computed locally.
+    """The expert layer: routes every token over all ``E_physical``
+    experts and computes the part of the result that the experts held
+    here give.
 
-    Used for smoke tests and single-host serving.  ``dropless=True`` sets
-    capacity = T (an expert can receive at most one slot per token since
-    top-k indices are distinct), which guarantees no drops — required for
-    lossless serving."""
+    Holding all of them (``we1`` has every virtual expert) is
+    single-program MoE with sort-based capacity dispatch: tokens stay
+    put, all experts computed locally.  ``dropless=True`` sets capacity =
+    T (an expert can receive at most one slot per token since top-k
+    indices are distinct), which guarantees no drops — required for
+    lossless serving.
+
+    Holding a share (``we1`` has fewer experts than the router scores:
+    one chip's share of expert parallelism, physical experts
+    ``[expert_offset, expert_offset + E_held)``) computes only the slots
+    routed to the held experts, as grouped matmuls over the slots sorted
+    by expert (``lax.ragged_dot``): no capacity, no dropped slot.  The
+    routing weights are those of the whole top-k, so the shares of all
+    chips sum to the whole layer; the absent experts' part is left out.
+    Routing with ``score_fn="sigmoid"`` takes its logits in float32."""
     t, d = x.shape
     e = we1.shape[0]                                          # virtual
-    logits = x @ router_w                                     # (T, E_phys)
-    weights, topi = topk_route(logits, top_k)                 # (T, k)
+    e_route = router_w.shape[1]
+    with jax.named_scope("router"):
+        if score_fn == "softmax":
+            logits = x @ router_w                             # (T, E_phys)
+        else:
+            logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+        weights, topi = topk_route(logits, top_k, score_fn=score_fn,
+                                   bias=router_bias,
+                                   scale=routed_scale)        # (T, k)
+    if e // expert_split < e_route:
+        assert expert_split == 1, "a held share takes whole experts"
+        with jax.named_scope("experts"):
+            return _held_share(x, weights, topi - expert_offset, we1, we3,
+                               we2)
     weights, topi = expand_virtual_experts(weights, topi, expert_split)
     k_eff = top_k * expert_split
     n = t * k_eff
@@ -387,6 +434,32 @@ def moe_ffn_local(
     gathered = jnp.where(keep[:, None], y_flat[safe_slot], 0.0)
     gathered = gathered * weights.reshape(n)[:, None].astype(x.dtype)
     return jnp.sum(gathered.reshape(t, k_eff, d), axis=1)
+
+
+def _held_share(x: jax.Array, weights: jax.Array, local: jax.Array,
+                we1: jax.Array, we3: jax.Array, we2: jax.Array) -> jax.Array:
+    """Sum over each token's slots routed to the held experts of weight x
+    SwiGLU expert output.  ``local`` (T, k) is each slot's expert index
+    relative to the first held one; slots outside ``[0, E_held)`` are
+    absent.  The slots are sorted by expert (absent ones last, past
+    every group) and run as three grouped matmuls whose group sizes are
+    the held experts' slot counts."""
+    t, d = x.shape
+    k = local.shape[1]
+    e = we1.shape[0]
+    flat = local.reshape(t * k)
+    held = (flat >= 0) & (flat < e)
+    group = jnp.where(held, flat, e)
+    order = jnp.argsort(group, stable=True)                  # slots by expert
+    tok = (order // k).astype(jnp.int32)
+    sizes = jnp.bincount(group, length=e + 1)[:e].astype(jnp.int32)
+    xs = x[tok]                                               # (T*k, d)
+    h = jax.nn.silu(lax.ragged_dot(xs, we1, sizes)) * lax.ragged_dot(
+        xs, we3, sizes)
+    y = lax.ragged_dot(h, we2, sizes, preferred_element_type=jnp.float32)
+    w = jnp.where(held, weights.reshape(t * k), 0.0)[order]
+    y = jnp.where(held[order][:, None], y * w[:, None], 0.0)
+    return jnp.zeros((t, d), jnp.float32).at[tok].add(y).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

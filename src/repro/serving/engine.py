@@ -56,13 +56,16 @@ swap-ins carry per-shard queues (cross-shard copies fall back to the
 eager global-view path).  The occupancy-bucket jit cache is unchanged:
 ``jit_traces == len(buckets_used)`` holds under ``shard_map`` too.
 
-Engine scope: decoder-only token LMs (dense / MoE / sliding-window mixes).
+Engine scope: decoder-only token LMs (dense / MoE / sliding-window mixes,
+and latent attention with a latent-only pool on one device: see
+docs/ARCHITECTURE.md).
 SSM-family archs have no evictable KV cache and are served by the dense
 decode path in ``repro.models`` instead.
 """
 from __future__ import annotations
 
 import contextlib
+import math
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -81,6 +84,7 @@ from repro.kernels.msa import (
     pad_worklist,
     write_kv_pages,
 )
+from repro.kernels.msa.latent import LatentLayout
 from repro.core.offload import HostHalf, dequantize_half
 from repro.models.layers import apply_rope, moe_ffn_local, rms_norm, swiglu_mlp
 from repro.models.model import _layer_windows
@@ -153,7 +157,8 @@ def touched_pages(write_slot: np.ndarray, valid: np.ndarray, nw: int,
 
 
 def write_layer_pages(k_pools, v_pools, layer: int, k_new, v_new,
-                      write_page, write_row, write_off, valid):
+                      write_page, write_row, write_off, valid,
+                      latent: Optional[LatentLayout] = None):
     """Write one layer's new K/V rows into the layer-stacked, donated
     ``(L, P, KH, page, D)`` pools, page by page: gather the ``NW`` touched
     pages, scatter the rows into that small buffer (``write_kv_pages``
@@ -164,7 +169,16 @@ def write_layer_pages(k_pools, v_pools, layer: int, k_new, v_new,
     XLA on TPU then moves the whole pool into another layout and back on
     every layer; a scatter whose window is whole ``(KH, page, D)`` pages
     stays in place.  Padding entries of ``write_page`` (slot P) gather a
-    clamped page and their write-back is dropped."""
+    clamped page and their write-back is dropped.  A latent cache
+    (``latent``; ``v_pools`` None) writes its one half: the touched page
+    tiles open into rows of cached vectors, take the new rows and close
+    again."""
+    if latent is not None:
+        rows = latent.from_tile(k_pools[layer, write_page])
+        rows, _ = write_kv_pages(rows, None, k_new, None, write_row,
+                                 write_off, valid)
+        return (k_pools.at[layer, write_page].set(latent.to_tile(rows),
+                                                  mode="drop"), None)
     k_pages, v_pages = write_kv_pages(
         k_pools[layer, write_page], v_pools[layer, write_page], k_new, v_new,
         write_row, write_off, valid)
@@ -174,22 +188,25 @@ def write_layer_pages(k_pools, v_pools, layer: int, k_new, v_new,
 
 def write_and_attend(k_pools, v_pools, layer: int, q, k_new, v_new,
                      f: Dict[str, jax.Array], worklist, *, window: int,
-                     softcap: float, q_tile: int, impl: str):
+                     softcap: float, q_tile: int, impl: str,
+                     latent: Optional[LatentLayout] = None):
     """A fused single-device layer's KV write and its ONE ``msa_fused``
     dispatch, which reads ``layer`` of the stacked pools in place: no
     layer's pool is ever sliced out into a buffer of its own.  ``f``
-    holds one fused iteration's step fields.  Returns the pools and the
-    attention output."""
+    holds one fused iteration's step fields.  A latent cache passes its
+    ``latent`` layout and ``v_pools`` and ``v_new`` None.  Returns the
+    pools and the attention output."""
     with jax.named_scope("kv_write"):
         k_pools, v_pools = write_layer_pages(
             k_pools, v_pools, layer, k_new, v_new, f["write_page"],
-            f["write_row"], f["write_off"], f["valid"])
+            f["write_row"], f["write_off"], f["valid"], latent)
     with jax.named_scope("attention"):
         attn = msa_fused(q, k_pools, v_pools, f["bt"], f["ctx"],
                          f["positions"], f["seq_ids"], f["valid"],
                          q_start=f["qstart"], q_len=f["qlen"],
                          worklist=worklist, layer=layer, window=window,
-                         softcap=softcap, q_tile=q_tile, impl=impl)
+                         softcap=softcap, q_tile=q_tile, latent=latent,
+                         impl=impl)
     return k_pools, v_pools, attn
 
 
@@ -385,6 +402,8 @@ class Engine:
         self.ecfg = ecfg
         self.mesh = mesh
         self.n_shards = 1 if mesh is None else int(mesh.shape["model"])
+        if cfg.latent and self.n_shards > 1:
+            raise ValueError("latent attention runs on one device only")
         dt = jnp.dtype(cfg.dtype)
         L = cfg.n_layers
         # split swap-queue wire format + write-time pool-grid snap
@@ -409,11 +428,19 @@ class Engine:
                                   if self._payload_fmt == "fp"
                                   else np.dtype(np.int8))
         # head-major pools: one (page, D) tile per (slot, kv head) is the
-        # block the TPU kernel streams
-        self.k_pools = jnp.zeros(
-            (L, ecfg.num_pages, cfg.n_kv_heads, ecfg.page_size, cfg.head_dim),
-            dt)
-        self.v_pools = jnp.zeros_like(self.k_pools)
+        # block the TPU kernel streams.  A latent cache is its K half
+        # alone, one (rows, 128) tile per slot holding the page's
+        # (page, kv_lora_rank + qk_rope_head_dim) cached vectors
+        # (``LatentLayout``); its ``v_pools`` is an empty placeholder that
+        # every step passes through untouched.
+        self.latent = (LatentLayout(ecfg.page_size, cfg.kv_lora_rank,
+                                    cfg.qk_rope_head_dim)
+                       if cfg.latent else None)
+        page_shape = ((1, self.latent.rows, 128) if cfg.latent else
+                      (cfg.n_kv_heads, ecfg.page_size, cfg.head_dim))
+        self.k_pools = jnp.zeros((L, ecfg.num_pages) + page_shape, dt)
+        self.v_pools = (jnp.zeros((0,), dt) if cfg.latent
+                        else jnp.zeros_like(self.k_pools))
         in_shardings = None
         if self.n_shards > 1:
             # sharded serving: xla oracle impl (Pallas-on-mesh is a TPU
@@ -474,10 +501,9 @@ class Engine:
                  ecfg.page_size, cfg.head_dim), dt), self._swap_sh)
         else:
             self._zero_swap = jnp.zeros(
-                (L, ecfg.max_instep_swaps, cfg.n_kv_heads, ecfg.page_size,
-                 cfg.head_dim), pdt)
+                (L, ecfg.max_instep_swaps) + page_shape, pdt)
         self._zero_scale = (jnp.zeros(
-            (L, ecfg.max_instep_swaps, cfg.n_kv_heads), jnp.float32)
+            (L, ecfg.max_instep_swaps, page_shape[0]), jnp.float32)
             if self._payload_fmt == "q8" else None)
         R, QP, B, NP = (ecfg.max_prefills, ecfg.max_chunk,
                         ecfg.max_decodes, ecfg.max_blocks_per_seq)
@@ -593,6 +619,8 @@ class Engine:
         # trace-time slicing of the pack into named views
         inp = self._unpack(inp, t_bucket, np_bucket, w_bucket, n_iter)
 
+        # a latent cache has no V half: its placeholder passes through
+        v_out, v_pools = v_pools, (None if cfg.latent else v_pools)
         # in-step page maintenance: swap-ins land first (they commit pages
         # a COW fork in the same round may use as its donor), then copies;
         # both must precede the KV writes/attention that read those pages
@@ -606,9 +634,11 @@ class Engine:
             else:
                 # quantized payloads dequantize inside apply_swap_ins —
                 # the transfer above carried the compressed wire bytes
+                # (a latent cache swaps its one half in: no V entries)
                 k_pools, v_pools = apply_swap_ins(
-                    k_pools, v_pools, inp["swap_k_dst"], inp["swap_v_dst"],
-                    inp["swap_k"], inp["swap_v"],
+                    k_pools, v_pools, inp["swap_k_dst"],
+                    inp["swap_v_dst"][:0] if cfg.latent
+                    else inp["swap_v_dst"], inp["swap_k"], inp["swap_v"],
                     inp.get("swap_k_scale"), inp.get("swap_v_scale"))
                 k_pools, v_pools = apply_page_copies(
                     k_pools, v_pools, inp["copy_src"], inp["copy_dst"])
@@ -639,7 +669,8 @@ class Engine:
             ids_steps.append(ids)
         # (R+B,) for one iteration, (n_iter, R+B) for a k-token decode
         token_ids = ids if n_iter == 1 else jnp.stack(ids_steps)
-        return token_ids, out_logits, k_pools, v_pools
+        return (token_ids, out_logits, k_pools,
+                v_out if cfg.latent else v_pools)
 
     def _snap(self, x):
         """Snap freshly computed K/V to the offload quantization grid at
@@ -657,26 +688,68 @@ class Engine:
             return (q * s).astype(x.dtype)
         return x.astype(jnp.float8_e4m3fn).astype(x.dtype)
 
-    def _mlp_sublayer(self, x, blk):
+    def _layer_params(self, params, layer: int):
+        """Layer ``layer``'s weights: the leading ``first_k_dense`` layers
+        (a dense MLP before the MoE layers) are stacked in
+        ``dense_blocks``, every other layer in ``blocks``."""
+        k = self.cfg.first_k_dense
+        group, i = ((params["dense_blocks"], layer) if layer < k
+                    else (params["blocks"], layer - k))
+        return jax.tree_util.tree_map(lambda a: a[i], group)
+
+    def _mlp_sublayer(self, x, blk, layer: int):
         cfg = self.cfg
         h2 = rms_norm(x, blk["mlp_norm"], cfg.norm_eps)
-        if cfg.moe is not None:
+        if cfg.mlp_kind(layer) == "moe":
+            mo = cfg.moe
             y = moe_ffn_local(h2, blk["router"], blk["we1"], blk["we3"],
-                              blk["we2"], cfg.moe.top_k,
-                              cfg.moe.capacity_factor,
-                              dropless=cfg.moe.dropless,
-                              expert_split=cfg.moe.expert_split)
+                              blk["we2"], mo.top_k, mo.capacity_factor,
+                              dropless=mo.dropless,
+                              expert_split=mo.expert_split,
+                              score_fn=mo.score_fn,
+                              router_bias=blk.get("router_bias"),
+                              routed_scale=mo.routed_scale,
+                              expert_offset=mo.expert_offset)
+            if mo.shared_d_ff:
+                with jax.named_scope("shared_experts"):
+                    y = y + swiglu_mlp(h2, blk["ws1"], blk["ws3"],
+                                       blk["ws2"])
         else:
             y = swiglu_mlp(h2, blk["w1"], blk["w3"], blk["w2"])
         return x + y
+
+    def _latent_qk(self, h, blk, pos):
+        """Latent attention in absorbed form (DeepSeek-V2 §2.1.3): the
+        kernel's query ``[q_nope·W_UKᵀ | RoPE(q_pe)]`` (T, H, r + dr) and
+        the token's cached vector ``[RMSNorm(c_kv) | RoPE(k_pe)]`` (T, 1,
+        r + dr).  The kernel scales scores by 1/sqrt(r + dr); the model's
+        1/sqrt(dn + dr) is folded into q."""
+        cfg = self.cfg
+        r, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        q = jnp.einsum("td,dhk->thk", h, blk["wq"])        # (T, H, dn + dr)
+        kva = h @ blk["w_kva"]                             # (T, r + dr)
+        c_kv = rms_norm(kva[:, :r], blk["kv_norm"], cfg.norm_eps)
+        k_pe = apply_rope(kva[:, None, r:], pos, cfg.rope_theta)
+        q_pe = apply_rope(q[..., dn:], pos, cfg.rope_theta)
+        scale = math.sqrt((r + dr) / (dn + dr))
+        q_lat = jnp.einsum("thn,rhn->thr", q[..., :dn],
+                           blk["w_kvb"][:, :, :dn],
+                           preferred_element_type=jnp.float32)  # (T, H, r)
+        q = jnp.concatenate([q_lat * scale,
+                             q_pe.astype(jnp.float32) * scale], axis=-1)
+        return (q.astype(h.dtype),
+                jnp.concatenate([c_kv[:, None], k_pe], axis=-1))
 
     def _fused_pass(self, params, k_pools, v_pools, tokens, f,
                     t_bucket: int):
         """One forward over a varlen token stream (``f``: one fused
         iteration's step fields): per layer the projections, the KV write
-        and the ONE attention dispatch, then the MLP.  A single device
-        writes and attends with ``write_and_attend``, a mesh with
-        ``_write_and_attend_sharded``.  Returns the updated pools and the
+        and the ONE attention dispatch, then the MLP of the layer's kind.
+        A single device writes and attends with ``write_and_attend``, a
+        mesh with ``_write_and_attend_sharded``.  Under latent attention
+        ``v_pools`` is None, the kernel takes V from the cached latent,
+        and each head's latent output goes up through W_UV before the
+        output projection.  Returns the updated pools and the
         pre-final-norm residual."""
         cfg, e = self.cfg, self.ecfg
         pos = f["positions"]
@@ -685,18 +758,23 @@ class Engine:
         with jax.named_scope("embed"):
             x = params["embed"][tokens]                 # (T, d)
         for l in range(cfg.n_layers):
-            blk = jax.tree_util.tree_map(lambda a: a[l], params["blocks"])
+            blk = self._layer_params(params, l)
             window = self.windows[l]
             with jax.named_scope("qkv"):
                 h = rms_norm(x, blk["attn_norm"], cfg.norm_eps)
-                q = jnp.einsum("td,dhk->thk", h, blk["wq"])
-                k_new = jnp.einsum("td,dhk->thk", h, blk["wk"])
-                v_new = jnp.einsum("td,dhk->thk", h, blk["wv"])
-                if cfg.rope_theta > 0:
-                    q = apply_rope(q, pos, cfg.rope_theta)
-                    k_new = apply_rope(k_new, pos, cfg.rope_theta)
-                k_new = self._snap(k_new)
-                v_new = self._snap(v_new)
+                if cfg.latent:
+                    with jax.named_scope("latent"):
+                        q, k_new = self._latent_qk(h, blk, pos)
+                    k_new, v_new = self._snap(k_new), None
+                else:
+                    q = jnp.einsum("td,dhk->thk", h, blk["wq"])
+                    k_new = jnp.einsum("td,dhk->thk", h, blk["wk"])
+                    v_new = jnp.einsum("td,dhk->thk", h, blk["wv"])
+                    if cfg.rope_theta > 0:
+                        q = apply_rope(q, pos, cfg.rope_theta)
+                        k_new = apply_rope(k_new, pos, cfg.rope_theta)
+                    k_new = self._snap(k_new)
+                    v_new = self._snap(v_new)
             if self.n_shards > 1:
                 k_pools, v_pools, attn = self._write_and_attend_sharded(
                     k_pools, v_pools, l, q, k_new, v_new, f, window)
@@ -704,11 +782,16 @@ class Engine:
                 k_pools, v_pools, attn = write_and_attend(
                     k_pools, v_pools, l, q, k_new, v_new, f, worklist,
                     window=window, softcap=cfg.attn_logit_softcap,
-                    q_tile=min(e.q_tile, t_bucket), impl=e.attn_impl)
+                    q_tile=min(e.q_tile, t_bucket), impl=e.attn_impl,
+                    latent=self.latent)
             with jax.named_scope("attention"):
+                if cfg.latent:
+                    attn = jnp.einsum(
+                        "thr,rhv->thv", attn,
+                        blk["w_kvb"][:, :, cfg.qk_nope_head_dim:])
                 x = x + jnp.einsum("thk,hkd->td", attn, blk["wo"])
             with jax.named_scope("mlp"):
-                x = self._mlp_sublayer(x, blk)
+                x = self._mlp_sublayer(x, blk, l)
         return k_pools, v_pools, x
 
     def _write_and_attend_sharded(self, k_pools, v_pools, layer: int, q,
@@ -1070,9 +1153,9 @@ class Engine:
                 out[key_s] = self._zero_scale
             return out
         cfg = self.cfg
-        buf = np.zeros((cfg.n_layers, S, cfg.n_kv_heads, e.page_size,
-                        cfg.head_dim), self._payload_npdt)
-        scale = (np.zeros((cfg.n_layers, S, cfg.n_kv_heads), np.float32)
+        page_shape = self.k_pools.shape[2:]
+        buf = np.zeros((cfg.n_layers, S) + page_shape, self._payload_npdt)
+        scale = (np.zeros((cfg.n_layers, S, page_shape[0]), np.float32)
                  if self._payload_fmt == "q8" else None)
         for j, (slot, half) in enumerate(queue):
             assert half.fmt == self._payload_fmt, (half.fmt,
@@ -1185,7 +1268,8 @@ class Engine:
         src = jnp.asarray([p[0] for p in pairs], jnp.int32)
         dst = jnp.asarray([p[1] for p in pairs], jnp.int32)
         self.k_pools = self.k_pools.at[:, dst].set(self.k_pools[:, src])
-        self.v_pools = self.v_pools.at[:, dst].set(self.v_pools[:, src])
+        if not self.cfg.latent:
+            self.v_pools = self.v_pools.at[:, dst].set(self.v_pools[:, src])
 
     # -- host-tier swaps (paper §7 hierarchical storage) ----------------
     @staticmethod
@@ -1217,14 +1301,15 @@ class Engine:
         from the queue: the queued payload IS the block's content, and
         letting it land later would clobber whatever the reallocated page
         holds by then.  Both split queues are ALWAYS purged, even for
-        halves the caller does not need — that purge is the safety net."""
+        halves the caller does not need — that purge is the safety net.
+        A latent cache has no V half: its ``v`` is always None."""
         kh = self._pop_queued(self._pending_swap_k, slot)
         vh = self._pop_queued(self._pending_swap_v, slot)
         out_k = out_v = None
         if need_k:
             out_k = kh if kh is not None \
                 else np.asarray(self.k_pools[:, slot])
-        if need_v:
+        if need_v and not self.cfg.latent:
             out_v = vh if vh is not None \
                 else np.asarray(self.v_pools[:, slot])
         return out_k, out_v
@@ -1295,7 +1380,28 @@ class Engine:
             "multi_token_rollbacks": self.multi_token_rollbacks,
             "k_counts": {f"k{k}": c for k, c
                          in sorted(self.k_counts.items())},
+            # bytes one pool page holds over all layers (K and V halves;
+            # a latent cache has only the K half)
+            "kv_bytes_per_page": self.kv_bytes_per_page,
+            # layers by "<attention>+<mlp>" kind, e.g. "latent+moe"
+            "layers_by_kind": self.layers_by_kind(),
         }
+
+    @property
+    def kv_bytes_per_page(self) -> int:
+        halves = 1 if self.cfg.latent else 2
+        pools = self.k_pools
+        return (halves * pools.shape[0] * int(np.prod(pools.shape[2:]))
+                * pools.dtype.itemsize)
+
+    def layers_by_kind(self) -> Dict[str, int]:
+        cfg = self.cfg
+        attn = "latent" if cfg.latent else "gqa"
+        out: Dict[str, int] = {}
+        for l in range(cfg.n_layers):
+            kind = f"{attn}+{cfg.mlp_kind(l)}"
+            out[kind] = out.get(kind, 0) + 1
+        return out
 
     def reset_perf_counters(self) -> None:
         """Zero the deterministic accounting so a benchmark can measure

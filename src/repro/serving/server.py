@@ -240,10 +240,12 @@ class AsymCacheServer:
         # per-half byte sizes: one (L, KH, page, D) half in pool precision
         # (the host-tier BYTE budget unit) and in the configured wire
         # format (what a spill actually moves)
+        # (a latent cache has no V half)
         fp_half = (cfg.n_layers * scfg.block_size
-                   * max(cfg.n_kv_heads, 1) * cfg.head_dim
+                   * max(cfg.cache_heads, 1) * cfg.cache_dim
                    * np.dtype(cfg.dtype).itemsize)
         wire_half = int(fp_half * scfg.offload.payload_ratio)
+        v_half = 0 if cfg.latent else 1
         # content-addressed global prefix store: always constructed (the
         # default config is disabled, counters merge as zeros); the
         # fingerprint binds stored KV to this exact architecture+weights
@@ -258,11 +260,13 @@ class AsymCacheServer:
                                prefix_sharing=scfg.prefix_sharing,
                                n_shards=scfg.n_shards,
                                offload=scfg.offload,
-                               block_bytes=(fp_half, fp_half),
-                               payload_half_bytes=(wire_half, wire_half),
+                               block_bytes=(fp_half, v_half * fp_half),
+                               payload_half_bytes=(wire_half,
+                                                   v_half * wire_half),
                                pcie_bw=scfg.pcie_bw,
                                faults=scfg.faults,
-                               store=self.store)
+                               store=self.store,
+                               k_only=cfg.latent)
         self.sched = ChunkingScheduler(scfg.scheduler, self.bm)
         # host phases of the serve loop: spans, counters, per-step ring
         self.tracer = ServeTracer()

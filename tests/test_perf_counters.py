@@ -44,6 +44,8 @@ ENGINE_COUNTER_KEYS = frozenset({
     "multi_token_iterations",
     "multi_token_rollbacks",
     "k_counts",
+    "kv_bytes_per_page",
+    "layers_by_kind",
 })
 
 # cumulative integer counters that must never decrease across dispatches

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.msa import WL_FIELDS
+from repro.kernels.msa.latent import LatentLayout
 from repro.kernels.msa.msa_fused import block_pages, msa_fused_pallas
 from repro.serving.engine import write_and_attend
 
@@ -156,6 +157,60 @@ def test_engine_layer_writes_pools_in_place_for_v5e(spec, no_compile_cache):
         pool, pool, spec((t, H, D), jnp.bfloat16), rows, rows, f,
         wl).compile()
     layer_elems = pages * kh * PAGE * D
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer_elems
+    copies = [(name, op) for name, op, sizes in _entry_ops(compiled.as_text())
+              if max(sizes, default=0) >= layer_elems
+              and any(k in (op if op != "fusion" else name)
+                      for k in ("copy", "slice", "dynamic-update-slice"))]
+    assert not copies, copies
+
+
+# Moonlight-16B-A3B's latent attention: 16 query heads over one cached
+# 576-lane vector per token (kv_lora_rank 512 + qk_rope 64), stored as a
+# (72, 128) tile a page, the cell's q tile 64; the decode-only bucket
+# and the largest prefill bucket at their largest work-lists
+LATENT = LatentLayout(page=PAGE, rank=512, rope=64)
+LATENT_CASES = [(32, 4096), (2080, 8192)]
+
+
+@pytest.mark.parametrize("t,w", LATENT_CASES,
+                         ids=[f"{t}-{w}" for t, w in LATENT_CASES])
+def test_latent_msa_fused_compiles_for_v5e(spec, no_compile_cache, t, w):
+    pool = spec((27, POOL_PAGES, 1, LATENT.rows, 128), jnp.bfloat16)
+    _compiles(lambda *a: msa_fused_pallas(*a, q_tile=64, latent=LATENT),
+              (spec((t, 16, LATENT.width), jnp.bfloat16), pool, None,
+               spec((34,)), spec((34,)), spec((t,)), spec((34,)),
+               spec((w,)), spec((w,)), spec((w * block_pages(PAGE),)),
+               *(spec((w,)) for _ in range(3)), spec(())))
+
+
+def test_latent_layer_writes_pool_in_place_for_v5e(spec, no_compile_cache):
+    """Two latent layer bodies over a donated pool of 4096 latent pages in
+    the 2080-token bucket: the touched page tiles open into rows, take
+    the new rows and close again, and no temporary or copy the size of
+    one layer's pool appears."""
+    n_layers, pages, t, w, np_b, n = 2, 4096, 2080, 512, 512, 34
+    nw = 166                        # kv_write_capacity: 130 + 2 * 2 + 32
+
+    def layers(k_pools, q, k_new, f, wl):
+        for layer in range(n_layers):
+            k_pools, _, attn = write_and_attend(
+                k_pools, None, layer, q, k_new, None, f, wl, window=0,
+                softcap=0.0, q_tile=64, impl="pallas", latent=LATENT)
+            k_new = k_new + attn[:, :1, :1]
+        return k_pools, attn
+
+    pool = spec((n_layers, pages, 1, LATENT.rows, 128), jnp.bfloat16)
+    f = {"write_page": spec((nw,)), "write_row": spec((t,)),
+         "write_off": spec((t,)), "valid": spec((t,), jnp.bool_),
+         "bt": spec((n, np_b)), "ctx": spec((n,)), "positions": spec((t,)),
+         "seq_ids": spec((t,)), "qstart": spec((n,)), "qlen": spec((n,))}
+    wl = tuple(spec((w * block_pages(PAGE),) if name == "wl_slot" else (w,))
+               for name in WL_FIELDS)
+    compiled = jax.jit(layers, donate_argnums=(0,)).lower(
+        pool, spec((t, 16, LATENT.width), jnp.bfloat16),
+        spec((t, 1, LATENT.width), jnp.bfloat16), f, wl).compile()
+    layer_elems = pages * LATENT.rows * 128
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer_elems
     copies = [(name, op) for name, op, sizes in _entry_ops(compiled.as_text())
               if max(sizes, default=0) >= layer_elems
