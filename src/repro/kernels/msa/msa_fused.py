@@ -24,22 +24,24 @@ the engine hands the kernel its whole layer-stacked ``(L, P, KH, page,
 D)`` pools and a scalar-prefetched layer index, so no layer's pool is
 ever sliced out into a buffer of its own (a per-layer pool is the
 ``L = 1`` case);
-the wrapper feeds q grouped by KV head, ``(KH, G, T, D)`` with
-``G = H / KH``, so every block ends in a ``(rows, D)`` tile — ``(page,
-D)`` for a K/V page, ``(TQ, D)`` per query head for q/out, ``(TQ, 1)``
-for the q positions — which is what Mosaic's (8, 128) tiling accepts.  A
+the wrapper feeds q grouped by KV head and token-major inside it,
+``(KH, T·G, D)`` with ``G = H / KH``: row ``t·G + j`` is query head ``j``
+of the KV head's group for stream token ``t``, so one token's G heads
+are G adjacent rows (the q positions, ``(T·G, 1)``, and the output,
+``(KH, T·G, DV)``, alike).  Every block ends in a ``(rows, D)`` tile —
+``(page, D)`` for a K/V page, ``(TQ·G, D)`` for q/out, ``(TQ·G, 1)`` for
+the q positions — which is what Mosaic's (8, 128) tiling accepts.  A
 size-1 head axis second to last (the token-major ``(P, page, KH, D)``
-layout) is refused by the TPU compiler.
+pool layout) is refused by the TPU compiler.
 
 Grid: ``(KH, W)``, both axes sequential on a TPU core.  One grid step
-serves all G query heads of a KV head against one item: the ``(G, TQ,
-D)`` q block is one ``(G·TQ, D)`` matmul operand, the item's NB K pages
-and NB V pages are DMA'd from the pool slots in ``wl_slot`` into a
-double-buffered ``(2, NB, page, D)`` VMEM block (the next item's copies
-are in flight while this one computes) and read as one ``(NB·page, D)``
-key block, so the step computes one ``(G·TQ, NB·page)`` score tile and
-one online-softmax update.  Each K/V page is fetched once per KV head
-(not once per query head).  Items of one q tile are consecutive,
+serves all G query heads of a KV head against one item: the item's NB
+K pages and NB V pages are DMA'd from the pool slots in ``wl_slot`` into
+a double-buffered ``(2, NB, page, D)`` VMEM block (the next item's
+copies are in flight while this one computes) and read as one
+``(NB·page, D)`` key block, and the step runs one online-softmax update
+of the tile's rows against it.  Each K/V page is fetched once per KV
+head (not once per query head).  Items of one q tile are consecutive,
 carrying the flash running max/sum in VMEM scratch across blocks (and
 across the several sequences that may share a tile: each item
 contributes only rows inside its own sequence's run; the row-wise
@@ -48,15 +50,30 @@ tail) are masked by logical position.  Work-list padding items point at
 the sentinel sequence row N (``q_len == 0``), mask every row, and are
 exact no-ops.
 
+Wide and narrow items: an item of a run of several tokens (a prefill
+chunk) computes the whole ``(TQ·G, NB·page)`` score tile.  An item of a
+one-token run (a decode row: ``q_len == 1``, read from the
+scalar-prefetched metadata) computes only the ``R = round_up(G, 8)``
+rows of the 8-row-aligned slice that holds its token's G heads
+(``narrow_rows``), an ``(R, NB·page)`` score tile, and updates only those
+rows of the running max, sum and accumulator; the rest of the tile's
+rows are untouched, as a masked row would leave them.  Every needed row
+takes the same operations either way.  The narrow path slices the q
+block and the scratch in place (no buffer of its own) and is compiled
+only where it saves rows: ``R < TQ·G``, ``TQ·G`` a whole number of
+8-row tiles, and G a divisor or a multiple of 8 (so G heads never
+straddle two 8-row tiles); else every item is wide.
+
 VMEM working set at TQ=128, page=16, NB=8, D=128, bf16 q and pools, for
 G=16 (2,048 rows, ChatGLM3's 2 KV groups): q and out blocks 512 KiB each,
-double-buffered (2 MiB); q positions ``(G, TQ, 1)`` int32, lane-padded,
+double-buffered (2 MiB); q positions ``(TQ·G, 1)`` int32, lane-padded,
 1 MiB double-buffered (2 MiB); K/V page buffers 2 x 2 x 32 KiB; f32
 accumulator 1 MiB and running max/sum 1 MiB each (lane-padded); f32
 score, probability and upcast-q temporaries of ``(2048, 128)`` 1 MiB
 each: about 10 MiB by this count, and it compiles for v5e inside the
 default scoped VMEM limit (``tests/test_tpu_compile.py``); at G=4 a
-quarter of it.
+quarter of it.  A narrow item's temporaries are ``R`` rows of the same
+widths (16 rows at G=16: 8 KiB each), beside the wide ones.
 
 Latent attention (``latent``, a ``LatentLayout``): one cached vector per
 token serves every query head as key and, in its first ``rank`` lanes,
@@ -65,6 +82,8 @@ as value.  The pool holds one ``(rows, 128)`` tile a page and no V pool
 from whole 8-row, 128-lane slices of them, and scores a query widened
 by ``LatentLayout.kernel_q``.  At G=16, TQ=64 and 768 query lanes the q,
 output and accumulator blocks and the upcast q take about 12 MiB of VMEM.
+A narrow item still assembles its keys and values from its page tiles
+once; only its query side shrinks, to 16 of the tile's 1,024 rows.
 """
 from __future__ import annotations
 
@@ -110,7 +129,7 @@ def build_worklist(
     n_tiles: int,
     window: int = 0,
     pad_to: Optional[int] = None,
-) -> Tuple[Dict[str, np.ndarray], int, int]:
+) -> Tuple[Dict[str, np.ndarray], int, int, int]:
     """Host-side (numpy) construction of the compacted work-list.
 
     A page is *needed* by a (sequence, q tile) pair iff it starts inside
@@ -126,14 +145,16 @@ def build_worklist(
     by q tile in ascending order (the kernel's accumulator residency
     depends on items of a tile being consecutive).
 
-    Returns the field dict, the count of page-block items and the count
-    of pages they cover (both before padding, sentinel items not
-    counted)."""
+    Returns the field dict, the count of page-block items, the count of
+    pages they cover and the count of *narrow* items, those of one-token
+    runs (decode rows), which the kernel computes on their token's rows
+    alone where ``narrow_rows`` allows (all before padding, sentinel
+    items not counted)."""
     n = q_start.shape[0]
     np_width = block_tables.shape[1]
     nb = block_pages(page)
     seqs, qtiles, slots, kvbases, inits, lasts = [], [], [], [], [], []
-    n_pages = 0
+    n_pages = n_narrow = 0
     for t in range(n_tiles):
         t_lo, t_hi = t * q_tile, (t + 1) * q_tile
         first_of_tile = len(seqs)
@@ -163,6 +184,7 @@ def build_worklist(
                 inits.append(0)
                 lasts.append(0)
                 n_pages += j1 - j0
+                n_narrow += ql == 1
         if len(seqs) > first_of_tile:
             inits[first_of_tile] = 1
             lasts[-1] = 1
@@ -186,7 +208,7 @@ def build_worklist(
            "wl_last": np.asarray(lasts, np.int32)}
     if pad_to is not None:
         out = pad_worklist(out, pad_to, sentinel_seq=n)
-    return out, n_items, n_pages
+    return out, n_items, n_pages, n_narrow
 
 
 def pad_worklist(wl: Dict[str, np.ndarray], w: int,
@@ -211,6 +233,18 @@ def pad_worklist(wl: Dict[str, np.ndarray], w: int,
         for f, a in wl.items()}
 
 
+def narrow_rows(grp: int, tq: int) -> Optional[int]:
+    """Rows a narrow item computes: its token's G query rows rounded up
+    to a whole 8-row sublane tile, ``round_up(G, 8)``.  None where no
+    narrow item pays: the tile is no wider (``G·TQ`` rows), is no whole
+    number of 8-row tiles, or a token's G rows could straddle two 8-row
+    tiles (G neither divides 8 nor is a multiple of 8)."""
+    r = -(-grp // 8) * 8
+    if r >= grp * tq or (grp * tq) % 8 or (8 % grp and grp % 8):
+        return None
+    return r
+
+
 def _msa_fused_kernel(
     # scalar prefetch (work-list + per-sequence metadata, sentinel row N)
     wl_seq,           # (W,)  sequence row per item
@@ -224,8 +258,8 @@ def _msa_fused_kernel(
     context_lens,     # (N+1,)
     layer,            # (1,)  layer of the stacked pools to attend over
     # inputs
-    q_pos_ref,        # (G, TQ, 1) int32 — logical positions of this q tile
-    q_ref,            # (1, G, TQ, D) — the G query heads of one KV head
+    q_pos_ref,        # (TQ*G, 1) int32 — logical position of each q row
+    q_ref,            # (1, TQ*G, D) — one KV head's query rows, token-major
     k_hbm,            # (L, P, KH, page, D) stacked pools, left in HBM
     *refs,
     nb: int,
@@ -238,13 +272,14 @@ def _msa_fused_kernel(
     interpret: bool,
 ):
     """``refs``: ``v_hbm`` (L, P, KH, page, D) unless ``latent``, then
-    the output ``o_ref`` (1, G, TQ, DV), and the scratch: ``k_buf`` (2,
+    the output ``o_ref`` (1, TQ*G, DV), and the scratch: ``k_buf`` (2,
     NB, page, D) — this item's pages and the next's —, ``v_buf`` like it
     unless ``latent``, the DMA semaphores (2 [K, V], 2 buffers), the f32
-    accumulator ``acc_ref`` (G*TQ, DV) and running max and sum
-    ``m_ref``/``l_ref`` (G*TQ, 1).  Under ``latent`` a page is one
-    ``(rows, 128)`` latent tile (``LatentLayout``), K and V both come
-    from it, and no V page moves."""
+    accumulator ``acc_ref`` (TQ*G, DV) and running max and sum
+    ``m_ref``/``l_ref`` (TQ*G, 1).  Row ``r`` of a tile is query head
+    ``r % G`` of the tile's token ``r // G``.  Under ``latent`` a page
+    is one ``(rows, 128)`` latent tile (``LatentLayout``), K and V both
+    come from it, and no V page moves."""
     if latent is not None:
         o_ref, k_buf, sem, acc_ref, m_ref, l_ref = refs
         v_hbm = v_buf = None
@@ -254,8 +289,8 @@ def _msa_fused_kernel(
     w = pl.program_id(1)
     s = wl_seq[w]
     lyr = layer[0]
-    _, grp, tq, d = q_ref.shape
-    rows_g = grp * tq
+    rows_g = q_ref.shape[1]
+    grp = rows_g // q_tile
 
     def each_page(item, buf, op):
         """``op`` on each of the item's NB K and NB V page copies into
@@ -294,49 +329,74 @@ def _msa_fused_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # the G heads' (TQ, D) tiles stacked into one (G*TQ, D) operand; the
-    # NB gathered pages stacked into one (NB*page, D) block of keys
-    qt = q_ref[0].astype(jnp.float32).reshape(rows_g, d) * scale
-    if latent is not None:
-        kt, vt = _latent_kv(k_buf[buf].astype(jnp.float32), latent)
-    else:
-        kt = k_buf[buf].astype(jnp.float32).reshape(nb * page, d)
-        vt = v_buf[buf].astype(jnp.float32).reshape(nb * page, d)
-
-    sc = jax.lax.dot_general(qt, kt, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    if softcap > 0:
-        sc = softcap * jnp.tanh(sc / softcap)
-
-    # rows of this tile that belong to THIS item's sequence run; rows of
+    # this item's sequence run as tile rows [run_lo, run_hi); rows of
     # other sequences sharing the tile are handled by their own items
-    rows = wl_qtile[w] * q_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (grp, tq, 1), 1).reshape(rows_g, 1)          # (G*TQ, 1)
-    row_ok = (rows >= q_start[s]) & (rows < q_start[s] + q_len[s])
+    run_lo = (q_start[s] - wl_qtile[w] * q_tile) * grp
+    run_hi = run_lo + q_len[s] * grp
 
-    # logical key positions of the block: slots past the needed range
-    # land beyond the horizon or the context and are masked here
-    ctx = context_lens[s]
-    kv_off = jax.lax.broadcasted_iota(jnp.int32, (1, nb * page), 1)
-    if latent is not None:
-        kv_off = _latent_key_offsets(kv_off, nb, latent)
-    kv_pos = wl_kvbase[w] + kv_off                               # (1, K)
-    rel = q_pos_ref[...].reshape(rows_g, 1) - kv_pos            # (G*TQ, K)
-    mask = row_ok & (rel >= 0) & (kv_pos < ctx)
-    if window > 0:
-        mask = mask & (rel < window)
-    sc = jnp.where(mask, sc, NEG_INF)
+    def attend(r0, n_rows):
+        """The item's score, mask, online-softmax and PV steps on tile
+        rows ``[r0, r0 + n_rows)``: only those rows of the running
+        max, sum and accumulator move."""
+        rs = pl.ds(r0, n_rows)
+        # the item's rows of the (TQ*G, D) operand; the NB gathered pages
+        # stacked into one (NB*page, D) block of keys
+        qt = q_ref[0, rs, :].astype(jnp.float32) * scale
+        if latent is not None:
+            kt, vt = _latent_kv(k_buf[buf].astype(jnp.float32), latent)
+        else:
+            d = k_buf.shape[-1]
+            kt = k_buf[buf].astype(jnp.float32).reshape(nb * page, d)
+            vt = v_buf[buf].astype(jnp.float32).reshape(nb * page, d)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-    p = jnp.exp(sc - m_new)
-    p = jnp.where(mask, p, 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, vt, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+        sc = jax.lax.dot_general(qt, kt, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        if softcap > 0:
+            sc = softcap * jnp.tanh(sc / softcap)
+
+        rows = r0 + jax.lax.broadcasted_iota(jnp.int32, (n_rows, 1), 0)
+        row_ok = (rows >= run_lo) & (rows < run_hi)
+
+        # logical key positions of the block: slots past the needed
+        # range land beyond the horizon or the context and are masked
+        ctx = context_lens[s]
+        kv_off = jax.lax.broadcasted_iota(jnp.int32, (1, nb * page), 1)
+        if latent is not None:
+            kv_off = _latent_key_offsets(kv_off, nb, latent)
+        kv_pos = wl_kvbase[w] + kv_off                           # (1, K)
+        rel = q_pos_ref[rs, :] - kv_pos                     # (n_rows, K)
+        mask = row_ok & (rel >= 0) & (kv_pos < ctx)
+        if window > 0:
+            mask = mask & (rel < window)
+        sc = jnp.where(mask, sc, NEG_INF)
+
+        m_prev = m_ref[rs, :]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        p = jnp.where(mask, p, 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[rs, :] = l_ref[rs, :] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+        acc_ref[rs, :] = acc_ref[rs, :] * alpha + jax.lax.dot_general(
+            p, vt, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[rs, :] = m_new
+
+    narrow = narrow_rows(grp, q_tile)
+    if narrow is None:
+        attend(0, rows_g)
+    else:
+        # a one-token run (a decode row) needs only its token's G rows:
+        # the whole 8-row tiles that hold them
+        one_token = q_len[s] == 1
+
+        @pl.when(one_token)
+        def _narrow():
+            attend(pl.multiple_of(run_lo & -8, 8), narrow)
+
+        @pl.when(jnp.logical_not(one_token))
+        def _wide():
+            attend(0, rows_g)
 
     @pl.when(wl_last[w] == 1)
     def _emit():
@@ -344,7 +404,7 @@ def _msa_fused_kernel(
         # emitted by their items' earlier tiles never reach here with
         # l == 0 except true padding, which emits exact zeros like the ref
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = out.reshape(grp, tq, o_ref.shape[-1]).astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _latent_kv(tiles, lay: LatentLayout):
@@ -441,12 +501,13 @@ def msa_fused_pallas(
     if t_pad != t:
         q = jnp.pad(q, ((0, t_pad - t), (0, 0), (0, 0)))
         q_pos = jnp.pad(q_pos, (0, t_pad - t))
-    # q grouped by KV head, (KH, G, T, D): one block per (KV head, q tile)
-    # carries every query head that reads the same K/V pages.  Only the
-    # activations move; the pools are already head-major.
-    qg = q.reshape(t_pad, kh, grp, d).transpose(1, 2, 0, 3)
-    qpos = jnp.broadcast_to(q_pos.astype(jnp.int32).reshape(1, t_pad, 1),
-                            (grp, t_pad, 1))
+    # q grouped by KV head and token-major inside it, (KH, T*G, D): one
+    # block per (KV head, q tile) carries every query head that reads
+    # the same K/V pages, and one token's G heads are G adjacent rows.
+    # Only the activations move; the pools are already head-major.
+    qg = q.reshape(t_pad, kh, grp, d).transpose(1, 0, 2, 3).reshape(
+        kh, t_pad * grp, d)
+    qpos = jnp.repeat(q_pos.astype(jnp.int32), grp).reshape(t_pad * grp, 1)
     # sentinel sequence row N: padding work-list items resolve to it and
     # mask every q row (q_len 0)
     zero = jnp.zeros((1,), jnp.int32)
@@ -455,10 +516,10 @@ def msa_fused_pallas(
     ctx = jnp.concatenate([context_lens.astype(jnp.int32), zero])
 
     def qpos_index(g_, w_, wl_seq_, wl_qtile_, *refs):
-        return (0, wl_qtile_[w_], 0)
+        return (wl_qtile_[w_], 0)
 
     def q_index(g_, w_, wl_seq_, wl_qtile_, *refs):
-        return (g_, 0, wl_qtile_[w_], 0)
+        return (g_, wl_qtile_[w_], 0)
 
     pools = [k_pages] if latent is not None else [k_pages, v_pages]
     page_bufs = [pltpu.VMEM((2, nb) + p.shape[3:], p.dtype) for p in pools]
@@ -466,10 +527,10 @@ def msa_fused_pallas(
         num_scalar_prefetch=10,
         grid=(kh, n_items),
         in_specs=[
-            pl.BlockSpec((grp, q_tile, 1), qpos_index),
-            pl.BlockSpec((1, grp, q_tile, d), q_index),
+            pl.BlockSpec((q_tile * grp, 1), qpos_index),
+            pl.BlockSpec((1, q_tile * grp, d), q_index),
         ] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-        out_specs=pl.BlockSpec((1, grp, q_tile, dv), q_index),
+        out_specs=pl.BlockSpec((1, q_tile * grp, dv), q_index),
         scratch_shapes=page_bufs + [
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((grp * q_tile, dv), jnp.float32),
@@ -483,7 +544,7 @@ def msa_fused_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((kh, grp, t_pad, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((kh, t_pad * grp, dv), q.dtype),
         # the page prefetch of item w+1 runs during item w, so both axes
         # run in order on one core
         compiler_params=pltpu.CompilerParams(
@@ -494,4 +555,5 @@ def msa_fused_pallas(
       wl_slot.astype(jnp.int32), wl_kvbase.astype(jnp.int32),
       wl_init.astype(jnp.int32), wl_last.astype(jnp.int32),
       qs, ql, ctx, layer, qpos, qg, *pools)
-    return out.transpose(2, 0, 1, 3).reshape(t_pad, h, dv)[:t]
+    return out.reshape(kh, t_pad, grp, dv).transpose(1, 0, 2, 3).reshape(
+        t_pad, h, dv)[:t]

@@ -534,6 +534,11 @@ class Engine:
         # distinct pool pages written by fused steps, summed over steps
         # (and iterations): each layer's KV write moves this many pages
         self.kv_pages_written = 0
+        # work-list items of the Pallas steps, summed over steps (and
+        # iterations), and those of one-token runs, which the kernel
+        # computes on their token's query rows alone
+        self.attn_items = 0
+        self.attn_narrow_items = 0
         # multi-token decode dispatch + decode-phase accounting
         self.decode_only_dispatches = 0    # dispatches with no prefill chunk
         self.decode_tokens_emitted = 0     # decode tokens across iterations
@@ -845,11 +850,11 @@ class Engine:
             tq = min(self.ecfg.q_tile, t_b)
             per_it = (lambda a, i: a[i] if n_it > 1 else a)
             wls = []
-            items = pages = 0
+            items = pages = narrow = 0
             with (self.tracer.worklist() if self.tracer is not None
                   else contextlib.nullcontext()):
                 for i in range(n_it):
-                    wl, n_items, n_pages = build_worklist(
+                    wl, n_items, n_pages, n_narrow = build_worklist(
                         fields["qstart"], per_it(fields["qlen"], i),
                         per_it(fields["ctx"], i), fields["bt"],
                         per_it(fields["positions"], i),
@@ -858,8 +863,11 @@ class Engine:
                     wls.append(wl)
                     items += n_items
                     pages += n_pages
+                    narrow += n_narrow
+            self.attn_items += items
+            self.attn_narrow_items += narrow
             if self.tracer is not None:
-                self.tracer.worklist_built(items, pages)
+                self.tracer.worklist_built(items, pages, narrow)
             # power-of-two W buckets keep the per-W jit variants at most
             # log2(Wmax) many
             w_b = max(WL_BUCKET, 1 << (max(
@@ -1370,6 +1378,10 @@ class Engine:
             "swap_bytes_shipped": self.swap_bytes_shipped,
             # pool pages the fused steps' KV writes touched (per layer)
             "kv_pages_written": self.kv_pages_written,
+            # work-list items of the Pallas steps, and those of one-token
+            # runs (decode rows) among them
+            "attn_items": self.attn_items,
+            "attn_narrow_items": self.attn_narrow_items,
             # multi-token decode dispatch (schema frozen by
             # tests/test_perf_counters.py — benchmark gates read these)
             "engine_dispatches": self.steps_executed,
@@ -1418,6 +1430,7 @@ class Engine:
         self.instep_swaps = self.eager_swaps = 0
         self.swap_bytes_shipped = 0
         self.kv_pages_written = 0
+        self.attn_items = self.attn_narrow_items = 0
         self.decode_only_dispatches = 0
         self.decode_tokens_emitted = 0
         self.multi_token_dispatches = 0

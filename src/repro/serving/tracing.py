@@ -56,9 +56,12 @@ class StepRecord(NamedTuple):
     compiled: bool          # the launch traced a new step variant
     # the step's work-lists (Pallas attention only, else 0): page-block
     # items and the pages they cover, before padding; the block fill is
-    # wl_pages / (wl_items * block_pages(page))
+    # wl_pages / (wl_items * block_pages(page)); wl_narrow_items of the
+    # items belong to one-token runs (decode rows), which the kernel
+    # computes on their token's query rows alone
     wl_items: int = 0
     wl_pages: int = 0
+    wl_narrow_items: int = 0
 
     @property
     def t_bucket(self) -> int:
@@ -99,7 +102,7 @@ class ServeTracer:
         self._step_span = None
         self._acc = [0.0] * n
         self._wl = 0.0
-        self._wl_work = (0, 0)           # (items, pages) of this step
+        self._wl_work = (0, 0, 0)   # (items, pages, narrow) of this step
         self._info: Optional[tuple] = None   # (now, prefill, decode rows)
         self._launch: Tuple[tuple, bool] = ((), False)
 
@@ -114,7 +117,7 @@ class ServeTracer:
         if self._step_span is not None:
             self._step_span.__exit__(None, None, None)
         self._acc = [0.0] * len(PHASES)
-        self._wl, self._wl_work, self._info = 0.0, (0, 0), None
+        self._wl, self._wl_work, self._info = 0.0, (0, 0, 0), None
         t = time.perf_counter()
         self.t = self.t_dispatch = t
         self._begin_step()
@@ -159,11 +162,12 @@ class ServeTracer:
         if compiled and self._span is not None:
             self._span.set_metadata(compiled=1)
 
-    def worklist_built(self, items: int, pages: int) -> None:
+    def worklist_built(self, items: int, pages: int, narrow: int) -> None:
         """A work-list of this step holds ``items`` page-block items
-        covering ``pages`` pages (before padding)."""
-        i, p = self._wl_work
-        self._wl_work = (i + items, p + pages)
+        covering ``pages`` pages (before padding), ``narrow`` of them
+        items of one-token runs."""
+        self._wl_work = tuple(
+            a + b for a, b in zip(self._wl_work, (items, pages, narrow)))
 
     @contextlib.contextmanager
     def worklist(self):
@@ -230,7 +234,7 @@ class ServeTracer:
         self.n_steps += 1
         self._acc = [0.0] * len(PHASES)
         self._wl = 0.0
-        self._wl_work = (0, 0)
+        self._wl_work = (0, 0, 0)
         self._info = None
         self._launch = ((), False)
         self._step_span.__exit__(None, None, None)

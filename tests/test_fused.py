@@ -12,6 +12,7 @@ from _hypothesis_compat import given, settings, st
 from repro.kernels.msa import (WL_FIELDS, block_pages, build_worklist,
                                msa_fused)
 from repro.kernels.msa import ref as msa_ref
+from repro.kernels.msa.msa_fused import narrow_rows
 
 KEY = jax.random.PRNGKey(0)
 
@@ -74,7 +75,7 @@ def _varlen_case(rng, *, n_pre, n_dec, page, NP, P, H, KH, D, max_run=14):
 def _worklist_for(case, *, page, q_tile, window):
     TQ = min(q_tile, case["T"])
     n_tiles = -(-case["T"] // TQ)
-    wl, _, _ = build_worklist(
+    wl, _, _, _ = build_worklist(
         np.asarray(case["q_start"]), np.asarray(case["q_len"]),
         np.asarray(case["ctx"]), np.asarray(case["bt"]),
         np.asarray(case["q_pos"]), page=page, q_tile=TQ,
@@ -141,10 +142,10 @@ def test_fused_ref_bitwise_matches_split_refs(seed, window, softcap):
 
 @settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 2**31 - 1),
-       h_kh=st.sampled_from([(4, 2), (4, 4), (8, 1)]),
+       h_kh=st.sampled_from([(4, 2), (4, 4), (8, 1), (16, 1), (16, 2)]),
        window=st.sampled_from([0, 9]),
        softcap=st.sampled_from([0.0, 20.0]),
-       q_tile=st.sampled_from([4, 8, 16]))
+       q_tile=st.sampled_from([4, 8, 16, 32]))
 def test_fused_kernel_property_sweep(seed, h_kh, window, softcap, q_tile):
     rng = np.random.RandomState(seed)
     H, KH = h_kh
@@ -177,7 +178,7 @@ def test_fused_kernel_zeroes_bucket_slack_tiles():
     q = jnp.pad(c["q"], ((0, t_bucket - c["T"]), (0, 0), (0, 0)))
     q_pos = jnp.pad(c["q_pos"], (0, t_bucket - c["T"]))
     n_tiles = -(-t_bucket // TQ)
-    wl, _, _ = build_worklist(
+    wl, _, _, _ = build_worklist(
         np.asarray(c["q_start"]), np.asarray(c["q_len"]),
         np.asarray(c["ctx"]), np.asarray(c["bt"]), np.asarray(q_pos),
         page=page, q_tile=TQ, n_tiles=n_tiles, window=0)
@@ -319,7 +320,7 @@ def test_fused_kernel_page_blocks(name, page):
     valid = jnp.pad(c["valid"], (0, pad))
     n_tiles = -(-t_b // TQ)
     if runs:
-        wl, n_items, n_pages = build_worklist(
+        wl, n_items, n_pages, _ = build_worklist(
             np.asarray(c["q_start"]), np.asarray(c["q_len"]),
             np.asarray(c["ctx"]), np.asarray(c["bt"]), np.asarray(q_pos),
             page=page, q_tile=TQ, n_tiles=n_tiles, window=window)
@@ -366,6 +367,68 @@ def test_fused_kernel_reads_its_layer_of_stacked_pools(layer):
     assert np.all(np.isfinite(np.asarray(got)))
 
 
+# decode rows (one-token runs) inside the tiles of prefill chunks and of
+# each other: a chunk of 5, three decode rows, a chunk of 3, two decode
+# rows (one over three 128-key blocks), so at TQ 8 a decode row's
+# 8-row-aligned slice of the tile also holds rows of other sequences
+_NARROW_RUNS = [(np.arange(200, 205), 205), (np.asarray([76]), 77),
+                (np.asarray([129]), 130), (np.asarray([3]), 4),
+                (np.arange(40, 43), 43), (np.asarray([LONG_CTX - 1]),
+                                          LONG_CTX),
+                (np.asarray([150]), 151)]
+
+
+@pytest.mark.parametrize("grp,q_tile", [(2, 8), (4, 8), (16, 8), (4, 16)])
+def test_fused_kernel_narrow_decode_items(grp, q_tile):
+    """A decode row's items compute only its token's query rows (the
+    8-row tiles that hold its G heads) while prefill chunks and other
+    decode rows share its q tile; the kernel agrees with the varlen
+    oracle on every row."""
+    assert narrow_rows(grp, q_tile) == max(8, grp)
+    c = _runs_case(_NARROW_RUNS, page=16, seed=grp, H=2 * grp, KH=2)
+    wl = _worklist_for(c, page=16, q_tile=q_tile, window=0)
+    args = (c["q"], c["k_pages"], c["v_pages"], c["bt"], c["ctx"],
+            c["q_pos"], c["seq_ids"], c["valid"])
+    o = msa_fused(*args, q_start=c["q_start"], q_len=c["q_len"],
+                  worklist=wl, q_tile=q_tile, impl="pallas_interpret")
+    o_ref = msa_fused(*args, impl="xla")
+    assert float(jnp.max(jnp.abs(o - o_ref))) < 1e-5
+
+
+def test_narrow_rows_only_where_they_save_rows():
+    """The narrow item's rows: whole 8-row tiles holding one token's G
+    heads, and none where that is the whole tile, the tile is no whole
+    number of 8-row tiles, or G heads could straddle two of them."""
+    assert [narrow_rows(g, 16) for g in (1, 2, 4, 8, 16, 32)] == \
+        [8, 8, 8, 8, 16, 32]
+    assert narrow_rows(1, 8) is None and narrow_rows(2, 4) is None
+    assert narrow_rows(4, 3) is None and narrow_rows(4, 4) == 8
+    assert narrow_rows(3, 16) is None and narrow_rows(12, 16) is None
+
+
+def test_worklist_counts_narrow_items():
+    """Every real item of a decode-only step is narrow; in a mixed step
+    the narrow items are the decode rows' items."""
+    c = _runs_case(_NARROW_RUNS, page=16, seed=0)
+    meta = {k: np.asarray(c[k]) for k in ("q_start", "q_len", "ctx", "bt",
+                                           "q_pos")}
+    decode = meta["q_len"] == 1
+    wl, n_items, _, n_narrow = build_worklist(
+        *meta.values(), page=16, q_tile=8, n_tiles=-(-c["T"] // 8))
+    real = wl["wl_seq"] < c["n"]
+    assert n_items == real.sum()
+    assert n_narrow == decode[wl["wl_seq"][real]].sum()
+    assert 0 < n_narrow < n_items
+    # the decode rows alone, as a decode-only step's stream
+    dec = np.flatnonzero(decode)
+    q_pos = meta["q_pos"][meta["q_start"][dec]]
+    wl, n_items, _, n_narrow = build_worklist(
+        np.arange(len(dec), dtype=np.int32), np.ones(len(dec), np.int32),
+        meta["ctx"][dec], meta["bt"][dec], q_pos, page=16, q_tile=8,
+        n_tiles=1)
+    assert n_narrow == n_items == (wl["wl_seq"] < len(dec)).sum() > len(dec)
+
+
 def _needed_pages(c, *, page, q_tile, n_tiles, window):
     """Page-granular rule, one page at a time: (seq, tile) -> needed
     logical pages."""
@@ -401,13 +464,15 @@ def test_worklist_blocks_the_needed_pages(page):
                          NP=-(-900 // page), P=64, max_run=40)
         q_tile = (8, 16, 32)[trial % 3]
         n_tiles = -(-c["T"] // q_tile)
-        wl, n_items, n_pages = build_worklist(
+        wl, n_items, n_pages, n_narrow = build_worklist(
             c["q_start"], c["q_len"], c["ctx"], c["bt"], c["q_pos"],
             page=page, q_tile=q_tile, n_tiles=n_tiles, window=window)
         need = _needed_pages(c, page=page, q_tile=q_tile, n_tiles=n_tiles,
                              window=window)
         assert n_items == sum(-(-len(v) // nb) for v in need.values())
         assert n_pages == sum(len(v) for v in need.values())
+        assert n_narrow == sum(-(-len(v) // nb) for (s, _), v in need.items()
+                               if c["q_len"][s] == 1)
         assert wl["wl_slot"].shape == (wl["wl_seq"].shape[0] * nb,)
         seen = {}
         for i, s in enumerate(wl["wl_seq"]):
@@ -465,7 +530,7 @@ def test_blocked_worklist_fits_the_warm_lattice():
                     lo = q_start[s]
                     q_pos[lo:lo + q_len[s]] = np.arange(ctx - q_len[s], ctx)
                 bt = np.arange(n * np_b).reshape(n, np_b) % ecfg.num_pages
-                wl, _, _ = build_worklist(
+                wl, _, _, _ = build_worklist(
                     q_start, np.asarray(q_len), np.full((n,), ctx), bt,
                     q_pos, page=page, q_tile=tq, n_tiles=-(-t_b // tq))
                 w = max(WL_BUCKET, 1 << (wl["wl_seq"].shape[0] - 1)

@@ -46,7 +46,7 @@ def _fused_both(q, k_pages, v_pages, bt, ctx, runs, *, t_bucket, page,
     seq_ids[:t] = np.repeat(np.arange(len(runs)), q_len)
     valid = np.arange(t_bucket) < t
     tq = min(q_tile, t_bucket)
-    wl, _, _ = build_worklist(q_start, q_len, np.asarray(ctx),
+    wl, _, _, _ = build_worklist(q_start, q_len, np.asarray(ctx),
                               np.asarray(bt), q_pos, page=page, q_tile=tq,
                               n_tiles=-(-t_bucket // tq), window=window)
     args = (q, k_pages, v_pages, bt, ctx, jnp.asarray(q_pos),

@@ -22,6 +22,7 @@ import pytest
 
 from repro.kernels.msa import build_worklist, msa_fused, pad_worklist
 from repro.kernels.msa.latent import LatentLayout
+from repro.kernels.msa.msa_fused import narrow_rows
 from repro.models.layers import moe_ffn_local, swiglu_mlp
 from repro.serving import (AsymCacheServer, EngineConfig, SchedulerConfig,
                            ServerConfig, WorkloadConfig, multi_turn_workload)
@@ -113,7 +114,7 @@ def test_msa_fused_takes_v_from_the_cached_latent():
     pool, q, bt, ctx, q_pos, seq_ids, valid = _latent_case(0, lay)
     q_start = np.array([0, 30], np.int32)
     q_len = np.array([30, 1], np.int32)
-    wl, _, _ = build_worklist(q_start, q_len, ctx, bt, q_pos, page=16,
+    wl, _, _, _ = build_worklist(q_start, q_len, ctx, bt, q_pos, page=16,
                               q_tile=16, n_tiles=2)
     wl = pad_worklist(wl, 64, sentinel_seq=2)
     args = (q, pool, None, jnp.asarray(bt), jnp.asarray(ctx),
@@ -135,6 +136,44 @@ def test_msa_fused_takes_v_from_the_cached_latent():
         np.testing.assert_allclose(got[row], p.T @ keys[:, :lay.rank],
                                    rtol=1e-4, atol=1e-5)
     np.testing.assert_array_equal(got[31], 0.0)
+
+
+def test_msa_fused_latent_decode_rows_go_narrow():
+    """Latent attention at G=16 and TQ=32: decode rows whose items
+    compute only their token's 16 query rows, in one tile with a
+    prefill chunk, other decode rows and padding, equal the oracle."""
+    lay = LatentLayout(page=16, rank=128, rope=64)
+    assert narrow_rows(16, 32) == 16
+    rng = np.random.default_rng(5)
+    n_pages, n, t = 48, 7, 32
+    vecs = rng.standard_normal((n_pages, 1, lay.page, lay.width))
+    pool = lay.to_tile(jnp.asarray(vecs, jnp.float32))
+    bt = np.stack([rng.permutation(n_pages)[:20] for _ in range(n)])
+    bt = bt.astype(np.int32)
+    # a 20-token chunk, then six decode rows over 1 to 3 128-key blocks
+    ctx = np.array([120, 7, 129, 300, 64, 257, 18], np.int32)
+    q_len = np.array([20] + [1] * 6, np.int32)
+    q_start = np.concatenate([[0], np.cumsum(q_len)[:-1]]).astype(np.int32)
+    q_pos = np.zeros((t,), np.int32)
+    q_pos[:20] = np.arange(100, 120)
+    q_pos[20:26] = ctx[1:] - 1
+    seq_ids = np.zeros((t,), np.int32)
+    seq_ids[:26] = np.repeat(np.arange(n), q_len)
+    valid = np.arange(t) < 26
+    q = jnp.asarray(rng.standard_normal((t, 16, lay.width)), jnp.float32)
+    wl, n_items, _, n_narrow = build_worklist(q_start, q_len, ctx, bt,
+                                              q_pos, page=16, q_tile=32,
+                                              n_tiles=1)
+    assert n_narrow == n_items - 1 > 6
+    args = (q, pool, None, jnp.asarray(bt), jnp.asarray(ctx),
+            jnp.asarray(q_pos), jnp.asarray(seq_ids), jnp.asarray(valid))
+    got = msa_fused(*args, q_start=jnp.asarray(q_start),
+                    q_len=jnp.asarray(q_len),
+                    worklist=tuple(jnp.asarray(wl[f]) for f in wl),
+                    q_tile=32, latent=lay, impl="pallas_interpret")
+    want = msa_fused(*args, latent=lay, impl="xla")
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(got[26:], 0.0)
 
 
 def test_absorbed_latent_attention_equals_expanded(model, arch):

@@ -37,6 +37,8 @@ ENGINE_COUNTER_KEYS = frozenset({
     "eager_swaps",
     "swap_bytes_shipped",
     "kv_pages_written",
+    "attn_items",
+    "attn_narrow_items",
     "engine_dispatches",
     "decode_only_dispatches",
     "decode_tokens_emitted",
@@ -63,6 +65,8 @@ MONOTONIC_KEYS = (
     "eager_swaps",
     "swap_bytes_shipped",
     "kv_pages_written",
+    "attn_items",
+    "attn_narrow_items",
 )
 
 # the sim engine mirrors this subset so stress-benchmark gates read the

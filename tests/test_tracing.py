@@ -169,6 +169,28 @@ def test_spans_carry_step_ids_in_a_profiler_trace(small_model, tmp_path):
     assert 0 < srv.tracer.worklist_s < srv.tracer.totals[ASSEMBLE]
 
 
+def test_records_count_the_narrow_items(small_model):
+    """Each step's record counts its work-list items of one-token runs:
+    some on every step that carries a decode row, all of them on a
+    decode-only step; the engine's counters sum the records."""
+    cfg, params = small_model
+    srv = _server(cfg, params, attn_impl="pallas_interpret")
+    srv.run(_wl(n_sessions=2, turns_per_session=(1, 1),
+                first_ctx_len=(20, 40), output_len=(4, 6)))
+    recs = srv.tracer.steps()
+    assert any(r.prefill_tokens and r.decode_rows for r in recs)
+    assert any(r.decode_rows and not r.prefill_tokens for r in recs)
+    for r in recs:
+        assert 0 <= r.wl_narrow_items <= r.wl_items
+        if r.decode_rows:
+            assert r.wl_narrow_items > 0, r
+        if not r.prefill_tokens:
+            assert r.wl_narrow_items == r.wl_items, r
+    pc = srv.engine.perf_counters()
+    assert pc["attn_items"] == sum(r.wl_items for r in recs)
+    assert pc["attn_narrow_items"] == sum(r.wl_narrow_items for r in recs)
+
+
 def test_step_hlo_names_every_scope(served):
     srv, _ = served
     eng = srv.engine
